@@ -536,7 +536,8 @@ def run_op_benchmarks(ops=None, ctx=None, warmup=5, runs=50,
     from mxnet_tpu.ops import registry
 
     if ctx is None:
-        ctx = mx.tpu() if mx.num_tpus() > 0 else mx.cpu()
+        mx.context.require_tpu("opperf")
+        ctx = mx.tpu()
     if ops is None:
         ops = [o for o in _PROFILES if registry.op_exists(o)]
     results = []

@@ -70,9 +70,9 @@ and external measurements subtract cleanly.
   0/2/4, tok/s + accept rate + tokens/step per row); ``--spec-K N``
   arms speculation on the headline e2e engine run instead.
 * ``tp`` (round 14, ``--tp N``) — tensor-parallel serving on the
-  8-device VIRTUAL CPU mesh (the same
-  ``--xla_force_host_platform_device_count`` mechanism the MULTICHIP
-  dry-runs use; requested before jax initializes, so ``--tp`` runs as
+  8-device VIRTUAL CPU mesh (the same ``jax_num_cpu_devices``
+  mechanism the MULTICHIP dry-runs use; requested before a backend
+  initializes, so ``--tp`` runs as
   its own invocation — ENFORCED: the other sections are skipped, as
   their recorded numbers assume the single-device host topology the
   virtual mesh replaces): the closed-loop engine run at tp=1 and
@@ -2379,15 +2379,11 @@ def main(argv=None):
         ap.error("--chrome-trace needs the telemetry section; drop "
                  "--no-telemetry")
     if args.tp > 1:
-        # request the virtual CPU mesh BEFORE anything below imports
-        # jax (the same mechanism the tests' conftest and the
-        # MULTICHIP dry-runs use); a no-op if the flag is already
-        # present or a real multi-chip backend is up
-        if "xla_force_host_platform_device_count" not in \
-                os.environ.get("XLA_FLAGS", ""):
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "")
-                + " --xla_force_host_platform_device_count=8").strip()
+        # request the virtual CPU mesh BEFORE any backend initializes
+        # (the same mechanism the tests' conftest uses); the CPU
+        # backend is not the default where a real chip is visible
+        import jax
+        jax.config.update("jax_num_cpu_devices", 8)
     p = PRESETS["quick" if args.quick else args.preset]
 
     params, cfg = _model(p)

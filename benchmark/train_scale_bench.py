@@ -36,7 +36,7 @@ Sections (all rows JSON; ``--json`` writes the MULTICHIP_r10 file):
               numeric one).  dp=1 is the unsharded baseline row.
 
 CPU-pricing caveat (same as the round-14 tp rows): the 8-device mesh
-here is ``--xla_force_host_platform_device_count`` over ONE host CPU —
+here is ``jax_num_cpu_devices`` over ONE host CPU —
 the dp>1 ex/s prices emulated collectives and core-sharing, not ICI,
 so the scaling curve's SHAPE is not a chip prediction; the exactness
 and byte-accounting claims are placement facts and transfer.
@@ -520,7 +520,7 @@ def run_gate_pretrain(preset="full", seed=0):
     if dp < 2:
         raise RuntimeError(
             "bert_pretrain gate needs >= 2 devices (virtual mesh ok: "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
+            "jax.config.update('jax_num_cpu_devices', 8))")
     ex_row = run_exactness("mid" if preset == "full" else preset,
                            seed=seed)
     by_row = run_fsdp_bytes(preset, dp=dp, seed=seed)
@@ -561,13 +561,11 @@ def main(argv=None):
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
 
-    # request the virtual CPU mesh BEFORE jax imports (the conftest /
-    # serve_bench --tp mechanism); a no-op on a real multi-chip backend
-    if "xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=8").strip()
+    # request the virtual CPU mesh BEFORE any backend initializes (the
+    # conftest / serve_bench --tp mechanism); the CPU backend is not
+    # the default where a real chip is visible
+    import jax
+    jax.config.update("jax_num_cpu_devices", 8)
 
     rows = []
     if args.all or args.exactness:

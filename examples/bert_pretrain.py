@@ -33,10 +33,7 @@ def main():
     # mesh before the backend initializes (same trick as tests/conftest)
     need = max(1, args.dp) * args.tp * args.sp
     if os.environ.get("JAX_PLATFORMS") == "cpu":
-        try:
-            jax.config.update("jax_num_cpu_devices", need)
-        except Exception:
-            pass
+        jax.config.update("jax_num_cpu_devices", need)
 
     import jax.numpy as jnp
     from mxnet_tpu.parallel import make_mesh
@@ -54,7 +51,7 @@ def main():
 
     mk = T.bert_base if args.size == "base" else T.bert_tiny
     cfg = mk(max_len=args.seq_len, dropout=0.1, remat=True,
-             use_flash=jax.default_backend() == "tpu",
+             use_flash=True,
              seq_parallel="ring" if args.sp > 1 else None)
     init_state, step = T.make_train_step(cfg, mesh=mesh,
                                          learning_rate=1e-4)

@@ -37,7 +37,7 @@ def main():
     p.add_argument("--lr", type=float, default=0.05)
     args = p.parse_args()
 
-    ctx = mx.tpu() if mx.num_tpus() > 0 else mx.cpu()
+    ctx = mx.tpu()   # device 0 of the default backend
     print("context:", ctx)
 
     net = nn.HybridSequential()

@@ -34,7 +34,7 @@ def main():
                    help="bf16 compute with f32 master params")
     args = p.parse_args()
 
-    ctx = mx.tpu() if mx.num_tpus() > 0 else mx.cpu()
+    ctx = mx.tpu()   # device 0 of the default backend
     net = getattr(vision, args.model)()
     net.initialize(mx.initializer.Xavier(), ctx=ctx)
 

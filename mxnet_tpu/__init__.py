@@ -14,7 +14,9 @@ Usage mirrors the reference::
 """
 __version__ = "0.1.0"
 
-from .base import MXNetError
+from .base import MXNetError, place_compile_cache
+place_compile_cache()
+del place_compile_cache
 from .context import Context, cpu, gpu, tpu, cpu_pinned, current_context, \
     num_gpus, num_tpus
 from . import engine

@@ -36,6 +36,27 @@ class MXNetError(RuntimeError):
     """Framework-level error, mirrors the reference's ``MXNetError``."""
 
 
+def place_compile_cache() -> None:
+    """Give JAX's persistent compilation cache a home that can be placed
+    from outside.  ``JAX_COMPILATION_CACHE_DIR``, if set, is JAX's own
+    setting and nothing is touched; otherwise the cache goes to
+    ``<checkout>/.jax_cache`` — a fixed path derived from this package's
+    location alone (the path is part of the cache key, so it must not
+    move between runs or differ between a parent and the workers it
+    spawns).  A process pinned to the CPU backend gets none: the cache
+    is for the chip's minutes-long compiles, and XLA:CPU reloads a
+    cached executable with a machine-feature warning per entry.  Called
+    once, from the package ``__init__``; touches no backend."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    if jax.config.jax_platforms == "cpu":
+        return
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(root, ".jax_cache"))
+
+
 def env_flag(name: str, default: bool = False) -> bool:
     v = os.environ.get(name)
     if v is None:

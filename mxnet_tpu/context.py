@@ -8,9 +8,8 @@ and resolves to the accelerator backend too.  ``cpu()`` maps to the JAX CPU
 backend (always present).
 
 Under the test harness (``JAX_PLATFORMS=cpu`` with
-``--xla_force_host_platform_device_count=N``) ``tpu(i)`` resolves to virtual
-host device ``i`` so multi-device code paths are exercisable without
-hardware.
+``jax_num_cpu_devices=N``) ``tpu(i)`` resolves to virtual host device ``i``
+so multi-device code paths are exercisable without hardware.
 """
 from __future__ import annotations
 
@@ -20,20 +19,68 @@ from typing import List, Optional
 from .base import MXNetError
 
 __all__ = ["Context", "cpu", "gpu", "tpu", "cpu_pinned", "current_context",
-           "num_tpus", "num_gpus", "device"]
+           "num_tpus", "num_gpus", "device", "accel_platform",
+           "require_tpu", "host_tpu_chips", "one_chip_env",
+           "held_accelerator"]
 
 
-def _accel_platform():
-    """Return the platform name of the accelerator backend, or None."""
+def accel_platform() -> str:
+    """Platform of the default JAX backend: ``"tpu"`` on a chip host,
+    ``"cpu"`` under the test harness.  ``num_tpus()`` cannot say this —
+    it counts ``tpu(i)``-addressable devices, and the harness's virtual
+    CPU devices are addressable as ``tpu(i)``."""
     import jax
-    try:
-        devs = jax.devices()
-    except Exception:
-        return None
-    if not devs:
-        return None
-    plat = devs[0].platform
-    return plat
+    return jax.devices()[0].platform
+
+
+def require_tpu(what: str) -> None:
+    """Measurement and oracle entry points call this first: a number
+    taken on the CPU backend must never be printed under the name of a
+    device metric, so off-chip they exit non-zero instead of falling
+    back."""
+    plat = accel_platform()
+    if plat != "tpu":
+        raise SystemExit(
+            "%s needs a TPU: JAX found platform %r and will not fall "
+            "back to it" % (what, plat))
+
+
+# -- one process per chip -------------------------------------------------
+# A TPU chip belongs to one process at a time: a process that has touched
+# JAX holds every chip it can see, and a child that needs one then fails
+# ("The TPU is already in use by process with pid N").  A parent that
+# starts chip-using children therefore stays off the chips itself and
+# hands each child exactly one.  These three helpers initialise no
+# backend, so a launcher can call them.
+
+def host_tpu_chips() -> int:
+    """TPU chips on this host, counted from the device nodes libtpu
+    opens (``/dev/vfio/N`` on v5e; ``/dev/accelN`` on older hosts)."""
+    import glob
+    return len(glob.glob("/dev/vfio/[0-9]*")
+               + glob.glob("/dev/accel[0-9]*"))
+
+
+def one_chip_env(index: int) -> dict:
+    """Environment that lets a child process claim exactly chip
+    ``index`` of this host and leaves the others free.  The set libtpu
+    0.0.34 honours: four such children ran side by side on a v5e 2x2
+    host, each seeing one device (PR 21 four-chip run); without it the
+    first child takes all four and the second fails on libtpu's
+    lockfile."""
+    return {"TPU_VISIBLE_CHIPS": str(int(index)),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
+def held_accelerator():
+    """Platform name of an accelerator backend THIS process has
+    already initialised — and whose chips it therefore holds — or None.
+    Never initialises one."""
+    # the registry of initialised backends has no public reader that
+    # does not itself initialise them
+    from jax._src import xla_bridge
+    return next((p for p in xla_bridge._backends if p != "cpu"), None)
 
 
 class Context:
@@ -76,13 +123,11 @@ class Context:
             # local_devices(backend=...) keeps the cpu path process-local
             # too — jax.devices("cpu") is cluster-global under multi-host
             # and could hand a non-zero worker another host's CPU device.
-            try:
-                devs = [d for d in jax.local_devices()
-                        if d.platform == "cpu"] \
-                    or jax.local_devices(backend="cpu")
-            except RuntimeError:
-                # CPU backend absent (rare); fall back to default backend.
-                devs = jax.local_devices()
+            # (raises RuntimeError where no CPU backend exists — a cpu
+            # context never silently lands on the accelerator)
+            devs = [d for d in jax.local_devices()
+                    if d.platform == "cpu"] \
+                or jax.local_devices(backend="cpu")
             return devs[self.device_id % len(devs)]
         # tpu/gpu → accelerator backend; under the CPU test harness this is
         # the virtual host-device array.

@@ -11,6 +11,18 @@ forward saves per-row logsumexp; ``delta = rowsum(dO·O)`` is a cheap jnp
 reduction; a dq kernel (grid over q blocks, scanning kv) and a dk/dv
 kernel (grid over kv blocks, scanning q) recompute probabilities
 blockwise so nothing quadratic is ever materialized.
+
+Sequence-length limit: every program holds a WHOLE ``(1, T, dh)`` K and
+V block in VMEM (the dk/dv kernel: Q and dO), double-buffered, with dh
+padded to the 128-lane tile.  AOT compiles against v5e (PR 21) put the
+ceiling at a block of about 4 MiB — T = 16,384 in bf16, 8,192 in f32:
+up to 2 MiB (T = 8,192 bf16, the longest ever run on a chip) every
+program tried compiles; between 2 and 4 MiB it depends on how much
+VMEM XLA takes for the surrounding program (``RESOURCE_EXHAUSTED …
+vmem`` when it does not fit); above 4 MiB every 12-head program was
+refused, so ``flash_attention`` raises ``ValueError`` there.  Longer
+sequences need K/V streamed in blocks (ROADMAP B7) or
+``parallel/ring_attention.py``.
 """
 from __future__ import annotations
 
@@ -27,6 +39,10 @@ __all__ = ["flash_attention"]
 # reference to tight tolerances without MXU rounding in the way; also
 # forces the kernel path regardless of sequence length
 _INTERPRET = False
+
+# largest whole-sequence VMEM block (T x 128-padded dh x itemsize) the
+# kernels accept — module docstring, "Sequence-length limit"
+MAX_KV_BLOCK_BYTES = 4 << 20
 
 # below this sequence length the XLA-fused attention wins on this
 # hardware (measured fwd+bwd crossover — docs/perf.md "Long context"):
@@ -446,9 +462,12 @@ def flash_attention(q, k, v, mask=None, causal=False, dropout=0.0,
     (T, T) mask is ever materialized, and the backward regenerates the
     identical mask from positions (SURVEY.md §5.7; round-4 item #7).
 
-    Falls back to the jnp reference off-TPU (CPU tests) or when shapes
-    don't tile (T not divisible by the 128 block, dh not lane-aligned);
-    the fallback applies the same hash dropout.
+    Routing: the jnp reference where the operands live on the CPU
+    backend (under tracing: where ``jit`` will place the program),
+    below ``MXNET_FLASH_MIN_SEQ``, or when shapes don't tile (T not
+    divisible by the 128 block, dh not lane-aligned); the reference
+    applies the same hash dropout.  Above ``MAX_KV_BLOCK_BYTES`` (module
+    docstring) the kernels cannot hold K/V in VMEM: ``ValueError``.
 
     Memory note: the fallback materializes the (B, H, T, T) keep mask
     densely on top of the probs tensor, so dropout training roughly
@@ -470,7 +489,10 @@ def flash_attention(q, k, v, mask=None, causal=False, dropout=0.0,
         seed = jnp.asarray(dropout_seed, jnp.int32).reshape(1)
     else:
         seed = jnp.zeros(1, jnp.int32)
-    platform = jax.devices()[0].platform
+    from .platform import default_platform, platform_of
+    # decided here at trace time, not staged per platform: training
+    # differentiates through this call (platform.run_kernel docstring)
+    platform = platform_of(q, k, v) or default_platform()
     B, T, H, dh = q.shape
     if not _INTERPRET and (platform == "cpu" or T < _min_seq()):
         return _reference_attention(q, k, v, mask, causal=causal,
@@ -478,6 +500,15 @@ def flash_attention(q, k, v, mask=None, causal=False, dropout=0.0,
     if T % 128 != 0 or dh not in (64, 128, 256):
         return _reference_attention(q, k, v, mask, causal=causal,
                                     dropout=dropout, seed=seed)
+    block_bytes = T * max(dh, 128) * jnp.dtype(q.dtype).itemsize
+    if not _INTERPRET and block_bytes > MAX_KV_BLOCK_BYTES:
+        raise ValueError(
+            "flash_attention: T=%d, dh=%d, %s needs a %.1f MiB whole-"
+            "sequence K/V block in VMEM; the kernels hold at most %d MiB "
+            "(T=16384 bf16 / 8192 f32) — shard the sequence "
+            "(parallel/ring_attention.py)"
+            % (T, dh, jnp.dtype(q.dtype).name, block_bytes / 2**20,
+               MAX_KV_BLOCK_BYTES >> 20))
     key = (causal, dropout)
     fn = _flash_cached.get(key)
     if fn is None:

@@ -59,7 +59,9 @@ def _expand_per_tensor(values, meta, total):
     """Per-tensor scalars → flat per-element vector matching the packed
     buffer layout."""
     import jax.numpy as jnp
-    parts = [jnp.full((n,), float(v), jnp.float32)
+    # v may be a traced scalar: the eager-jit path passes lr/lrs as
+    # jit arguments so an lr schedule does not retrace per step
+    parts = [jnp.full((n,), v, jnp.float32)
              for v, (_, _, _, n) in zip(values, meta)]
     flat = jnp.concatenate(parts) if parts else jnp.zeros((0,),
                                                           jnp.float32)
@@ -108,8 +110,7 @@ def fused_multi_sgd(weights, grads, moms=None, *, lrs, wds,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    from .platform import run_kernel
 
     if len(lrs) != len(weights) or len(wds) != len(weights):
         # the per-tensor loop path would IndexError; fail just as loudly
@@ -131,21 +132,26 @@ def fused_multi_sgd(weights, grads, moms=None, *, lrs, wds,
     if moms is None:
         kern = functools.partial(_sgd_kernel, rescale=rescale_grad,
                                  clip=clip_gradient)
-        new_flat = pl.pallas_call(
-            kern, grid=(n_blocks,),
-            in_specs=[spec, spec, spec, spec], out_specs=spec,
-            out_shape=out_shape, interpret=interpret,
-        )(wflat, gflat, lrvec, wdvec)
-        return group_unflatten(new_flat, meta), None
+        args = (wflat, gflat, lrvec, wdvec)
+        out_specs, out_shapes = spec, out_shape
+    else:
+        mflat, _ = group_flatten(moms)
+        kern = functools.partial(_sgd_mom_kernel, momentum=momentum,
+                                 rescale=rescale_grad,
+                                 clip=clip_gradient)
+        args = (wflat, gflat, mflat, lrvec, wdvec)
+        out_specs, out_shapes = [spec, spec], [out_shape, out_shape]
 
-    mflat, _ = group_flatten(moms)
-    kern = functools.partial(_sgd_mom_kernel, momentum=momentum,
-                             rescale=rescale_grad, clip=clip_gradient)
-    new_flat, new_mflat = pl.pallas_call(
-        kern, grid=(n_blocks,),
-        in_specs=[spec, spec, spec, spec, spec],
-        out_specs=[spec, spec],
-        out_shape=[out_shape, out_shape], interpret=interpret,
-    )(wflat, gflat, mflat, lrvec, wdvec)
+    def call(interp):
+        return pl.pallas_call(
+            kern, grid=(n_blocks,), in_specs=[spec] * len(args),
+            out_specs=out_specs, out_shape=out_shapes,
+            interpret=interp)
+
+    # interpreted exactly where the group lives on the CPU backend
+    out = run_kernel(call, *args, interpret=interpret)
+    if moms is None:
+        return group_unflatten(out, meta), None
+    new_flat, new_mflat = out
     return (group_unflatten(new_flat, meta),
             group_unflatten(new_mflat, meta))

@@ -52,32 +52,24 @@ cases in interpreter mode, and the serving tests pin full greedy
 TOKEN-identity of the pallas engine against ``generate`` — the
 exactness bar the serving stack actually guarantees.
 
-Chip status: NOT chip-measured this round (no TPU session).  The
-interpreter path is the tier-1 correctness oracle; on CPU it runs the
-grid as a compiled loop (~10x slower than the XLA gather at mid-preset
-shapes — the fusion win is an HBM-traffic argument that only a chip
-can price).  Refresh ``gpt_serve_decode_step_ms`` (tp=1) and
-``gpt_serve_pallas_tp2_step_ms`` (the mesh lowering) with
-``perf_regression.py --update`` at the next chip session —
-docs/perf.md "Chip-readiness" has the full order.
+Chip status (PR 21): the two head-batched ``dot_general``s this kernel
+was written with (batch dim not leading, no free rhs dim) were refused
+by Mosaic — ``failed to parse TPU_DotDimensionNumbersAttr`` — so before
+PR 21 the kernel had only ever run in the interpreter.  Both
+contractions are now multiply-and-reduce on the VPU (one query row per
+head: the walk is bound by the page stream, not by flops), which
+Mosaic compiles for v5e at every shape ``tests/test_kernels_mosaic.py``
+tries (bf16/f32/int8 pools, the ``full`` preset's 12 heads and its
+H/tp slices 6 and 3, the head-blocked 16/32-head walk).  On the chip
+``chip_smoke.py`` pins it against ``paged_attention_reference`` and
+runs the ``full`` preset engine through it; CHANGES.md (PR 21) has
+what that run found.  Its speed is NOT measured — ROADMAP A3.
 """
 from __future__ import annotations
 
 import functools
 
 __all__ = ["paged_attention", "paged_attention_reference"]
-
-# test hook (mirrors kernels/flash_attention.py): force interpreter
-# mode regardless of platform.  paged_attention() also auto-interprets
-# whenever the default device is not a TPU, so tier-1 CPU tests and the
-# serving engine's kernel="pallas" path need no explicit flag.
-_INTERPRET = False
-
-
-def _use_interpret():
-    import jax
-    return _INTERPRET or jax.devices()[0].platform != "tpu"
-
 
 def _kernel(bt_ref, pos_ref, q_ref, kv_ref, *rest, page_size, dh,
             int8):
@@ -114,40 +106,44 @@ def _kernel(bt_ref, pos_ref, q_ref, kv_ref, *rest, page_size, dh,
         kv = kv_ref[0]                       # (ps, HB, 2*dh) cdt|int8
         q = q_ref[0]                         # (HB, dh) cdt
         cdt = q.dtype
-        k = kv[:, :, :dh].astype(cdt)
-        v = kv[:, :, dh:].astype(cdt)
-        # scores: contraction over dh, batched over heads → (HB, ps)
-        s = jax.lax.dot_general(
-            k, q, (((2,), (1,)), ((1,), (0,))),
-            preferred_element_type=jnp.float32)
+        f32 = jnp.float32
+        k = kv[:, :, :dh].astype(f32)
+        v = kv[:, :, dh:].astype(f32)
+        # One query row per head: both contractions are multiply-and-
+        # reduce on the VPU.  Mosaic has no matmul form for a batch dim
+        # that is not leading, and a (1, dh) x (dh, ps) product per
+        # head would leave the MXU idle anyway — the walk is bound by
+        # the page stream, not by these flops.  Products of cdt (or
+        # int8) values are exact in f32, so this matches an MXU dot
+        # with f32 accumulation up to summation order.
+        s = jnp.sum(k * q.astype(f32)[None], axis=-1)   # (ps, HB)
         if int8:
             # k scale multiplies the scores (the same fold point as
-            # _attend_rows).  s_ref[0] is the page's retiled scale
-            # block (2, ps, HB): plane 0 = k scales, plane 1 = v —
-            # each plane streams as aligned (sublane=ps, lane=HB)
-            # tiles instead of the old per-column (.., HB, 2) rows
-            s = s * s_ref[0][0].T
-        s = s / jnp.sqrt(jnp.float32(dh))
-        k_pos = j * page_size + jnp.arange(page_size)
-        s = jnp.where(k_pos[None, :] <= pos, s, -1e30)
+            # _attend_rows).  s_ref[0] is the page's scale block
+            # (2, ps, HB): plane 0 = k scales, plane 1 = v
+            s = s * s_ref[0, 0]
+        s = s / jnp.sqrt(f32(dh))
+        k_pos = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0)
+        s = jnp.where(k_pos <= pos, s, -1e30)
 
-        m_prev = m_ref[:, :1]                # (HB, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)               # (HB, ps) f32
+        m_prev = m_ref[...]                  # (1, HB)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)               # (ps, HB) f32
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[:, :1] = l_ref[:, :1] * alpha + \
-            jnp.sum(p, axis=-1, keepdims=True)
+        l_ref[...] = l_ref[...] * alpha + \
+            jnp.sum(p, axis=0, keepdims=True)
         if int8:
-            # v scale folds into the softmax weights before the V dot
-            p = p * s_ref[0][1].T
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(cdt), v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)  # (HB, dh)
-        m_ref[:, :1] = m_new
+            # v scale folds into the softmax weights before the V sum
+            p = p * s_ref[0, 1]
+        p = p.astype(cdt).astype(f32)
+        acc_ref[...] = acc_ref[...] * alpha.T + \
+            jnp.sum(p[:, :, None] * v, axis=0)          # (HB, dh)
+        m_ref[...] = m_new
 
     @pl.when(j == nj - 1)
     def _out():
-        o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[...].T).astype(o_ref.dtype)
 
 
 # bounded cache of built pallas_call closures, keyed on every
@@ -170,13 +166,16 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
     if fn is not None:
         return fn
 
-    # head blocking (round 22): walk the heads axis in VREG-shaped
-    # blocks — 8 heads (the f32 sublane count) when H divides, the
-    # whole axis otherwise (small-model/test shapes).  Keeps the kv
-    # block's trailing (HB, 2*dh) tile at the 8×128 register shape
-    # and bounds per-step VMEM at HB·(ps·2dh + dh) instead of
-    # H·(ps·2dh + dh) however many heads this shard holds.
-    HB = 8 if H % 8 == 0 else H
+    # head blocking: walk the heads axis in blocks of 8 (the f32
+    # sublane count) when H divides, the whole axis otherwise
+    # (small-model/test shapes, and the H/tp slices 6 and 3 of the
+    # `full` preset).  Bounds per-step VMEM at HB·(ps·2dh + dh)
+    # instead of H·(ps·2dh + dh) however many heads this shard holds.
+    # An int8 pool walks whole heads: its scale planes carry heads on
+    # the LANE axis, and Mosaic takes a lane block only if it is
+    # 128-divisible or the whole axis — an 8-head slice of 16 is
+    # neither.
+    HB = 8 if H % 8 == 0 and not int8 else H
     NH = H // HB
 
     def page_map(t, h, j, bt, pos):
@@ -186,16 +185,15 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
         pl.BlockSpec((1, HB, dh), lambda t, h, j, bt, pos: (t, h, 0)),
         pl.BlockSpec((1, page_size, HB, 2 * dh), page_map),
     ]
-    scratch = [pltpu.VMEM((HB, 1), jnp.float32),
-               pltpu.VMEM((HB, 1), jnp.float32),
+    scratch = [pltpu.VMEM((1, HB), jnp.float32),
+               pltpu.VMEM((1, HB), jnp.float32),
                pltpu.VMEM((HB, dh), jnp.float32)]
     if int8:
-        # retiled scale block: (2, ps, HB) — two (ps, heads) planes
-        # indexed by the SAME page map, heads axis last (aligned
-        # lanes; paged_kv.py module docstring)
+        # scale block: (2, ps, H) — two (ps, heads) planes indexed by
+        # the SAME page map, the whole heads axis last (HB == H here)
         in_specs.append(pl.BlockSpec(
             (1, 2, page_size, HB),
-            lambda t, h, j, bt, pos: (bt[t * PP + j], 0, 0, h)))
+            lambda t, h, j, bt, pos: (bt[t * PP + j], 0, 0, 0)))
     body = functools.partial(_kernel, page_size=page_size, dh=dh,
                              int8=int8)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -247,13 +245,15 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
         outside — so the lowering adds no communication.  ``None``
         (or a trivial tp=1 mesh) is the single-device path.
 
-    Returns (T, H, dh) f32.  ``interpret=None`` auto-selects
-    interpreter mode off-TPU (the tier-1 CPU path).
+    Returns (T, H, dh) f32.  ``interpret=None`` interprets where the
+    call runs on the CPU backend (the tier-1 path) and compiles with
+    Mosaic on the chip.
     """
+    import jax
     import jax.numpy as jnp
 
-    if interpret is None:
-        interpret = _use_interpret()
+    from .platform import run_kernel
+
     T, H, dh = q.shape
     num_pages = pool_kv.shape[0]
     PP = block_tables.shape[1]
@@ -261,42 +261,38 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
         raise ValueError("paged_attention: pool page_size %d != %d"
                          % (pool_kv.shape[1], page_size))
     int8 = pool_s is not None
-    bt = block_tables.reshape(-1).astype(jnp.int32)
-    pos = row_pos.astype(jnp.int32)
+    args = [block_tables.reshape(-1).astype(jnp.int32),
+            row_pos.astype(jnp.int32), q, pool_kv]
+    if int8:
+        args.append(pool_s)
 
     tp_axis = None
     if mesh is not None:
         from ..parallel.mesh import live_axis
         tp_axis = live_axis(mesh, "tp")
-    if tp_axis is None:
-        fn = _build(T, H, dh, PP, page_size, num_pages, pool_kv.dtype,
-                    q.dtype, int8, bool(interpret))
-        if int8:
-            return fn(bt, pos, q, pool_kv, pool_s)
-        return fn(bt, pos, q, pool_kv)
-
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import shard_map_compat
-
-    tp = int(mesh.shape["tp"])
+    tp = int(mesh.shape["tp"]) if tp_axis else 1
     if H % tp:
         raise ValueError("paged_attention: H=%d not divisible by "
                          "tp=%d" % (H, tp))
-    fn = _build(T, H // tp, dh, PP, page_size, num_pages,
-                pool_kv.dtype, q.dtype, int8, bool(interpret))
-    in_specs = [P(), P(), P(None, "tp", None),
-                P(None, None, "tp", None)]
-    args = [bt, pos, q, pool_kv]
-    if int8:
-        in_specs.append(P(None, None, None, "tp"))
-        args.append(pool_s)
-    # check_vma off: the pallas_call's output carries no replication
-    # info for the checker to verify — the out spec is the contract
-    sm = shard_map_compat(fn, mesh=mesh, in_specs=tuple(in_specs),
-                          out_specs=P(None, "tp", None),
-                          check_vma=False)
-    return sm(*args)
+
+    def call(interp):
+        fn = _build(T, H // tp, dh, PP, page_size, num_pages,
+                    pool_kv.dtype, q.dtype, int8, interp)
+        if tp_axis is None:
+            return fn
+        from jax.sharding import PartitionSpec as P
+        in_specs = [P(), P(), P(None, "tp", None),
+                    P(None, None, "tp", None)]
+        if int8:
+            in_specs.append(P(None, None, None, "tp"))
+        # check_vma off: the pallas_call's output carries no
+        # replication info for the checker to verify — the out spec is
+        # the contract
+        return jax.shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
+                             out_specs=P(None, "tp", None),
+                             check_vma=False)
+
+    return run_kernel(call, *args, interpret=interpret)
 
 
 def paged_attention_reference(q, pool_kv, pool_s, block_tables,

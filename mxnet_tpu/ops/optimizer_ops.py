@@ -229,14 +229,15 @@ def lamb_update_phase2(weight, g_update, r1, r2, lr=0.01,
 # grouped multi-tensor updates (one dispatch, many params)
 # ---------------------------------------------------------------------------
 
-def _concrete_rates(lrs, wds):
-    """True when per-tensor rates are host numbers.  Array-valued rates
-    (the preloaded_* ops — LARS recomputes them on device every step)
-    must stay on the traced per-tensor path: the fused kernel bakes
-    rates in as floats, which would force a host sync per step eagerly
-    and break under jit."""
+def _scalar_rates(lrs, wds):
+    """True when every per-tensor rate is a scalar the fused kernel can
+    broadcast: a host number, or a 0-d device value — what the
+    eager-jit path turns ``lrs`` into (``registry._DYN_ATTR_NAMES``)
+    and what iterating the preloaded_* ops' rate vectors yields.  The
+    kernel takes them traced, so neither forces a host sync."""
     import numbers
     return all(isinstance(v, numbers.Number)
+               or getattr(v, "shape", None) == ()
                for seq in (lrs, wds) for v in list(seq))
 
 
@@ -256,7 +257,7 @@ def multi_sgd_update(data, lrs=None, wds=None, rescale_grad=1.0,
                      clip_gradient=-1.0, num_weights=1, **kw):
     ws = [data[2 * i] for i in range(num_weights)]
     if num_weights > 1 and _use_fused_group(data) \
-            and _concrete_rates(lrs, wds):
+            and _scalar_rates(lrs, wds):
         from ..kernels.fused_optimizer import fused_multi_sgd
         gs = [data[2 * i + 1] for i in range(num_weights)]
         outs, _ = fused_multi_sgd(ws, gs, lrs=lrs, wds=wds,
@@ -280,7 +281,7 @@ def multi_sgd_mom_update(data, lrs=None, wds=None, momentum=0.0,
                          num_weights=1, **kw):
     ws = [data[3 * i] for i in range(num_weights)]
     if num_weights > 1 and _use_fused_group(data) \
-            and _concrete_rates(lrs, wds):
+            and _scalar_rates(lrs, wds):
         from ..kernels.fused_optimizer import fused_multi_sgd
         gs = [data[3 * i + 1] for i in range(num_weights)]
         ms = [data[3 * i + 2] for i in range(num_weights)]

@@ -127,12 +127,41 @@ def _compose(spec, dim, axis, ndim):
     return P(*entries)
 
 
-def fsdp_param_specs(cfg, dp: str = "dp", tp: Optional[str] = None):
+def _fitting_dim(spec, shape, dim, axis, sizes):
+    """The dim of a ``shape``-d param that ``axis`` can shard under
+    mesh axis ``sizes``: the rule table's ``dim`` if its extent divides
+    by everything that would partition it, else the largest other dim
+    that does, else None (the leaf stays replicated over ``axis``).
+    BERT-base's vocabulary, 30522 = 2·3·5087, is the case: at dp=4
+    ``tok_emb`` shards its hidden dim instead and the 30522-long
+    ``mlm_bias`` replicates (first four-chip run, PR 21)."""
+    entries = list(spec) if spec is not None else []
+    entries = entries[:len(shape)] + [None] * (len(shape) - len(entries))
+
+    def fits(d):
+        cur = entries[d]
+        axes = () if cur is None else \
+            cur if isinstance(cur, tuple) else (cur,)
+        parts = sizes[axis]
+        for a in axes:
+            parts *= sizes[a]
+        return shape[d] % parts == 0
+
+    others = sorted((d for d in range(len(shape)) if d != dim),
+                    key=lambda d: -shape[d])
+    return next((d for d in [dim] + others if fits(d)), None)
+
+
+def fsdp_param_specs(cfg, dp: str = "dp", tp: Optional[str] = None,
+                     sizes: Optional[Dict[str, int]] = None):
     """Mesh-free FSDP ``PartitionSpec`` pytree for a transformer
     config: the megatron table (``param_specs`` — the SAME table
     tensor-parallel serving binds) with ``dp`` composed onto the dim
     the rule table names.  ``tp=None`` drops the tensor axis (a pure
-    dp mesh)."""
+    dp mesh).  With ``sizes`` (mesh axis name -> size, what
+    ``fsdp_param_shardings`` passes) the choice is shape-aware: a
+    param whose named dim does not divide moves ``dp`` to a dim that
+    does, or stays replicated (``_fitting_dim``)."""
     import jax
     from jax.sharding import PartitionSpec as P
     from ..models import transformer as T
@@ -153,10 +182,13 @@ def fsdp_param_specs(cfg, dp: str = "dp", tp: Optional[str] = None):
     paths = [p for p, _ in _tree_paths(shapes)]
     shape_leaves = [l for _, l in _tree_paths(shapes)]
     assert len(paths) == len(leaves)
-    out = [
-        _compose(spec, triples[path], dp, len(leaf.shape))
-        for path, leaf, spec in zip(paths, shape_leaves, leaves)
-    ]
+    out = []
+    for path, leaf, spec in zip(paths, shape_leaves, leaves):
+        dim = triples[path]
+        if sizes is not None:
+            dim = _fitting_dim(spec, leaf.shape, dim, dp, sizes)
+        out.append(spec if dim is None
+                   else _compose(spec, dim, dp, len(leaf.shape)))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -171,7 +203,8 @@ def fsdp_param_shardings(cfg, mesh, dp: str = "dp"):
         raise MXNetError(
             "fsdp needs a live %r mesh axis (size > 1); mesh has %s"
             % (dp, dict(mesh.shape)))
-    specs = fsdp_param_specs(cfg, dp=dp, tp=live_axis(mesh, "tp"))
+    specs = fsdp_param_specs(cfg, dp=dp, tp=live_axis(mesh, "tp"),
+                             sizes=dict(mesh.shape))
     return jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), specs,
         is_leaf=lambda x: isinstance(x, P))

@@ -155,8 +155,7 @@ def pipeline_apply(stage_fn, stacked_params, x, aux=None, *, mesh,
         # aux sums across stages.
         return out_acc[None], aux_acc[None]
 
-    from .mesh import shard_map_compat
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis), P(), P()),
         out_specs=(P(axis), P(axis)),

@@ -122,11 +122,10 @@ def _wrap(fn_shard, q, k, v, mask, mesh, seq_axis, causal):
     mspec = P(batch_axis, seq_axis)
     fn = functools.partial(fn_shard, axis_name=seq_axis, causal=causal,
                            sm_scale=sm_scale)
-    from .mesh import shard_map_compat
-    return shard_map_compat(fn, mesh=mesh,
-                            in_specs=(qspec, qspec, qspec, mspec),
-                            out_specs=qspec,
-                            check_vma=False)(q, k, v, mask)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(qspec, qspec, qspec, mspec),
+                         out_specs=qspec,
+                         check_vma=False)(q, k, v, mask)
 
 
 def ring_attention(q, k, v, mask=None, *, mesh, seq_axis="sp",

@@ -64,14 +64,18 @@ class PallasKernel:
         else:
             shape, dtype = out_shape
             out = jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
-        if interpret is None:
-            # interpret mode keeps kernels runnable on CPU (tests /
-            # debugging); on TPU run the compiled path.
-            interpret = jax.default_backend() != "tpu"
-        kw = dict(out_shape=out, interpret=interpret, **pallas_kw)
+        kw = dict(out_shape=out, **pallas_kw)
         if grid is not None:
             kw["grid"] = grid
-        result = pl.pallas_call(self._fn, **kw)(*unwrapped)
+
+        def call(interp):
+            return pl.pallas_call(self._fn, interpret=interp, **kw)
+
+        # interpret mode keeps kernels runnable where the inputs live
+        # on the CPU backend (tests / debugging); Mosaic compiles them
+        # on the chip
+        from .kernels.platform import run_kernel
+        result = run_kernel(call, *unwrapped, interpret=interpret)
         if want_nd:
             from . import ndarray as nd
             return nd.array(result)

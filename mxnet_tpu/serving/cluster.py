@@ -294,8 +294,11 @@ class ServingCluster:
     config is captured ONCE (``_engine_kwargs``) so a failover
     resubmission always lands on a survivor with identical tp/mesh
     setup (``tests/test_serving_tp.py`` pins failover-under-tp).
-    On one host the replicas time-share the same tp devices — the
-    scale-out story across hosts is ROADMAP item 3.
+    On one host tp>1 replicas time-share the same tp devices.  tp=1
+    replicas spread over the host's chips: replica i's params and KV
+    pools are committed to local device i mod n (``_replica_device``),
+    so one process drives n chips with n replicas instead of stacking
+    them all on the default device.
     """
 
     def __init__(self, params, cfg, *, replicas=2, num_slots,
@@ -403,18 +406,22 @@ class ServingCluster:
             collections.deque()
         self.replicas: List[_Replica] = []
         for i in range(replicas):
-            eng = ServingEngine(params, cfg, rid_start=i * RID_BLOCK,
-                                **self._engine_kwargs)
-            self.replicas.append(_Replica(i, eng))
+            self.replicas.append(_Replica(i, self._build_engine(i)))
         # submit()-side validation limits, captured once (replica 0's
         # engine may be released by a later scale-down)
         self._max_seq = self.replicas[0].engine.max_seq
-        # pre-warm the (shared) step program BEFORE workers and the
-        # watchdog start: a first-step compile longer than watchdog_s
-        # would otherwise read as a stall and cascade failovers across
-        # equally-cold survivors.  One compile covers every replica —
-        # the step cache keys on config, not engine.
-        self._warm_engine(self.replicas[0].engine)
+        # pre-warm the step program BEFORE workers and the watchdog
+        # start: a first-step compile longer than watchdog_s would
+        # otherwise read as a stall and cascade failovers across
+        # equally-cold survivors.  The jitted step is shared (its cache
+        # keys on config, not engine) but XLA compiles it once per
+        # device, so one warm-up per distinct replica device.
+        warmed = set()
+        for rep in self.replicas:
+            dev = self._replica_device(rep.idx)
+            if dev not in warmed:
+                warmed.add(dev)
+                self._warm_engine(rep.engine)
         for rep in self.replicas:
             rep.thread = threading.Thread(
                 target=self._worker, args=(rep,), daemon=True,
@@ -431,6 +438,26 @@ class ServingCluster:
         if self._obs is not None:
             with self._lock:
                 self._sync_gauges_locked()
+
+    def _replica_device(self, idx):
+        """Replica ``idx``'s chip: local device ``idx mod n`` for tp=1
+        replicas on a host with several devices; None (JAX's default
+        placement) for tp>1 replicas, which their mesh places, and on
+        a one-device host."""
+        import jax
+        kw = self._engine_kwargs
+        if kw["tp"] > 1 or kw["mesh"] is not None:
+            return None
+        devs = jax.local_devices()
+        return devs[idx % len(devs)] if len(devs) > 1 else None
+
+    def _build_engine(self, block):
+        """Engine for replica/rid-block ``block`` from the captured
+        config, on that replica's device."""
+        return ServingEngine(self._params, self._cfg,
+                             rid_start=block * RID_BLOCK,
+                             device=self._replica_device(block),
+                             **self._engine_kwargs)
 
     @staticmethod
     def _warm_engine(eng):
@@ -1081,9 +1108,7 @@ class ServingCluster:
                 raise ClusterClosed("add_replica() after close()")
             block = self._rid_blocks
             self._rid_blocks += 1
-        eng = ServingEngine(self._params, self._cfg,
-                            rid_start=block * RID_BLOCK,
-                            **self._engine_kwargs)
+        eng = self._build_engine(block)
         self._warm_engine(eng)
         with self._lock:
             idx = None if self._closed else len(self.replicas)
@@ -1354,18 +1379,28 @@ class _DisaggObs:
         self.trace = RequestTraceEmitter()
 
 
+def _why_not_ready(got):
+    """What the READY wait got instead: a worker that could not build
+    its engine says why in an ``error`` frame (e.g. a chip it could
+    not claim); one that died without a word leaves None."""
+    if got not in (None, "timeout") and got[0] == "error":
+        return got[1].get("msg")
+    return repr(got)
+
+
 class _WorkerHandle:
     """Router-side record of one worker process."""
     __slots__ = ("name", "role", "proc", "conn", "data_host",
                  "data_port", "last_seen", "dead", "draining",
                  "outstanding", "stats", "stats_evt", "stats_sid",
                  "error", "recv_thread", "pid", "clock_offset",
-                 "clock_rtt", "flight_tail")
+                 "clock_rtt", "flight_tail", "chip")
 
     def __init__(self, name, role):
         self.name = name
         self.role = role
         self.proc = None
+        self.chip = None                  # TPU chip a spawned worker owns
         self.pid = None                   # from hello (put-segment sweep)
         self.conn = None
         self.data_host = None
@@ -1569,17 +1604,10 @@ class DisaggServingCluster:
         self._listener.start(self._pending_conns.put)
         host_params = jax.device_get(params)
         self._params_frames = tree_to_frames(host_params)
-        if spawn:
-            import multiprocessing as mp
-            ctx = mp.get_context("spawn")
-            for name, wh in self.workers.items():
-                wh.proc = ctx.Process(
-                    target=_disagg_worker_entry,
-                    args=(name, wh.role, self._listener.host,
-                          self._listener.port),
-                    daemon=True, name="serving-" + name)
-                wh.proc.start()
         try:
+            if spawn:
+                for wh in self.workers.values():
+                    self._spawn_worker(wh)
             self._handshake_all(ready_timeout)
         except BaseException:
             # a failed construction must not strand live worker
@@ -1599,6 +1627,63 @@ class DisaggServingCluster:
             target=self._monitor_loop, daemon=True,
             name="disagg-monitor")
         self._monitor.start()
+
+    # ------------------------------------------------------- spawn ---
+    def _spawn_worker(self, wh):
+        """Start ``wh``'s worker process.  Where the workers will run
+        on TPU chips (this host has some and ``JAX_PLATFORMS`` lets the
+        children use them) each gets exactly one — the lowest not owned
+        by a live spawned worker — through its environment
+        (``context.one_chip_env``), and this process must hold none: it
+        only ships frames, so its params can stay host-side."""
+        import multiprocessing as mp
+        from ..context import (held_accelerator, host_tpu_chips,
+                               one_chip_env)
+        plats = os.environ.get("JAX_PLATFORMS", "")
+        chips = host_tpu_chips() \
+            if not plats or "tpu" in plats.split(",") else 0
+        env = {}
+        if chips:
+            held = held_accelerator()
+            if held is not None:
+                raise RuntimeError(
+                    "DisaggServingCluster(spawn=True): this process "
+                    "has initialised the %r backend and holds the "
+                    "host's chips, so a spawned worker cannot claim "
+                    "one (it would die with 'The TPU is already in use "
+                    "by process with pid %d').  Keep the router on the "
+                    "CPU backend — jax.config.update('jax_platforms', "
+                    "'cpu') before anything touches JAX; it only ships "
+                    "host-side params — or start the workers yourself "
+                    "(spawn=False, tools/launch.py --launcher serve)."
+                    % (held, os.getpid()))
+            owned = {w.chip for w in self.workers.values()
+                     if w.chip is not None and w.proc is not None
+                     and w.proc.is_alive()}
+            free = [c for c in range(chips) if c not in owned]
+            if not free:
+                raise RuntimeError(
+                    "DisaggServingCluster(spawn=True): worker %s needs "
+                    "a chip of its own and all %d on this host are "
+                    "owned by live workers" % (wh.name, chips))
+            wh.chip = free[0]
+            env = one_chip_env(wh.chip)
+        wh.proc = mp.get_context("spawn").Process(
+            target=_disagg_worker_entry,
+            args=(wh.name, wh.role, self._listener.host,
+                  self._listener.port),
+            daemon=True, name="serving-" + wh.name)
+        # a spawned child inherits os.environ as it is at start()
+        saved = {k: os.environ.get(k) for k in env}
+        os.environ.update(env)
+        try:
+            wh.proc.start()
+        finally:
+            for k, v in saved.items():
+                if v is None:
+                    os.environ.pop(k, None)
+                else:
+                    os.environ[k] = v
 
     # --------------------------------------------------- handshake ---
     def _handshake_all(self, timeout):
@@ -1640,7 +1725,7 @@ class DisaggServingCluster:
             if got in (None, "timeout") or got[0] != "ready":
                 raise RuntimeError(
                     "DisaggServingCluster: worker %s failed to build "
-                    "its engine (%r)" % (name, got))
+                    "its engine: %s" % (name, _why_not_ready(got)))
             _, meta, _ = got
             wh.data_host = meta["data_host"]
             wh.data_port = meta["data_port"]
@@ -2511,8 +2596,8 @@ class DisaggServingCluster:
             1.0, deadline - time.perf_counter()))
         if got in (None, "timeout") or got[0] != "ready":
             raise RuntimeError(
-                "add_worker: worker %s failed to build its engine "
-                "(%r)" % (wh.name, got))
+                "add_worker: worker %s failed to build its engine: "
+                "%s" % (wh.name, _why_not_ready(got)))
         _, meta, _ = got
         wh.data_host = meta["data_host"]
         wh.data_port = meta["data_port"]
@@ -2564,16 +2649,9 @@ class DisaggServingCluster:
             self.workers[name] = wh
         if spawn is None:
             spawn = self._spawn
-        if spawn:
-            import multiprocessing as mp
-            ctx = mp.get_context("spawn")
-            wh.proc = ctx.Process(
-                target=_disagg_worker_entry,
-                args=(name, role, self._listener.host,
-                      self._listener.port),
-                daemon=True, name="serving-" + name)
-            wh.proc.start()
         try:
+            if spawn:
+                self._spawn_worker(wh)
             self._handshake_one(wh, ready_timeout)
         except BaseException:
             with self._lock:
@@ -2822,7 +2900,18 @@ class _DisaggWorker:
             kw.update(prefix_cache=True, spec_K=0)
         else:
             kw.update(prefix_cache=False)
-        self.eng = ServingEngine(params, self.cfg, **kw)
+        try:
+            self.eng = ServingEngine(params, self.cfg, **kw)
+        except Exception as e:
+            # the first touch of the device: tell the router why (a
+            # chip this process could not claim, a pool that does not
+            # fit) — it is in its READY wait and fails construction
+            # with this reason instead of a bare EOF
+            try:
+                self.router.send("error", {"msg": repr(e)})
+            except OSError:
+                pass
+            raise
         # pre-warm the compiled step BEFORE reporting ready: the
         # handshake timeout covers the compile, so the router's
         # watchdog never mistakes a first-request compile for a stall

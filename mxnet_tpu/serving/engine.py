@@ -889,6 +889,10 @@ class ServingEngine:
         slice — speculation (``spec_K``) composes with both.
     mesh : optional pre-built mesh with a ``tp`` axis (e.g.
         ``parallel.serving_mesh(tp)``); overrides ``tp``.
+    device : the one chip a ``tp=1`` engine lives on — params and KV
+        pools are committed to it, so the step program runs there.
+        None (the default) leaves placement to JAX: the default
+        device.  ``ServingCluster`` passes replica i device i mod n.
     tier_bytes : host-DRAM KV tier budget in bytes (round 18).  > 0
         attaches a ``HostTierStore``: pressure-evicted refcount-0
         prefix chains spill to it (and re-install as warm hits),
@@ -914,7 +918,7 @@ class ServingEngine:
                  kv_int8=False, prefix_cache=False, metrics=None,
                  registry=None, rid_start=0, kernel="xla", spec_K=0,
                  spec_drafter="ngram", spec_ngram=2, tp=1, mesh=None,
-                 tier_bytes=None, overlap=None):
+                 tier_bytes=None, overlap=None, device=None):
         if not cfg.causal:
             cfg = dataclasses.replace(cfg, causal=True)
         if num_slots < 1:
@@ -942,6 +946,10 @@ class ServingEngine:
             tp = int(mesh.shape["tp"])
         if tp < 1:
             raise ValueError("ServingEngine: tp must be >= 1")
+        if device is not None and tp > 1:
+            raise ValueError("ServingEngine: device= places a tp=1 "
+                             "engine; a tp>1 engine is placed by its "
+                             "mesh")
         if tp > 1:
             # capability check (round 22): the Pallas walk is mesh-
             # lowered — any kernel serves tp>1 provided the heads
@@ -992,6 +1000,14 @@ class ServingEngine:
             params = jax.device_put(
                 params, _bind(self.mesh,
                               G.decode_param_specs(params, cfg)))
+        else:
+            # one placement, here: host leaves (a disaggregated
+            # worker's params come off the wire as numpy) would
+            # otherwise ride to the device again on every step.
+            # Leaves already on a device stay where they are unless
+            # ``device`` names another.
+            import jax
+            params = jax.device_put(params, device)
         self.params = params
         self.cfg = cfg
         self.num_slots = num_slots
@@ -1010,7 +1026,7 @@ class ServingEngine:
         self.n_rows = num_slots * (1 + self.spec_K) + prefill_chunk
         self.cache = PagedKVCache(cfg, num_pages, page_size,
                                   kv_int8=self.kv_int8,
-                                  mesh=self.mesh)
+                                  mesh=self.mesh, device=device)
         # host-DRAM KV tier (round 18): explicit argument >
         # MXNET_SERVE_TIER_BYTES env > off.  0/None disables — every
         # pre-tier behavior (drop on pressure, recompute on resume)

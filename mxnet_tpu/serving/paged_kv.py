@@ -181,7 +181,8 @@ class PagedKVCache:
     S_POOL_SPEC = (None, None, None, "tp")
 
     def __init__(self, cfg, num_pages, page_size, kv_int8=False,
-                 mesh=None):
+                 mesh=None, device=None):
+        import jax
         import jax.numpy as jnp
 
         if num_pages < 2:
@@ -199,8 +200,12 @@ class PagedKVCache:
         dh = cfg.d_model // H
         cdt = jnp.dtype(cfg.dtype)
         place = lambda x, spec=None: x       # noqa: E731
+        if device is not None:
+            # commit the pools to one chip (a cluster replica's): every
+            # step output and every eager page write then stays there
+            place = lambda x, spec=None: jax.device_put(  # noqa: E731
+                x, device)
         if mesh is not None:
-            import jax
             from jax.sharding import NamedSharding, PartitionSpec as P
             if "tp" not in mesh.axis_names:
                 raise ValueError("PagedKVCache: mesh has no 'tp' axis")

@@ -144,12 +144,12 @@ def check_numeric_gradient(fn, inputs, eps=1e-4, rtol=1e-2, atol=1e-4,
     ``fn`` maps NDArrays → a single NDArray; its sum is used as the scalar
     objective.  ``inputs`` are numpy arrays (float64 recommended).
 
-    Runs under ``jax.experimental.enable_x64`` so the finite differences are
+    Runs under ``jax.enable_x64`` so the finite differences are
     true float64 — without it XLA silently downcasts and the central
     difference loses half its digits.
     """
-    from jax.experimental import enable_x64
-    with enable_x64(True):
+    import jax
+    with jax.enable_x64(True):
         return _check_numeric_gradient_x64(fn, inputs, eps, rtol, atol,
                                            dtype)
 

@@ -88,8 +88,8 @@ def _gen(shapes, positive):
 def test_forward_dtype_matrix(name, fn, shapes, positive, dtype):
     """fwd(x.astype(dt)) ≈ fwd(x) within the dtype's tolerance."""
     if dtype == "float64":
-        from jax.experimental import enable_x64
-        ctx = enable_x64(True)
+        import jax
+        ctx = jax.enable_x64(True)
     else:
         import contextlib
         ctx = contextlib.nullcontext()

@@ -1,0 +1,119 @@
+"""The three Pallas kernels must COMPILE for the real chip, not only
+run in the interpreter.
+
+libtpu ships a compile-only v5e client that needs no chip:
+``jax.experimental.topologies.get_topology_desc("tpu", "v5e:2x2")``
+gives TPU devices a CPU-only process can ``jit(...).lower(...).compile()``
+against, which runs the real Pallas → Mosaic → TPU compile.  Each case
+asserts the compiled program holds a ``tpu_custom_call`` — i.e. the
+kernel was lowered by Mosaic, not routed to a jnp fallback or the
+interpreter.  Numerics are the interpreter tests' and the chip's job
+(``tests/test_paged_attention.py``, ``chip_smoke.py``).
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    # libtpu is part of the installation: no skip if this fails
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+def _compile(fn, device, *shapes):
+    s = jax.sharding.SingleDeviceSharding(device)
+    text = jax.jit(fn, in_shardings=s, out_shardings=s) \
+        .lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+
+
+# the serve_bench `full` preset's decode geometry: 32 step rows, 12
+# heads of 64, 16-token pages, 32 pages a row, a (353, 16, 12, 128) pool
+_FULL = dict(T=32, H=12, dh=64, ps=16, PP=32, NP=353)
+
+
+def _paged_shapes(T, H, dh, ps, PP, NP, kv_dtype, q_dtype):
+    int8 = jnp.dtype(kv_dtype) == jnp.int8
+    return (_sds((T, H, dh), q_dtype),
+            _sds((NP, ps, H, 2 * dh), kv_dtype),
+            _sds((NP, 2, ps, H), "float32") if int8 else None,
+            _sds((T, PP), "int32"), _sds((T,), "int32"))
+
+
+@pytest.mark.parametrize("geom,kv_dtype,q_dtype", [
+    (_FULL, "bfloat16", "bfloat16"),
+    (_FULL, "int8", "bfloat16"),
+    (_FULL, "float32", "float32"),
+    # H/tp head slices of the full preset at tp=2 / tp=4 (what each
+    # device of the mesh lowering walks)
+    (dict(_FULL, H=6), "bfloat16", "bfloat16"),
+    (dict(_FULL, H=3), "int8", "bfloat16"),
+    # H % 8 == 0, H > 8: the head-blocked walk
+    (dict(_FULL, H=16, dh=128), "bfloat16", "bfloat16"),
+    (dict(_FULL, H=32, dh=128), "int8", "bfloat16"),
+], ids=["full-bf16", "full-int8", "full-f32", "tp2-bf16", "tp4-int8",
+        "h16-bf16", "h32-int8"])
+def test_paged_attention_compiles(v5e, geom, kv_dtype, q_dtype):
+    from mxnet_tpu.kernels.paged_attention import paged_attention
+    q, kv, sc, bt, pos = _paged_shapes(kv_dtype=kv_dtype,
+                                       q_dtype=q_dtype, **geom)
+    ps = geom["ps"]
+    if sc is None:
+        _compile(lambda q, kv, bt, pos: paged_attention(
+            q, kv, None, bt, pos, page_size=ps),
+            v5e[0], q, kv, bt, pos)
+    else:
+        _compile(lambda q, kv, sc, bt, pos: paged_attention(
+            q, kv, sc, bt, pos, page_size=ps),
+            v5e[0], q, kv, sc, bt, pos)
+
+
+def test_flash_fwd_bwd_compiles(v5e):
+    from mxnet_tpu.kernels import flash_attention as fa
+    qkv = _sds((1, 4096, 12, 64), "bfloat16")
+
+    def loss(q, k, v, seed):
+        out = fa.flash_attention(q, k, v, causal=True, dropout=0.1,
+                                 dropout_seed=seed)
+        return jnp.sum(out.astype(jnp.float32))
+
+    # flash_attention routes at trace time by where jit will run
+    with jax.default_device(v5e[0]):
+        text = _compile(jax.grad(loss, argnums=(0, 1, 2)), v5e[0],
+                        qkv, qkv, qkv, _sds((), "int32"))
+    # forward, dq and dk/dv kernels
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_flash_rejects_sequences_past_vmem_limit(v5e):
+    from mxnet_tpu.kernels import flash_attention as fa
+    T = fa.MAX_KV_BLOCK_BYTES // (128 * 2) * 2
+    q = _sds((1, T, 1, 128), "bfloat16")
+    with jax.default_device(v5e[0]), \
+            pytest.raises(ValueError, match="VMEM"):
+        jax.eval_shape(lambda q: fa.flash_attention(q, q, q), q)
+
+
+@pytest.mark.parametrize("momentum", [0.0, 0.9])
+def test_fused_multi_sgd_compiles(v5e, momentum):
+    from mxnet_tpu.kernels.fused_optimizer import fused_multi_sgd
+    shapes = [(64, 3, 7, 7), (64,), (256, 64, 1, 1), (1000, 2048)]
+    ws = [_sds(s, "float32") for s in shapes]
+    n = len(ws)
+
+    def step(ws, gs, ms):
+        return fused_multi_sgd(ws, gs, ms if momentum else None,
+                               lrs=[0.1] * n, wds=[1e-4] * n,
+                               momentum=momentum)
+
+    _compile(step, v5e[0], ws, ws, ws)

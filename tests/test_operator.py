@@ -303,7 +303,6 @@ def test_sequence_ops():
 @pytest.mark.slow
 def test_fused_multi_sgd_matches_loop():
     """Pallas grouped optimizer kernel == per-tensor sgd_update loop."""
-    import os
     import numpy as np
     from mxnet_tpu import nd
 
@@ -315,23 +314,34 @@ def test_fused_multi_sgd_matches_loop():
     lrs = [0.1, 0.2, 0.05, 0.3]
     wds = [0.0, 0.01, 0.1, 0.0]
 
-    def run(fused):
-        os.environ["MXNET_FUSED_OPTIMIZER"] = "1" if fused else "0"
-        try:
-            data = []
-            moms = [m.copy() for m in ms]
-            for w, g, m in zip(ws, gs, moms):
-                data.extend([w.copy(), g, m])
-            outs = nd.multi_sgd_mom_update(
-                *data, lrs=lrs, wds=wds, momentum=0.9,
-                rescale_grad=0.5, clip_gradient=1.0, num_weights=4)
-            return ([o.asnumpy() for o in outs[:4]],
-                    [m.asnumpy() for m in moms])
-        finally:
-            os.environ["MXNET_FUSED_OPTIMIZER"] = "1"
-
-    outs_f, moms_f = run(True)
-    outs_r, moms_r = run(False)
+    # the eager-jit cache does not key on MXNET_FUSED_OPTIMIZER, so the
+    # reference is the per-tensor op itself, not the same op re-run
+    # with the variable flipped
+    from mxnet_tpu.kernels import fused_optimizer
+    calls = []
+    orig = fused_optimizer.fused_multi_sgd
+    fused_optimizer.fused_multi_sgd = \
+        lambda *a, **k: calls.append(1) or orig(*a, **k)
+    try:
+        data = []
+        moms_f = [m.copy() for m in ms]
+        for w, g, m in zip(ws, gs, moms_f):
+            data.extend([w.copy(), g, m])
+        outs = nd.multi_sgd_mom_update(
+            *data, lrs=lrs, wds=wds, momentum=0.9,
+            rescale_grad=0.5, clip_gradient=1.0, num_weights=4)
+    finally:
+        fused_optimizer.fused_multi_sgd = orig
+    assert calls, "multi_sgd_mom_update did not take the fused kernel"
+    outs_f = [o.asnumpy() for o in outs[:4]]
+    moms_f = [m.asnumpy() for m in moms_f]
+    outs_r, moms_r = [], []
+    for w, g, m, lr, wd in zip(ws, gs, ms, lrs, wds):
+        m = m.copy()
+        outs_r.append(nd.sgd_mom_update(
+            w.copy(), g, m, lr=lr, wd=wd, momentum=0.9,
+            rescale_grad=0.5, clip_gradient=1.0).asnumpy())
+        moms_r.append(m.asnumpy())
     for a, b in zip(outs_f, outs_r):
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
     for a, b in zip(moms_f, moms_r):

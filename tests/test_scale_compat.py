@@ -54,8 +54,7 @@ def test_index_widening_machinery():
     import jax
     import jax.numpy as jnp
     a = nd.zeros((4, 4))
-    from jax.experimental import enable_x64
-    with enable_x64(True):
+    with jax.enable_x64(True):
         k = a._widen_index_arrays((jnp.array([1, 2], jnp.int32),
                                    slice(None)))
         assert k[0].dtype == jnp.int64

@@ -468,6 +468,37 @@ def test_admit_prefilled_validation():
                             max_new_tokens=2)
 
 
+def test_spawn_on_a_tpu_host_is_one_chip_per_worker(monkeypatch):
+    """A chip belongs to one process at a time (PR 21, four-chip run):
+    ``spawn=True`` on a TPU host refuses at construction — with the
+    reason — when this process already holds the chips or when there
+    are fewer chips than workers, instead of workers that die at
+    backend init behind a bare handshake failure."""
+    from mxnet_tpu import context
+    from mxnet_tpu.serving import DisaggServingCluster
+    params, cfg = _tiny()
+    kw = dict(prefill=1, decode=1, num_slots=2, page_size=4,
+              prefill_chunk=4, spawn=True, ready_timeout=5)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+
+    monkeypatch.setattr(context, "host_tpu_chips", lambda: 4)
+    monkeypatch.setattr(context, "held_accelerator", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="holds the host's chips"):
+        DisaggServingCluster(params, cfg, **kw)
+
+    # parent off the chips, two workers, one chip: the first worker
+    # gets chip 0, the second is refused (and the first reaped)
+    monkeypatch.setattr(context, "host_tpu_chips", lambda: 1)
+    monkeypatch.setattr(context, "held_accelerator", lambda: None)
+    with pytest.raises(RuntimeError, match="owned by live workers"):
+        DisaggServingCluster(params, cfg, **kw)
+    assert "TPU_VISIBLE_CHIPS" not in os.environ   # parent env restored
+    assert context.one_chip_env(2) == {
+        "TPU_VISIBLE_CHIPS": "2",
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
 # ===========================================================================
 # SLOW tier (group j) — whole-process disaggregated clusters
 # ===========================================================================

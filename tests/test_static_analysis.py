@@ -1838,7 +1838,7 @@ class TestGraphFixtures:
             by_rule["graph-donation"].symbol
         assert "fix_f32_upcast" in by_rule["graph-dtype-drift"].symbol
         assert by_rule["graph-hbm-budget"].symbol == "fix_over_budget"
-        assert "debug_callback" in by_rule["graph-host-sync"].symbol
+        assert "debug_print" in by_rule["graph-host-sync"].symbol
 
     def test_dtype_finding_anchors_at_the_upcast_line(self, findings):
         f = [x for x in findings if x.rule == "graph-dtype-drift"][0]

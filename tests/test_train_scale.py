@@ -136,6 +136,33 @@ def test_fsdp_requires_live_dp_axis():
         fsdp_param_shardings(cfg, make_mesh({"tp": 8}))
 
 
+def test_fsdp_vocab_that_does_not_divide_dp():
+    """BERT-base's vocabulary (30522 = 2*3*5087) divides no dp of 4:
+    bound to a mesh, the rule table moves dp to a dim that does divide
+    or leaves the leaf replicated, and the step runs (first four-chip
+    run, PR 21: device_put refused PartitionSpec('dp',) on (30522,))."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+    from mxnet_tpu.models import transformer as T
+    from mxnet_tpu.parallel import make_mesh
+    from mxnet_tpu.parallel.fsdp import fsdp_param_shardings
+    cfg = _tiny_cfg(vocab_size=1022)
+    for axes, tok in (({"dp": 4}, P(None, "dp")),
+                      ({"dp": 2, "tp": 2}, P("dp", "tp"))):
+        mesh = make_mesh(axes, devices=jax.devices()[:4])
+        sh = fsdp_param_shardings(cfg, mesh)
+        assert sh["tok_emb"].spec == tok, (axes, sh["tok_emb"].spec)
+    # dp=4: 1022 % 4 != 0 -> the bias has no other dim: replicated
+    mesh = make_mesh({"dp": 4}, devices=jax.devices()[:4])
+    sh = fsdp_param_shardings(cfg, mesh)
+    assert sh["mlm_bias"].spec == P()
+    assert sh["layers"][0]["wq"].spec == P("dp", None)   # rule's own dim
+    init_state, step = T.make_train_step(cfg, mesh=mesh, fsdp=True)
+    state = init_state(jax.random.PRNGKey(0))
+    state, loss = step(state, _mlm_batch(cfg, B=8), jax.random.PRNGKey(1))
+    assert np.isfinite(float(loss))
+
+
 def test_bucket_overlap_validation():
     """Round 21: bucket_overlap is fenced to the configs where the
     homogeneous layer scan is sound — requires fsdp, refuses bogus

@@ -9,11 +9,9 @@ import sys
 # mutate process-global topology for hosts that never trace the tp
 # program (the tp spec builder raises a clear error if devices are
 # short at trace time).
-if "xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8").strip()
+import jax  # noqa: E402
+
+jax.config.update("jax_num_cpu_devices", 8)
 
 from .runner import main  # noqa: E402
 

@@ -294,7 +294,7 @@ def _registry_mesh():
             "%d-device mesh but only %d device(s) are visible — jax "
             "initialized before tools.analysis could request the "
             "virtual CPU mesh; run via `python -m tools.analysis` or "
-            "set XLA_FLAGS=--xla_force_host_platform_device_count=8"
+            "call jax.config.update('jax_num_cpu_devices', 8) first"
             % (_TP, len(jax.devices())))
     return serving_mesh(_TP)
 
@@ -476,8 +476,8 @@ def _train_mesh():
         raise RuntimeError(
             "graphlint: the bert_train_step_fsdp registry entries need "
             "a %d-device mesh but only %d device(s) are visible — run "
-            "via `python -m tools.analysis` or set XLA_FLAGS="
-            "--xla_force_host_platform_device_count=8"
+            "via `python -m tools.analysis` or call "
+            "jax.config.update('jax_num_cpu_devices', 8) first"
             % (_TRAIN_DP, len(jax.devices())))
     return make_mesh({"dp": _TRAIN_DP},
                      devices=list(jax.devices())[:_TRAIN_DP])
@@ -671,7 +671,7 @@ def _sub_jaxprs(eqn):
     """Yield nested (Closed)Jaxprs of an equation — pjit / scan /
     while / cond / remat / custom_* bodies; ``pallas_call`` is
     deliberately opaque (VMEM-scratch kernel internals)."""
-    from jax import core
+    from jax.extend import core
     if eqn.primitive.name in _SKIP_SUBJAXPR:
         return
     for v in eqn.params.values():
@@ -720,7 +720,9 @@ def peak_live_bytes(jaxpr) -> int:
     overlap, which XLA aliases away — a deliberate, deterministic
     overestimate.  The point is the trajectory, not the absolute
     number."""
-    from jax import core
+    from jax.extend import core
+    # jax.extend.core does not re-export DropVar
+    from jax._src.core import DropVar
     jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
     n = len(jaxpr.eqns)
     last: Dict[Any, int] = {}
@@ -745,7 +747,7 @@ def peak_live_bytes(jaxpr) -> int:
             inner = max(inner, max(0, peak_live_bytes(sub) - operand))
         alloc = 0
         for v in eqn.outvars:
-            if not isinstance(v, core.DropVar):
+            if not isinstance(v, DropVar):
                 alloc += _aval_bytes(v.aval)
         live += alloc
         peak = max(peak, live + inner)
@@ -757,7 +759,7 @@ def peak_live_bytes(jaxpr) -> int:
                 dead.add(v)
                 freed += _aval_bytes(v.aval)
         for v in eqn.outvars:
-            if not isinstance(v, core.DropVar) and v not in last:
+            if not isinstance(v, DropVar) and v not in last:
                 freed += _aval_bytes(v.aval)   # produced, never read
         live -= freed
     return peak

@@ -33,6 +33,37 @@ def _free_port():
     return port
 
 
+def _host_chips():
+    """(chip count, one_chip_env) from ``mxnet_tpu.context`` — which
+    initialises no JAX backend, so this launcher stays off the chips
+    and its children can claim them."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from mxnet_tpu.context import host_tpu_chips, one_chip_env
+    return host_tpu_chips(), one_chip_env
+
+
+def _chip_env(i, what):
+    """Environment for local child ``i`` that needs a TPU chip: exactly
+    chip ``i`` when this host has chips (a chip belongs to one process
+    at a time), nothing otherwise."""
+    chips, one_chip_env = _host_chips()
+    if not chips:
+        return {}
+    if i >= chips:
+        sys.stderr.write("launch: %s %d needs a chip of its own and "
+                         "this host has %d\n" % (what, i, chips))
+        sys.exit(2)
+    return one_chip_env(i)
+
+
+def _off_chip_env():
+    """Environment for a local child that must stay OFF the chips (a
+    parameter server, the serving router — they only move host bytes):
+    the CPU backend, where this host has chips to protect."""
+    return {"JAX_PLATFORMS": "cpu"} if _host_chips()[0] else {}
+
+
 def launch_local(args, command):
     port = args.port or _free_port()
     base_env = dict(os.environ)
@@ -48,6 +79,7 @@ def launch_local(args, command):
         env = dict(base_env)
         env["DMLC_ROLE"] = "server"
         env["DMLC_SERVER_ID"] = str(i)
+        env.update(_off_chip_env())
         procs.append(subprocess.Popen(
             [sys.executable, "-c",
              "from mxnet_tpu.parallel.dist import run_server; run_server()"],
@@ -57,6 +89,7 @@ def launch_local(args, command):
         env = dict(base_env)
         env["DMLC_ROLE"] = "worker"
         env["DMLC_WORKER_ID"] = str(i)
+        env.update(_chip_env(i, "worker"))
         procs.append(subprocess.Popen(command, env=env))
 
     workers = procs[args.num_servers:]
@@ -182,7 +215,8 @@ def launch_serve(args, command):
     processes connect to it exactly like locally-spawned ones, so
     the same protocol scales from this single-host topology to one
     worker per host (run ``run_worker()`` remotely with the env
-    pointing at the router)."""
+    pointing at the router).  On a TPU host each worker is given one
+    chip of its own and the router the CPU backend (``_chip_env``)."""
     if args.workers_only and not args.port:
         sys.stderr.write(
             "--workers-only: -p/--port must name the LIVE router's "
@@ -199,12 +233,15 @@ def launch_serve(args, command):
     })
     router = None
     if not args.workers_only:
-        router = subprocess.Popen(command, env=base_env)
+        router = subprocess.Popen(
+            command, env=dict(base_env, **_off_chip_env()))
     workers = []
     for role, n in (("prefill", args.prefill),
                     ("decode", args.decode)):
         for i in range(n):
             env = dict(base_env)
+            env.update(_chip_env(args.worker_start + len(workers),
+                                 "serve worker"))
             env["MXNET_SERVE_ROLE"] = role
             # --workers-only joins a LIVE cluster (round 16: the
             # autoscaler's off-host scale-up path — the router's
