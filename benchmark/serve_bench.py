@@ -309,7 +309,7 @@ def _bucket_width_at(v, bounds):
 
 def run_engine(params, cfg, p, workload, num_pages=None,
                page_size=None, closed_loop_k=None, metrics=False,
-               cross_check=True, kernel="xla", spec_K=0,
+               cross_check=True, kernel=None, spec_K=0,
                spec_drafter="ngram", overlap=None, tp=1):
     """Open-loop (Poisson ``workload``) or closed-loop (``k`` always in
     flight, workload gives the request shapes) engine run.
@@ -323,7 +323,9 @@ def run_engine(params, cfg, p, workload, num_pages=None,
     per-step observation work must not ride along on one side.
 
     ``kernel``/``spec_K`` (round 11) select the engine's attention
-    path and arm in-engine speculation; spec rows report the accept
+    path (None: the engine's own choice by its device — the Pallas
+    walk on a TPU, the XLA gather on CPU) and arm in-engine
+    speculation; spec rows report the accept
     rate and tokens/step alongside tok/s (the benchmark-definition
     note from round 6 applies: committed tokens per wall second moves
     with the accept rate as well as the step time)."""
@@ -438,7 +440,7 @@ def run_engine(params, cfg, p, workload, num_pages=None,
            "occupancy": eng.stats["slot_occupancy_sum"]
            / max(1, eng.stats["steps"]),
            "preemptions": eng.stats["preemptions"],
-           "steps": eng.stats["steps"], "kernel": kernel}
+           "steps": eng.stats["steps"], "kernel": eng.kernel}
     if eng.overlap:
         steps = max(1, eng.stats["steps"])
         out.update({
@@ -2239,12 +2241,13 @@ def main(argv=None):
                     help="alias for --preset quick")
     ap.add_argument("--sweep", action="store_true",
                     help="also run the occupancy + page-size sweeps")
-    ap.add_argument("--kernel", default="xla",
+    ap.add_argument("--kernel", default=None,
                     choices=("xla", "pallas"),
                     help="attention path for the e2e engine runs: the "
                          "block-table-gather XLA path or the fused "
                          "Pallas paged-attention kernel (interpreter "
-                         "mode off-TPU)")
+                         "mode off-TPU); default: the engine's own "
+                         "choice by its device")
     ap.add_argument("--spec-K", type=int, default=0, metavar="N",
                     help="arm in-engine speculative decode (N drafts "
                          "per decode row per step) on the e2e engine "

@@ -66,7 +66,12 @@ CHIP = dict(
                 scan_steps=10, lr=0.05),
     requests=_REQUESTS,
     bert=dict(factory="bert_base", seq=4096, batch=1, width={}),
-    paged=dict(T=32, H=12, dh=64, ps=16, PP=22, NP=353),
+    # the `full` preset's 12 heads (a bf16 or int8 pool of them runs
+    # the per-page grid, an f32 pool the walk), then 16 heads as the
+    # benchmark's serving cell has them: the walk over a bf16 pool,
+    # several page groups a row, the last row block short
+    paged=[dict(T=32, H=12, dh=64, ps=16, PP=22, NP=353),
+           dict(T=40, H=16, dh=64, ps=16, PP=32, NP=353)],
     fsdp=dict(factory="bert_base", seq=512, batch=8),
     cluster_requests=16,
 )
@@ -79,7 +84,8 @@ REHEARSE = dict(
     # head dim 64: the smallest the flash kernels tile
     bert=dict(factory="bert_tiny", seq=256, batch=1,
               width=dict(d_model=128, n_heads=2)),
-    paged=dict(T=6, H=4, dh=64, ps=8, PP=4, NP=17),
+    paged=[dict(T=6, H=4, dh=64, ps=8, PP=4, NP=17),
+           dict(T=19, H=8, dh=64, ps=8, PP=5, NP=17)],
     fsdp=dict(factory="bert_tiny", seq=64, batch=8),
     cluster_requests=8,
 )
@@ -370,39 +376,38 @@ def _kernel_paged(ctx):
     from mxnet_tpu.kernels.paged_attention import (
         paged_attention, paged_attention_reference)
 
-    g = ctx.sz["paged"]
-    T_, H, dh, ps, PP, NP = (g[k] for k in ("T", "H", "dh", "ps", "PP",
-                                            "NP"))
-    rng = np.random.RandomState(0)
-    bt = jnp.asarray(rng.randint(1, NP, (T_, PP)), jnp.int32)
-    pos = jnp.asarray(rng.randint(0, PP * ps, (T_,)), jnp.int32)
-    for kv_dtype in ("float32", "bfloat16", "int8"):
-        qdt = "float32" if kv_dtype == "float32" else "bfloat16"
-        q = jnp.asarray(rng.randn(T_, H, dh), qdt)
-        if kv_dtype == "int8":
-            kv = jnp.asarray(rng.randint(-127, 128, (NP, ps, H, 2 * dh)),
-                             jnp.int8)
-            sc = jnp.asarray(rng.uniform(0.005, 0.02, (NP, 2, ps, H)),
-                             jnp.float32)
-        else:
-            kv, sc = jnp.asarray(rng.randn(NP, ps, H, 2 * dh),
-                                 kv_dtype), None
-        kern = jax.jit(lambda *a: paged_attention(*a, page_size=ps))
-        compiled = kern.lower(q, kv, sc, bt, pos).compile()
-        ctx.assert_compiled("paged_attention %s pool" % kv_dtype,
-                            compiled.as_text())
-        got = np.asarray(compiled(q, kv, sc, bt, pos))
-        want = np.asarray(jax.jit(
-            lambda *a: paged_attention_reference(*a, page_size=ps))(
-                q, kv, sc, bt, pos))
-        err = float(np.abs(got - want).max() / np.abs(want).max())
-        if not np.isfinite(got).all() or err > _PAGED_TOL[kv_dtype]:
-            raise AssertionError(
-                "paged_attention %s: max|kernel-ref|/max|ref| = %.3g > "
-                "%.0e" % (kv_dtype, err, _PAGED_TOL[kv_dtype]))
-        ctx.note("paged_attention %s: max|kernel-ref|/max|ref| = %.2e "
-                 "(tolerance %.0e)" % (kv_dtype, err,
-                                       _PAGED_TOL[kv_dtype]))
+    for g in ctx.sz["paged"]:
+        T_, H, dh, ps, PP, NP = (
+            g[k] for k in ("T", "H", "dh", "ps", "PP", "NP"))
+        rng = np.random.RandomState(0)
+        bt = jnp.asarray(rng.randint(1, NP, (T_, PP)), jnp.int32)
+        pos = jnp.asarray(rng.randint(0, PP * ps, (T_,)), jnp.int32)
+        for kv_dtype in ("float32", "bfloat16", "int8"):
+            qdt = "float32" if kv_dtype == "float32" else "bfloat16"
+            q = jnp.asarray(rng.randn(T_, H, dh), qdt)
+            if kv_dtype == "int8":
+                kv = jnp.asarray(
+                    rng.randint(-127, 128, (NP, ps, H, 2 * dh)), jnp.int8)
+                sc = jnp.asarray(
+                    rng.uniform(0.005, 0.02, (NP, 2, ps, H)), jnp.float32)
+            else:
+                kv, sc = jnp.asarray(rng.randn(NP, ps, H, 2 * dh),
+                                     kv_dtype), None
+            kern = jax.jit(lambda *a: paged_attention(*a, page_size=ps))
+            compiled = kern.lower(q, kv, sc, bt, pos).compile()
+            what = "paged_attention %d heads %s" % (H, kv_dtype)
+            ctx.assert_compiled(what + " pool", compiled.as_text())
+            got = np.asarray(compiled(q, kv, sc, bt, pos))
+            want = np.asarray(jax.jit(
+                lambda *a: paged_attention_reference(*a, page_size=ps))(
+                    q, kv, sc, bt, pos))
+            err = float(np.abs(got - want).max() / np.abs(want).max())
+            if not np.isfinite(got).all() or err > _PAGED_TOL[kv_dtype]:
+                raise AssertionError(
+                    "%s: max|kernel-ref|/max|ref| = %.3g > %.0e"
+                    % (what, err, _PAGED_TOL[kv_dtype]))
+            ctx.note("%s: max|kernel-ref|/max|ref| = %.2e (tolerance "
+                     "%.0e)" % (what, err, _PAGED_TOL[kv_dtype]))
 
 
 def _kernel_fused_sgd(ctx):

@@ -1,33 +1,52 @@
-"""Fused paged-attention decode kernel — Pallas TPU (round 11).
+"""Fused paged-attention decode kernel — Pallas TPU.
 
-The serving engine's decode attention (``serving/engine.py _make_step``)
-previously materialized a block-table gather in HBM every step:
-``pool[row_pages]`` builds a dense (T*H, L, 2*dh) view — T·H·L·2·dh
-elements copied through HBM per layer per step — and only then runs the
-two attention dots (``models/gpt.py _attend_rows``).  For decode that
-gather IS the step cost: the dots read each element once, so the copy
-doubles the dominant HBM stream and adds a full intermediate buffer.
+The serving engine's attention (``serving/engine.py _make_step``) has
+two lowerings of one algorithm.  The jnp one,
+``paged_attention_reference``, materializes a block-table gather in
+HBM every step — ``pool[row_pages]`` builds a dense (T*H, L, 2*dh)
+view of every row's WHOLE table, XLA copies it into another layout,
+and only then ``models/gpt.py _attend_rows`` reads it: on the chip
+98% of a decode-heavy step (PERF.md).  It is the CPU path and the
+tests' oracle.  ``paged_attention`` here reads each row's live pages
+straight from the pool, once, and folds them into an
+**online-softmax accumulation** (running max / denominator /
+weighted-V sum, the FlashAttention recurrence over pages instead of
+k-blocks): it is what the engine runs on a TPU.
 
-This kernel walks each row's block table directly: grid (T, PP) with
-the block table scalar-prefetched (``pltpu.PrefetchScalarGridSpec``),
-so the BlockSpec index map streams page ``bt[t, j]`` HBM→VMEM per grid
-step (Pallas double-buffers consecutive pages automatically), and the
-kernel body folds that page into an **online-softmax accumulation**
-(running max / denominator / weighted-V accumulator in VMEM scratch,
-the FlashAttention recurrence over pages instead of k-blocks).  The
-ragged last page is masked by absolute position (``k_pos <= pos`` —
-the same per-row mask ``_attend_rows`` applies), pages past the row's
-length are skipped (``pl.when``), and int8-KV pages dequantize inside
-the loop — the k scale multiplies the scores, the v scale folds into
-the softmax weights, exactly where ``_attend_rows`` folds them —
-reading the round-22 TILE-SHAPED scale pages: ``(pages, 2, ps, H)``
-f32 planes (k plane 0, v plane 1), so a page's scales stream as
-``(ps, H)`` blocks with heads on the lane axis instead of the old
-per-column ``(ps, H, 2)`` stripes (``serving/paged_kv.py`` owns the
-layout; the engine's quant/dequant and the wire frames moved with it).
+**The walk** (``_walk_kernel``, PR 27).  Grid over blocks of R rows
+— 10 grid steps a layer for the benchmark's 160-row step, not one per
+page.  The block table and the positions are scalar-prefetched
+(``pltpu.PrefetchScalarGridSpec``); the pool stays in HBM
+(``memory_space=pl.ANY``).  Per row, a ``fori_loop`` over groups of G
+pages bounded by ``row_pos // page_size + 1`` and by nothing else:
+pages past a row's position, and every page of a dead row but its
+scratch page, are neither copied nor folded.  ``make_async_copy``
+brings group g+1 — or the NEXT row's first group, so a row's first
+copy hides behind the row before it — into one of two VMEM slots
+while group g is folded out of the other, F pages a turn; a whole
+page ``(ps, H, 2*dh)`` is one contiguous copy.  G, F and R follow
+from the shapes (``walk_geometry``): for the cell's bf16 pool of 16
+heads of 64, pages are 64 KiB, G = 8 (two 512 KiB slots), F = 2,
+R = 16.
 
-Round 22 — the mesh lowering (``mesh=``): ``paged_attention(...,
-mesh=serving_mesh(tp))`` wraps the same kernel in ``shard_map`` over
+**The per-page grid** (``_page_kernel``) is the older feeder of the
+same fold: grid (T, PP), the BlockSpec index map streams page
+``bt[t, j]`` per grid step, pages past the position skipped by
+``pl.when`` after their copy was paid.  It serves only the pools the
+walk cannot cut whole pages out of — this Mosaic refuses a
+``memref_slice`` whose two minor dims are not whole tiles even where
+it takes them whole: 16-bit pools whose head count is no multiple of
+8 (the ``full`` preset's 12 and its H/tp slices 6 and 3) and int8
+pools, whose ``(ps, H)`` scale planes are never whole tiles
+(ROADMAP C records the debt).  int8-KV pages dequantize inside the
+fold — the k scale multiplies the scores, the v scale folds into the
+softmax weights, exactly where ``_attend_rows`` folds them — reading
+the round-22 TILE-SHAPED scale pages: ``(pages, 2, ps, H)`` f32
+planes (k plane 0, v plane 1; ``serving/paged_kv.py`` owns the
+layout).
+
+The mesh lowering (``mesh=``, round 22): ``paged_attention(...,
+mesh=serving_mesh(tp))`` wraps the same call in ``shard_map`` over
 the serving mesh, each device walking its H/tp heads slice of the
 heads-sharded pool (``P(None, None, 'tp', None)``; scale planes shard
 their trailing heads axis) with q sharded on heads and the block
@@ -35,35 +54,33 @@ table/positions REPLICATED into scalar prefetch.  Attention is
 head-local, so the body is reused verbatim with H→H/tp and zero
 collectives inside — the output-projection psum stays the engine's
 (GSPMD inserts it outside the kernel, same as the XLA path).  The
-engine passes its mesh whenever ``kernel="pallas", tp>1``
-(``serving/engine.py``); tp∈{2,4} greedy token identity vs tp=1 and
-``generate`` is pinned in ``tests/test_serving_tp.py`` and the
-mesh-vs-reference parity in ``tests/test_paged_attention.py``.
+engine passes its mesh whenever it runs this kernel with tp>1;
+tp∈{2,4} greedy token identity vs tp=1 and ``generate`` is pinned in
+``tests/test_serving_tp.py`` and the mesh-vs-reference parity in
+``tests/test_paged_attention.py``.
 
-Numerics: online softmax normalizes ONCE at the end (acc / l) where
-the jnp reference normalizes the probabilities before the V dot, and
-the page-sequential accumulation orders the L-length reductions
-differently from one batched dot — both are 1–2 ulp effects in f32
-(measured max |diff| ~2e-7 on randn inputs; same caveat class as the
-paged-vs-contiguous reduction-order note in ``tests/test_serving.py``).
-``tests/test_paged_attention.py`` pins the kernel against the
-``_attend_rows`` reference at a few-ulp tolerance across page-boundary
-cases in interpreter mode, and the serving tests pin full greedy
-TOKEN-identity of the pallas engine against ``generate`` — the
-exactness bar the serving stack actually guarantees.
+Numerics (``_fold``): pages in the pool's dtype, scores and the
+running max / denominator / accumulator in f32, probabilities rounded
+to the compute dtype before the V sum, ONE normalization at the end
+(acc / l) where the jnp reference normalizes the probabilities before
+the V dot; the page-sequential accumulation orders the L-length
+reductions differently from one batched dot — both are 1–2 ulp
+effects in f32 (measured max |diff| ~2e-7 on randn inputs; same
+caveat class as the paged-vs-contiguous reduction-order note in
+``tests/test_serving.py``).  ``tests/test_paged_attention.py`` pins
+both feeders against the ``_attend_rows`` reference at a few-ulp
+tolerance across page- and group-boundary cases in interpreter mode,
+and the serving tests pin full greedy TOKEN-identity of the pallas
+engine against ``generate`` — the exactness bar the serving stack
+actually guarantees.
 
-Chip status (PR 21): the two head-batched ``dot_general``s this kernel
-was written with (batch dim not leading, no free rhs dim) were refused
-by Mosaic — ``failed to parse TPU_DotDimensionNumbersAttr`` — so before
-PR 21 the kernel had only ever run in the interpreter.  Both
-contractions are now multiply-and-reduce on the VPU (one query row per
-head: the walk is bound by the page stream, not by flops), which
-Mosaic compiles for v5e at every shape ``tests/test_kernels_mosaic.py``
-tries (bf16/f32/int8 pools, the ``full`` preset's 12 heads and its
-H/tp slices 6 and 3, the head-blocked 16/32-head walk).  On the chip
-``chip_smoke.py`` pins it against ``paged_attention_reference`` and
-runs the ``full`` preset engine through it; CHANGES.md (PR 21) has
-what that run found.  Its speed is NOT measured — ROADMAP A3.
+On the chip: ``tests/test_kernels_mosaic.py`` compiles every pool
+kind for v5e without one; ``chip_smoke.py`` pins both feeders against
+``paged_attention_reference`` there.  Measured (PERF.md, PR 27): in
+``bert_large_decoder.decode_heavy`` the walk is 8 ms of device time a
+step where the gather path was 68 and the per-page grid 43; it is
+bound by the fold's vector arithmetic (one query row per head leaves
+the MXU nothing to do), not by the page copies.
 """
 from __future__ import annotations
 
@@ -71,9 +88,203 @@ import functools
 
 __all__ = ["paged_attention", "paged_attention_reference"]
 
-def _kernel(bt_ref, pos_ref, q_ref, kv_ref, *rest, page_size, dh,
-            int8):
+# VMEM one DMA slot of the walk may hold: a group is as many whole
+# pages as fit (two slots are live, so the walk's buffers are twice
+# this, beside the f32 temporaries of one fold)
+_GROUP_BYTES = 512 * 1024
+# rows of the step a grid step walks: the first copy of a grid step
+# has nothing to hide behind, so its latency is paid once per _ROWS
+# rows; q and the output block over it and stay small
+_ROWS = 16
+
+
+def walk_geometry(H, dh, page_size, PP, kv_dtype):
+    """``(G, F, R)`` of the walk for one pool geometry — ``G`` pages
+    are copied per DMA group (as many whole ``(page_size, H, 2*dh)``
+    pages as ``_GROUP_BYTES`` holds, at least one, at most a row's
+    table), ``F`` of them are folded per turn of the inner loop (two
+    where G is even: a turn's fixed cost is paid half as often, and a
+    row's last turn folds at most one page it did not need), ``R``
+    rows are walked per grid step — or ``None`` where Mosaic cannot
+    cut whole pages out of the pool and the per-page grid serves
+    instead: a ``memref_slice`` of an HBM ref must be whole tiles in
+    its two minor dims even where it takes them whole, which a 16-bit
+    page is only when H is a multiple of 8 (12, 6 and 3 are refused:
+    "Slice shape along dimension 2 must be aligned to tiling (8)")
+    and an int8 pool's ``(ps, H)`` scale planes never are.  Chosen
+    from shapes alone; the tests read it to aim at the group
+    boundaries."""
+    import numpy as np
+    kv_dtype = np.dtype(kv_dtype)
+    if kv_dtype == np.int8 or (kv_dtype.itemsize < 4 and H % 8):
+        return None
+    page_bytes = page_size * H * 2 * dh * kv_dtype.itemsize
+    G = max(1, min(PP, _GROUP_BYTES // page_bytes))
+    return G, 2 - G % 2, _ROWS
+
+
+def _scale_folds(dh):
+    """Whether 1/sqrt(dh) is a power of two.  It then multiplies into
+    q ahead of the page loop (``_scaled``): scaling by a power of two
+    commutes with every rounding, so the scores are bit for bit those
+    of the division the reference makes after its dot.  Any other dh
+    keeps that division, per page."""
+    import math
+    return math.frexp(float(dh) ** -0.5)[0] == 0.5
+
+
+def _scaled(q, dh):
+    """A row's query in f32, carrying 1/sqrt(dh) where that is exact."""
+    import jax.numpy as jnp
+    q = q.astype(jnp.float32)
+    return q * (float(dh) ** -0.5) if _scale_folds(dh) else q
+
+
+def _fold(kv, sc, q, m, l, acc, k0, pos, dh, cdt):
+    """Fold one page into a row's online softmax: the FlashAttention
+    recurrence over pages.  ``kv`` (ps, H, 2*dh) is the page as the
+    pool holds it (cdt, or int8 with ``sc`` its (2, ps, H) scale
+    block), ``q`` (H, 2*dh) the row's ``_scaled`` query ZERO-EXTENDED
+    over the v half of the lanes, ``m`` / ``l`` (H, 1) and ``acc``
+    (H, 2*dh) the running max, denominator and weighted sum in f32,
+    ``k0`` the page's first position (``kv`` may be several
+    consecutive pages, ``ps`` then their tokens together).  Returns
+    the three updated.
+
+    One query row per head: both contractions are multiply-and-reduce
+    on the VPU (Mosaic has no matmul form for a batch dim that is not
+    leading, and one query row would leave the MXU idle anyway).
+    Products of cdt (or int8) values are exact in f32, so this matches
+    an MXU dot with f32 accumulation up to summation order.  Scores
+    keep heads on the sublanes — (ps, H, 1), the layout the lane
+    reduction leaves them in — and the v sum runs over the page's full
+    width, so nothing is relaid between the two contractions: the k
+    half of ``acc`` gathers p·k, finite and never read."""
     import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    kv = kv.astype(f32)
+    s = jnp.sum(kv * q[None], axis=-1, keepdims=True)   # (ps, H, 1)
+    if sc is not None:
+        # k scale multiplies the scores, v scale folds into the
+        # softmax weights (the same fold points as _attend_rows);
+        # plane 0 = k scales, plane 1 = v
+        s = s * sc[0][:, :, None]
+    if not _scale_folds(dh):
+        s = s / jnp.sqrt(f32(dh))
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    s = jnp.where(k_pos <= pos, s, -1e30)
+    m_new = jnp.maximum(m, jnp.max(s, axis=0))          # (H, 1)
+    p = jnp.exp(s - m_new[None])                        # (ps, H, 1)
+    alpha = jnp.exp(m - m_new)
+    l = l * alpha + jnp.sum(p, axis=0)
+    if sc is not None:
+        p = p * sc[1][:, :, None]
+    p = p.astype(cdt).astype(f32)
+    acc = acc * alpha + jnp.sum(p * kv, axis=0)         # (H, 2*dh)
+    return m_new, l, acc
+
+
+def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
+                 page_size, dh, T, PP, G, F, R):
+    """Grid over blocks of R rows; the pool stays in HBM.  Per row a
+    loop over groups of G pages, bounded by the row's own position:
+    group g+1 (or the next row's first group) is copied into one VMEM
+    slot while group g is folded, F pages a turn, out of the other."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    ps = page_size
+    H = q_ref.shape[1]
+    row0 = pl.program_id(0) * R
+    # rows of this grid step that exist (the last block may be short)
+    n_rows = jnp.minimum(R, T - row0)
+
+    def last_page(t):
+        # the page that holds the row's own position: the walk's one
+        # bound.  A dead row (pos 0, all-zero table row) walks the
+        # scratch page and nothing else
+        return jnp.minimum(pos_ref[t] // ps, PP - 1)
+
+    def copy(page, slot, i):
+        return pltpu.make_async_copy(kv_hbm.at[page], buf.at[slot, i],
+                                     sem.at[slot, i])
+
+    def start(t, g, slot):
+        # row t's group g: whole pages, HBM to VMEM slot ``slot``;
+        # pages past the row's position are not copied
+        last = last_page(t)
+        for i in range(G):
+            j = g * G + i
+
+            @pl.when(j <= last)
+            def _():
+                copy(bt_ref[t * PP + j], slot, i).start()
+
+    def row(r, slot):
+        # ``slot`` holds (or is receiving) this row's first group
+        t = row0 + r
+        pos = pos_ref[t]
+        last = last_page(t)
+        n_groups = last // G + 1
+        q = _scaled(q_ref[r], dh)                  # (H, 2*dh)
+
+        def group(g, carry):
+            m, l, acc, slot = carry
+            more = g + 1 < n_groups
+            t_nxt = jnp.where(more, t, jnp.minimum(t + 1, T - 1))
+            g_nxt = jnp.where(more, g + 1, 0)
+
+            @pl.when(more | (r + 1 < n_rows))
+            def _():
+                start(t_nxt, g_nxt, 1 - slot)
+
+            def turn(c, carry):
+                # F pages a turn; one past the row's last was not
+                # copied and holds an older page (or the zeros below):
+                # finite, and masked by position like any tail
+                for f in range(F):
+                    @pl.when(g * G + c * F + f <= last)
+                    def _():
+                        # a wait goes by the copy's slot and size, not
+                        # by its source
+                        copy(0, slot, c * F + f).wait()
+                kv = buf[slot, pl.ds(c * F, F)]
+                return _fold(kv.reshape(F * ps, H, 2 * dh), None, q,
+                             *carry, (g * G + c * F) * ps, pos, dh,
+                             q_ref.dtype)
+
+            n_pages = jnp.minimum(G, last + 1 - g * G)
+            m, l, acc = jax.lax.fori_loop(0, (n_pages + F - 1) // F,
+                                          turn, (m, l, acc))
+            return m, l, acc, 1 - slot
+
+        init = (jnp.full((H, 1), -jnp.inf, f32), jnp.zeros((H, 1), f32),
+                jnp.zeros((H, 2 * dh), f32), slot)
+        _, l, acc, slot = jax.lax.fori_loop(0, n_groups, group, init)
+        o_ref[r] = (acc[:, dh:] / l).astype(o_ref.dtype)
+        return slot
+
+    if F > 1:
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            # what a turn may fold without having copied it must not
+            # be whatever VMEM held before this call
+            buf[...] = jnp.zeros_like(buf)
+
+    start(row0, 0, 0)
+    jax.lax.fori_loop(0, n_rows, row, 0)
+
+
+def _page_kernel(bt_ref, pos_ref, q_ref, kv_ref, *rest, page_size, dh,
+                 int8):
+    """Grid (T, PP), the page walk innermost: the BlockSpec index map
+    streams page ``bt[t, j]`` per grid step and the online-softmax
+    state lives in VMEM scratch across a row's steps.  Only for the
+    pools ``walk_geometry`` turns away."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -82,13 +293,7 @@ def _kernel(bt_ref, pos_ref, q_ref, kv_ref, *rest, page_size, dh,
     else:
         s_ref = None
         o_ref, m_ref, l_ref, acc_ref = rest
-
-    # grid (T, NH, PP): rows, head BLOCKS, pages — the page walk is
-    # innermost so the online-softmax scratch accumulates over j for a
-    # fixed (row, head-block) and every ref below sees one HB-sized
-    # heads slice
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    j = pl.program_id(1)
     pos = pos_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
@@ -97,53 +302,18 @@ def _kernel(bt_ref, pos_ref, q_ref, kv_ref, *rest, page_size, dh,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # pages whose first slot is past the row's position hold nothing
-    # this row may attend to — skip the whole page (the scalar-prefetch
-    # index map still aims their prefetch at whatever bt says, which
-    # for unallocated tail entries is the scratch page 0)
+    # a page whose first slot is past the row's position holds nothing
+    # the row may attend to (its copy has been paid all the same)
     @pl.when(j * page_size <= pos)
     def _page():
-        kv = kv_ref[0]                       # (ps, HB, 2*dh) cdt|int8
-        q = q_ref[0]                         # (HB, dh) cdt
-        cdt = q.dtype
-        f32 = jnp.float32
-        k = kv[:, :, :dh].astype(f32)
-        v = kv[:, :, dh:].astype(f32)
-        # One query row per head: both contractions are multiply-and-
-        # reduce on the VPU.  Mosaic has no matmul form for a batch dim
-        # that is not leading, and a (1, dh) x (dh, ps) product per
-        # head would leave the MXU idle anyway — the walk is bound by
-        # the page stream, not by these flops.  Products of cdt (or
-        # int8) values are exact in f32, so this matches an MXU dot
-        # with f32 accumulation up to summation order.
-        s = jnp.sum(k * q.astype(f32)[None], axis=-1)   # (ps, HB)
-        if int8:
-            # k scale multiplies the scores (the same fold point as
-            # _attend_rows).  s_ref[0] is the page's scale block
-            # (2, ps, HB): plane 0 = k scales, plane 1 = v
-            s = s * s_ref[0, 0]
-        s = s / jnp.sqrt(f32(dh))
-        k_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        s = jnp.where(k_pos <= pos, s, -1e30)
+        m_ref[...], l_ref[...], acc_ref[...] = _fold(
+            kv_ref[0], s_ref[0] if int8 else None,
+            _scaled(q_ref[0], dh), m_ref[...], l_ref[...],
+            acc_ref[...], j * page_size, pos, dh, q_ref.dtype)
 
-        m_prev = m_ref[...]                  # (1, HB)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        p = jnp.exp(s - m_new)               # (ps, HB) f32
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + \
-            jnp.sum(p, axis=0, keepdims=True)
-        if int8:
-            # v scale folds into the softmax weights before the V sum
-            p = p * s_ref[0, 1]
-        p = p.astype(cdt).astype(f32)
-        acc_ref[...] = acc_ref[...] * alpha.T + \
-            jnp.sum(p[:, :, None] * v, axis=0)          # (HB, dh)
-        m_ref[...] = m_new
-
-    @pl.when(j == nj - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _out():
-        o_ref[0] = (acc_ref[...] / l_ref[...].T).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:, dh:] / l_ref[...]).astype(o_ref.dtype)
 
 
 # bounded cache of built pallas_call closures, keyed on every
@@ -166,48 +336,47 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
     if fn is not None:
         return fn
 
-    # head blocking: walk the heads axis in blocks of 8 (the f32
-    # sublane count) when H divides, the whole axis otherwise
-    # (small-model/test shapes, and the H/tp slices 6 and 3 of the
-    # `full` preset).  Bounds per-step VMEM at HB·(ps·2dh + dh)
-    # instead of H·(ps·2dh + dh) however many heads this shard holds.
-    # An int8 pool walks whole heads: its scale planes carry heads on
-    # the LANE axis, and Mosaic takes a lane block only if it is
-    # 128-divisible or the whole axis — an 8-head slice of 16 is
-    # neither.
-    HB = 8 if H % 8 == 0 and not int8 else H
-    NH = H // HB
-
-    def page_map(t, h, j, bt, pos):
-        return (bt[t * PP + j], 0, h, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, HB, dh), lambda t, h, j, bt, pos: (t, h, 0)),
-        pl.BlockSpec((1, page_size, HB, 2 * dh), page_map),
-    ]
-    scratch = [pltpu.VMEM((1, HB), jnp.float32),
-               pltpu.VMEM((1, HB), jnp.float32),
-               pltpu.VMEM((HB, dh), jnp.float32)]
-    if int8:
-        # scale block: (2, ps, H) — two (ps, heads) planes indexed by
-        # the SAME page map, the whole heads axis last (HB == H here)
-        in_specs.append(pl.BlockSpec(
-            (1, 2, page_size, HB),
-            lambda t, h, j, bt, pos: (bt[t * PP + j], 0, 0, 0)))
-    body = functools.partial(_kernel, page_size=page_size, dh=dh,
-                             int8=int8)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T, NH, PP),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, HB, dh),
-                               lambda t, h, j, bt, pos: (t, h, 0)),
-        scratch_shapes=scratch,
-    )
+    geometry = walk_geometry(H, dh, page_size, PP, kv_dtype)
+    if geometry is not None:
+        G, F, R = geometry
+        R = min(R, T)
+        grid = (-(-T // R),)
+        in_specs = [
+            pl.BlockSpec((R, H, 2 * dh), lambda b, bt, pos: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ]
+        out_specs = pl.BlockSpec((R, H, dh),
+                                 lambda b, bt, pos: (b, 0, 0))
+        scratch = [pltpu.VMEM((2, G, page_size, H, 2 * dh), kv_dtype),
+                   pltpu.SemaphoreType.DMA((2, G))]
+        body = functools.partial(_walk_kernel, page_size=page_size,
+                                 dh=dh, T=T, PP=PP, G=G, F=F, R=R)
+    else:
+        grid = (T, PP)
+        in_specs = [
+            pl.BlockSpec((1, H, 2 * dh), lambda t, j, bt, pos: (t, 0, 0)),
+            pl.BlockSpec((1, page_size, H, 2 * dh),
+                         lambda t, j, bt, pos: (bt[t * PP + j], 0, 0, 0)),
+        ]
+        if int8:
+            # scale block: (2, ps, H) — two (ps, heads) planes indexed
+            # by the SAME page map
+            in_specs.append(pl.BlockSpec(
+                (1, 2, page_size, H),
+                lambda t, j, bt, pos: (bt[t * PP + j], 0, 0, 0)))
+        out_specs = pl.BlockSpec((1, H, dh),
+                                 lambda t, j, bt, pos: (t, 0, 0))
+        scratch = [pltpu.VMEM((H, 1), jnp.float32),
+                   pltpu.VMEM((H, 1), jnp.float32),
+                   pltpu.VMEM((H, 2 * dh), jnp.float32)]
+        body = functools.partial(_page_kernel, page_size=page_size,
+                                 dh=dh, int8=int8)
     fn = pl.pallas_call(
         body,
         out_shape=jax.ShapeDtypeStruct((T, H, dh), jnp.float32),
-        grid_spec=grid_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
         interpret=interpret,
     )
     if len(_call_cache) >= _CALL_CACHE_MAX:
@@ -261,8 +430,11 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
         raise ValueError("paged_attention: pool page_size %d != %d"
                          % (pool_kv.shape[1], page_size))
     int8 = pool_s is not None
+    # q zero-extended over the v half of a page's lanes (the kernel's
+    # one full-width product then contracts q with k alone)
     args = [block_tables.reshape(-1).astype(jnp.int32),
-            row_pos.astype(jnp.int32), q, pool_kv]
+            row_pos.astype(jnp.int32),
+            jnp.concatenate([q, jnp.zeros_like(q)], axis=-1), pool_kv]
     if int8:
         args.append(pool_s)
 

@@ -37,7 +37,8 @@ Round 11 adds the raw-decode-speed levers (ROADMAP item 2):
   the fused block-table-walk Pallas kernel
   (``kernels/paged_attention.py``: online-softmax over pages, int8
   dequant in the inner loop, no materialized gather); ``"xla"`` keeps
-  the gather + ``_attend_rows`` path, cross-checked by tests.
+  the gather + ``_attend_rows`` path, cross-checked by tests.  Left
+  unset, an engine on a TPU takes the walk and any other the gather.
 - ``ServingEngine(spec_K=K)`` — in-engine speculative decode:
   host-side drafting (``drafters.ngram_draft``) feeds K extra rows
   per decode slot into the SAME step program, which verifies every

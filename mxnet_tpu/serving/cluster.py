@@ -283,7 +283,8 @@ class ServingCluster:
     prefix-affinity routing exists for); each replica has its own
     cache, so shared-prefix prefill is paid once per replica.  The
     round-11 decode levers pass straight through: ``kernel`` selects
-    each replica's attention path (xla gather vs fused pallas walk)
+    each replica's attention path (xla gather vs fused pallas walk;
+    None leaves it to each engine, which picks by its device)
     and ``spec_K``/``spec_drafter``/``spec_ngram`` arm in-engine
     speculative decode per replica — failover/resubmit semantics are
     unchanged because committed tokens are committed tokens however
@@ -308,7 +309,7 @@ class ServingCluster:
                  watchdog_s=None, default_ttl_s=None,
                  affinity_slack=None,
                  affinity_capacity=4096, retain_results=4096,
-                 kernel="xla", spec_K=0, spec_drafter="ngram",
+                 kernel=None, spec_K=0, spec_drafter="ngram",
                  spec_ngram=2, tp=1, mesh=None, tier_bytes=None,
                  overlap=None):
         if replicas < 1:
@@ -1514,7 +1515,7 @@ class DisaggServingCluster:
     def __init__(self, params, cfg, *, prefill=1, decode=1,
                  num_slots, page_size=16, num_pages=None,
                  pages_per_slot=None, prefill_chunk=8, kv_int8=False,
-                 kernel="xla", spec_K=0, metrics=None, registry=None,
+                 kernel=None, spec_K=0, metrics=None, registry=None,
                  watchdog_s=None, spawn=True, host="127.0.0.1",
                  port=0, ready_timeout=None, tier_bytes=None,
                  overlap=None):

@@ -31,9 +31,12 @@ Round 11 adds the two raw-decode-speed levers from ROADMAP item 2:
 
 * ``kernel="pallas"`` routes the step program's attention through the
   fused block-table-walk kernel (``kernels/paged_attention.py``):
-  online-softmax over pages streamed HBM→VMEM, int8 dequant in the
-  inner loop, no materialized gather.  ``"xla"`` (default) keeps the
-  gather + ``_attend_rows`` path; both are cross-checked by tests.
+  online-softmax over each row's live pages, copied HBM→VMEM once,
+  int8 dequant in the inner loop, no materialized gather.  ``"xla"``
+  keeps the gather + ``_attend_rows`` path; both are cross-checked by
+  tests.  Left unset, the engine picks by the platform its pools live
+  on: the walk on a TPU (PR 27: 8 ms of a decode-heavy step against
+  the gather's 68), the gather everywhere else.
 * ``spec_K=K`` folds speculative decode INTO the step program: each
   running decode slot feeds its pending token plus K host-drafted
   rows (``serving/drafters.py`` ngram by default), the ONE program
@@ -309,11 +312,13 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
                params=None, overlap=False):
     """Build (and cache) the jitted unified prefill+decode step.
 
-    ``kernel`` selects the decode-attention implementation: ``"xla"``
-    is the block-table gather + ``_attend_rows`` path (materializes
-    the gathered (T*H, L, 2*dh) view), ``"pallas"`` the fused
-    ``kernels/paged_attention.py`` walk (online softmax over pages,
-    no gather materialization; interpreter mode off-TPU).
+    ``kernel`` selects the decode-attention lowering: ``"xla"`` is
+    the block-table gather + ``_attend_rows`` path (materializes the
+    gathered (T*H, L, 2*dh) view of every row's whole table),
+    ``"pallas"`` the fused ``kernels/paged_attention.py`` walk (each
+    row's live pages copied once and folded into an online softmax,
+    no gather materialization; interpreter mode off-TPU).  The
+    engine resolves its own default before it calls this.
 
     ``n_sample`` is how many argmax rows each slot reads back per step
     (1 + spec_K): with in-engine speculation every decode slot feeds
@@ -413,11 +418,11 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
                     new_pools.append({"kv": pool_kv})
             if kernel == "pallas":
                 # fused block-table walk (kernels/paged_attention.py):
-                # pages stream HBM->VMEM per grid step, online-softmax
-                # accumulation, int8 dequant in the inner loop — no
-                # gathered view is ever materialized.  With a mesh the
-                # call shard_maps over tp: each device walks its own
-                # H/tp heads slice of the pools (round 22)
+                # each row's live pages are copied HBM->VMEM once,
+                # online-softmax accumulation, int8 dequant in the
+                # inner loop — no gathered view is ever materialized.
+                # With a mesh the call shard_maps over tp: each device
+                # walks its own H/tp heads slice of the pools (round 22)
                 from ..kernels.paged_attention import paged_attention
                 attn = paged_attention(q, pool_kv, pool_s, row_pages,
                                        row_pos, page_size=page_size,
@@ -544,7 +549,7 @@ class _Plan:
                  "was_decode", "prefill_mid", "n_dec_rows",
                  "n_pre_rows", "n_rows_used", "decode_rids",
                  "prefill_spans", "carried", "fenced", "empty",
-                 "pipelined")
+                 "pipelined", "kv_pages")
 
     def __init__(self):
         self.buf = None
@@ -562,6 +567,7 @@ class _Plan:
         self.fenced = False         # spec fence: nothing built
         self.empty = True           # no live rows
         self.pipelined = False      # built for the overlap path
+        self.kv_pages = 0           # K/V pages the step's attention reads
 
 
 def _planner_main(engine_ref, ctl, go, ready):
@@ -867,13 +873,20 @@ class ServingEngine:
         completed prompt pages are donated back; refcount-0 chains are
         LRU-evicted under pool pressure.  Off by default — the
         ``ServingCluster`` turns it on per replica.
-    kernel : ``"xla"`` (default) attends via the block-table gather +
+    kernel : ``"xla"`` attends via the block-table gather +
         ``_attend_rows``; ``"pallas"`` runs the fused
         ``kernels/paged_attention.py`` block-table walk (interpreter
         mode off-TPU, so tier-1 CPU tests cover the kernel path).
-        Outputs differ by 1–2 f32 ulps (online-softmax normalization
-        order — the kernel module docstring); greedy token-identity
-        vs ``generate`` is pinned for both by ``tests/test_serving``.
+        None (the default) picks by the platform the engine's pools
+        were placed on: ``"pallas"`` on a TPU, where the walk reads
+        each row's live pages once and the gather path copies every
+        row's whole table three times over; ``"xla"`` on anything
+        else, where the kernel would be interpreted.  Outputs differ
+        by 1–2 f32 ulps (online-softmax normalization order — the
+        kernel module docstring); greedy token-identity vs
+        ``generate`` is pinned for both by ``tests/test_serving``.
+        ``stats["kv_pages_read"]`` over ``stats["kv_pages_window"]``
+        says how much of the attention window a step read.
     spec_K : in-engine speculative decode — each running decode slot
         drafts K tokens per step, the step program verifies all rows'
         drafts in ONE batched forward over the paged cache, accepted
@@ -933,7 +946,7 @@ class ServingEngine:
     def __init__(self, params, cfg, *, num_slots, page_size=16,
                  num_pages=None, pages_per_slot=None, prefill_chunk=8,
                  kv_int8=False, prefix_cache=False, metrics=None,
-                 registry=None, rid_start=0, kernel="xla", spec_K=0,
+                 registry=None, rid_start=0, kernel=None, spec_K=0,
                  spec_drafter="ngram", spec_ngram=2, tp=1, mesh=None,
                  tier_bytes=None, overlap=None, device=None):
         if not cfg.causal:
@@ -943,9 +956,9 @@ class ServingEngine:
         if prefill_chunk < 1:
             raise ValueError("ServingEngine: prefill_chunk must be "
                              ">= 1")
-        if kernel not in ("xla", "pallas"):
-            raise ValueError("ServingEngine: kernel must be 'xla' or "
-                             "'pallas', got %r" % (kernel,))
+        if kernel not in (None, "xla", "pallas"):
+            raise ValueError("ServingEngine: kernel must be 'xla', "
+                             "'pallas' or None, got %r" % (kernel,))
         if spec_K < 0:
             raise ValueError("ServingEngine: spec_K must be >= 0")
         if spec_drafter != "ngram" and not callable(spec_drafter):
@@ -1032,7 +1045,6 @@ class ServingEngine:
         self.pages_per_slot = pages_per_slot
         self.prefill_chunk = prefill_chunk
         self.kv_int8 = bool(kv_int8)
-        self.kernel = kernel
         self.spec_K = int(spec_K)
         self.spec_drafter = spec_drafter
         self.spec_ngram = int(spec_ngram)
@@ -1044,6 +1056,21 @@ class ServingEngine:
         self.cache = PagedKVCache(cfg, num_pages, page_size,
                                   kv_int8=self.kv_int8,
                                   mesh=self.mesh, device=device)
+        # one attention, two lowerings; where none is asked for, the
+        # platform the pools were just placed on says which is fast
+        # (the step program runs where its pools live)
+        if kernel is None:
+            from ..kernels.platform import platform_of
+            kernel = "pallas" \
+                if platform_of(self.cache.pools) == "tpu" else "xla"
+        self.kernel = kernel
+        # whether attention walks each row's own pages (the Pallas
+        # walk, on a pool it can cut pages out of) or reads the whole
+        # (rows x pages_per_slot) window: what kv_pages_read books
+        from ..kernels.paged_attention import walk_geometry
+        self._walks_pages = kernel == "pallas" and walk_geometry(
+            cfg.n_heads // tp, cfg.d_model // cfg.n_heads, page_size,
+            pages_per_slot, self.cache.pools[0]["kv"].dtype) is not None
         # host-DRAM KV tier (round 18): explicit argument >
         # MXNET_SERVE_TIER_BYTES env > off.  0/None disables — every
         # pre-tier behavior (drop on pressure, recompute on resume)
@@ -1101,7 +1128,8 @@ class ServingEngine:
                       "swap_outs": 0, "swap_ins": 0,
                       "slot_occupancy_sum": 0.0,
                       "host_hidden_ms": 0.0, "overlap_steps": 0,
-                      "overlap_fences": 0}
+                      "overlap_fences": 0, "kv_pages_window": 0,
+                      "kv_pages_read": 0}
         # -------- round 21: scheduler/planner shared state ---------
         # One lock (_mu) guards everything BOTH the engine thread and
         # the planner thread touch: queue/slots/pages/prefix/stats and
@@ -1695,7 +1723,7 @@ class ServingEngine:
         with profiler.span("engine.plan"), self._mu:
             plan = self._build_plan(overlap=False)
         sp.set(decode=plan.n_dec_rows, prefill=plan.n_pre_rows,
-               dead=self.n_rows - plan.n_rows_used)
+               dead=self.n_rows - plan.n_rows_used, pages=plan.kv_pages)
         with self._operator_span():
             next_tok = self._dispatch(plan)
             with profiler.span("engine.wait") as wait:
@@ -1735,7 +1763,7 @@ class ServingEngine:
             self._maybe_plan_ahead()
             return finished
         sp.set(decode=plan.n_dec_rows, prefill=plan.n_pre_rows,
-               dead=self.n_rows - plan.n_rows_used)
+               dead=self.n_rows - plan.n_rows_used, pages=plan.kv_pages)
         old, old_tok = self._inflight, self._inflight_tok
         if not plan.empty:
             with self._operator_span():
@@ -2012,6 +2040,15 @@ class ServingEngine:
             # a phantom batch (the serial path dispatches dead batches
             # only when the idle check already found work)
             self.stats["dead_rows"] += T - r
+            # how far the attention's reads follow the rows: the
+            # window is every row's whole table; the walk reads up to
+            # each row's own position (a dead row its scratch page)
+            window = T * self.pages_per_slot
+            plan.kv_pages = int(
+                (row_pos // self.page_size + 1).sum()) \
+                if self._walks_pages else window
+            self.stats["kv_pages_window"] += window
+            self.stats["kv_pages_read"] += plan.kv_pages
             self.stats["peak_pages"] = max(self.stats["peak_pages"],
                                            self.cache.pages_in_use)
             self.stats["slot_occupancy_sum"] += \

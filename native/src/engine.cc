@@ -212,6 +212,14 @@ void Engine::Execute(Opr* op) {
 
 void Engine::OnComplete(Opr* op, const std::string& err, bool own_failure) {
   auto exc = err.empty() ? nullptr : std::make_shared<std::string>(err);
+  // Record the op's own failure BEFORE its vars are released: releasing
+  // them lets a queued WaitForVar waiter run, consume the var's error
+  // and clear the matching global entry — recorded after that clear, the
+  // error would be stale and resurface at the next WaitForAll.
+  if (own_failure) {
+    std::lock_guard<std::mutex> lk(err_mu_);
+    if (global_err_.empty()) global_err_ = err;
+  }
   for (auto* v : op->const_vars) {
     std::lock_guard<std::mutex> lk(v->mu);
     v->active_reads--;
@@ -225,10 +233,6 @@ void Engine::OnComplete(Opr* op, const std::string& err, bool own_failure) {
       if (exc) v->exception = exc;
     }
     ProcessQueue(v);
-  }
-  if (own_failure) {
-    std::lock_guard<std::mutex> lk(err_mu_);
-    if (global_err_.empty()) global_err_ = err;
   }
   if (op->delete_target) delete op->delete_target;
   delete op;

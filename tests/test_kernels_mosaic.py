@@ -58,16 +58,29 @@ def _paged_shapes(T, H, dh, ps, PP, NP, kv_dtype, q_dtype):
     # device of the mesh lowering walks)
     (dict(_FULL, H=6), "bfloat16", "bfloat16"),
     (dict(_FULL, H=3), "int8", "bfloat16"),
-    # H % 8 == 0, H > 8: the head-blocked walk
+    # more heads and lane-width head dims, whole heads a page
     (dict(_FULL, H=16, dh=128), "bfloat16", "bfloat16"),
     (dict(_FULL, H=32, dh=128), "int8", "bfloat16"),
+    # the benchmark's serving cell (bert_large_decoder): 160 step
+    # rows, 16 heads of 64, a (3073, 16, 16, 128) pool — the walk
+    (dict(T=160, H=16, dh=64, ps=16, PP=32, NP=3073), "bfloat16",
+     "bfloat16"),
+    # a short last row block, and a row table that is one odd group
+    (dict(T=37, H=8, dh=64, ps=16, PP=7, NP=353), "float32", "float32"),
 ], ids=["full-bf16", "full-int8", "full-f32", "tp2-bf16", "tp4-int8",
-        "h16-bf16", "h32-int8"])
+        "h16-bf16", "h32-int8", "cell-bf16", "odd-f32"])
 def test_paged_attention_compiles(v5e, geom, kv_dtype, q_dtype):
-    from mxnet_tpu.kernels.paged_attention import paged_attention
+    from mxnet_tpu.kernels.paged_attention import (paged_attention,
+                                                   walk_geometry)
     q, kv, sc, bt, pos = _paged_shapes(kv_dtype=kv_dtype,
                                        q_dtype=q_dtype, **geom)
     ps = geom["ps"]
+    # the walk where Mosaic can cut whole pages out of the pool, the
+    # per-page grid elsewhere: both must compile
+    walks = walk_geometry(geom["H"], geom["dh"], ps, geom["PP"],
+                          kv_dtype) is not None
+    assert walks == (kv_dtype == "float32" or
+                     (kv_dtype == "bfloat16" and geom["H"] % 8 == 0))
     if sc is None:
         _compile(lambda q, kv, bt, pos: paged_attention(
             q, kv, None, bt, pos, page_size=ps),

@@ -182,6 +182,96 @@ def test_pallas_kernel_token_identical():
                       kernel="mosaic")
 
 
+def test_default_kernel_follows_the_devices_platform(monkeypatch):
+    """``kernel=None`` resolves from where the engine's pools live —
+    a TPU takes the Pallas page walk, anything else the XLA gather —
+    and an explicit ``kernel=`` wins on either."""
+    import jax
+    from mxnet_tpu.kernels import platform
+    from mxnet_tpu.models import transformer as T
+    from mxnet_tpu.serving import ServingEngine
+
+    cfg = _cfg()
+    params = T.init_params(jax.random.PRNGKey(3), cfg)
+    kw = dict(num_slots=2, page_size=4, prefill_chunk=4)
+    assert ServingEngine(params, cfg, **kw).kernel == "xla"
+    assert ServingEngine(params, cfg, kernel="pallas",
+                         **kw).kernel == "pallas"
+    asked = []
+
+    def on_tpu(*operands):
+        asked.append(operands)
+        return "tpu"
+    monkeypatch.setattr(platform, "platform_of", on_tpu)
+    eng = ServingEngine(params, cfg, device=jax.devices()[0], **kw)
+    assert eng.kernel == "pallas"
+    # it asked about the pools it had just placed
+    assert asked and asked[0][0] is eng.cache.pools
+    assert ServingEngine(params, cfg, kernel="xla",
+                         **kw).kernel == "xla"
+
+
+@pytest.mark.parametrize("kernel,kv_int8", [
+    ("xla", False), ("pallas", False), ("pallas", True)])
+def test_kv_pages_counters(kernel, kv_int8):
+    """``kv_pages_window`` books every dispatched step's whole
+    attention window (rows x pages a slot); ``kv_pages_read`` equals
+    it on the gather path and on a pool the walk cannot cut pages out
+    of (int8), and equals the rows' own page counts — position //
+    page_size + 1, a dead row one scratch page — on the walk."""
+    import jax
+    from mxnet_tpu.models import transformer as T
+    from mxnet_tpu.serving import ServingEngine
+
+    cfg = _cfg()
+    params = T.init_params(jax.random.PRNGKey(3), cfg)
+    eng = ServingEngine(params, cfg, num_slots=3, page_size=4,
+                        prefill_chunk=6, kernel=kernel,
+                        kv_int8=kv_int8)
+    own, steps = [], []
+    dispatch = eng._dispatch
+
+    def counting(plan):
+        own.append(int((plan.buf.row_pos // 4 + 1).sum()))
+        steps.append(plan.kv_pages)
+        return dispatch(plan)
+    eng._dispatch = counting
+    rng = np.random.RandomState(0)
+    for P, N in [(5, 8), (3, 12), (9, 4), (2, 6)]:
+        eng.submit(rng.randint(1, 90, P).astype(np.int32), N)
+    eng.run()
+    window = len(own) * eng.n_rows * eng.pages_per_slot
+    assert len(own) > 8 and eng.stats["kv_pages_window"] == window
+    assert eng.stats["kv_pages_read"] == sum(steps)
+    if kernel == "pallas" and not kv_int8:
+        assert steps == own
+        assert sum(own) < window // 2
+    else:
+        assert eng.stats["kv_pages_read"] == window
+
+
+def test_kv_page_read_share_reader():
+    """The benchmark's reader of the two counters
+    (``chipbench/layer_metrics/kv_page_read_share.serve.py``): their
+    ratio in percent; nothing, without raising, from a program that
+    books no such counters (the parent commit's ``stats``) or from a
+    window in which no step was dispatched."""
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "layer_metrics",
+        "kv_page_read_share.serve.py")
+    spec = importlib.util.spec_from_file_location("kv_share", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    read = lambda counters: reader.read({}, {}, counters, None)  # noqa: E731
+    assert read({"steps": 400, "dead_rows": 17000}) is None
+    assert read({"kv_pages_window": 0, "kv_pages_read": 0}) is None
+    assert read({"kv_pages_window": 5120, "kv_pages_read": 5120}) == 100.0
+    assert read({"kv_pages_window": 5120, "kv_pages_read": 1385}) == \
+        pytest.approx(27.05, abs=0.01)
+
+
 @pytest.mark.slow
 def test_paged_int8_kv_agreement():
     """Paged int8-KV (per-(row, token) s8 pages + f32 scale pages)

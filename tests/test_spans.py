@@ -198,9 +198,13 @@ def test_engine_step_encloses_the_five_phases(overlap):
         assert all(a.t1 <= b.t0 for a, b in zip(kids, kids[1:]))
         assert sum(k.t1 - k.t0 for k in kids) <= st.t1 - st.t0
         assert st.parent == 0
-        assert set(st.args) == {"step", "decode", "prefill", "dead"}
+        assert set(st.args) == {"step", "decode", "prefill", "dead",
+                                "pages"}
         assert st.args["decode"] + st.args["prefill"] + st.args["dead"] \
             == eng.n_rows
+        # the gather path (this engine's, on CPU) reads every row's
+        # whole table
+        assert st.args["pages"] == eng.n_rows * eng.pages_per_slot
     assert [s.args["step"] for s in steps] == \
         list(range(steps[0].args["step"], steps[0].args["step"] + n_calls))
     hidden = [s for s in spans if s.name == "engine.plan"
