@@ -29,21 +29,37 @@ from the shapes (``walk_geometry``): for the cell's bf16 pool of 16
 heads of 64, pages are 64 KiB, G = 8 (two 512 KiB slots), F = 2,
 R = 16.
 
-**The per-page grid** (``_page_kernel``) is the older feeder of the
-same fold: grid (T, PP), the BlockSpec index map streams page
-``bt[t, j]`` per grid step, pages past the position skipped by
-``pl.when`` after their copy was paid.  It serves only the pools the
-walk cannot cut whole pages out of — this Mosaic refuses a
-``memref_slice`` whose two minor dims are not whole tiles even where
-it takes them whole: 16-bit pools whose head count is no multiple of
-8 (the ``full`` preset's 12 and its H/tp slices 6 and 3) and int8
-pools, whose ``(ps, H)`` scale planes are never whole tiles
-(ROADMAP C records the debt).  int8-KV pages dequantize inside the
-fold — the k scale multiplies the scores, the v scale folds into the
-softmax weights, exactly where ``_attend_rows`` folds them — reading
-the round-22 TILE-SHAPED scale pages: ``(pages, 2, ps, H)`` f32
-planes (k plane 0, v plane 1; ``serving/paged_kv.py`` owns the
-layout).
+**Grouped-query pools** (PR 28).  A model with fewer key/value heads
+than query heads keeps FLAT pages, ``(ps, Hkv*2*dh)``: each key/value
+head's k then v side by side on the lanes, tokens on the sublanes
+(``serving/paged_kv.py`` chooses the layout and writes it,
+``write_rows``; ``pool_kv.ndim == 3`` tells it here).  With 4 heads the ``(ps, H, 2*dh)`` page's two minor
+dims would be no whole tiles; the flat page is, so the same walk cuts
+it out of HBM with the same copies.  ``_fold_flat`` is the same
+recurrence with the roles of the axes turned: a head's scores are a
+column over the page's tokens, its k and v whole lane tiles read by
+each of the ``Hq / Hkv`` query heads that share them.
+
+**Which pool takes which feeder** (``walk_geometry`` decides, from
+shapes alone).  The walk: every 32-bit pool; 16-bit ``(ps, H, 2*dh)``
+pools whose head count is a multiple of 8 (the benchmark's BERT
+cells, 16 heads); flat 16-bit pools whose pages hold a multiple of 16
+tokens and of 128 lanes (the Falcon-H1 cell, 4 x 2 x 128 lanes at 16
+tokens).  **The per-page grid** (``_page_kernel``), the older feeder
+of the same folds — grid (T, PP), the BlockSpec index map streams
+page ``bt[t, j]`` per grid step, pages past the position skipped by
+``pl.when`` after their copy was paid — serves what is left, because
+this Mosaic refuses a ``memref_slice`` whose two minor dims are not
+whole tiles even where it takes them whole: 16-bit pools whose head
+count is no multiple of 8 (the ``full`` preset's 12 and its H/tp
+slices 6 and 3), flat 16-bit pools with 8-token pages, and int8
+pools, whose ``(ps, H)`` scale planes are never whole tiles (ROADMAP
+C records the debt).  int8-KV pages dequantize inside the fold — the
+k scale multiplies the scores, the v scale folds into the softmax
+weights, exactly where ``_attend_rows`` folds them — reading the
+round-22 TILE-SHAPED scale pages: ``(pages, 2, ps, H)`` f32 planes (k
+plane 0, v plane 1; ``serving/paged_kv.py`` owns the layout).  A flat
+pool has no int8 form and no mesh lowering yet.
 
 The mesh lowering (``mesh=``, round 22): ``paged_attention(...,
 mesh=serving_mesh(tp))`` wraps the same call in ``shard_map`` over
@@ -98,25 +114,41 @@ _GROUP_BYTES = 512 * 1024
 _ROWS = 16
 
 
-def walk_geometry(H, dh, page_size, PP, kv_dtype):
+def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False):
     """``(G, F, R)`` of the walk for one pool geometry — ``G`` pages
-    are copied per DMA group (as many whole ``(page_size, H, 2*dh)``
-    pages as ``_GROUP_BYTES`` holds, at least one, at most a row's
-    table), ``F`` of them are folded per turn of the inner loop (two
-    where G is even: a turn's fixed cost is paid half as often, and a
-    row's last turn folds at most one page it did not need), ``R``
-    rows are walked per grid step — or ``None`` where Mosaic cannot
-    cut whole pages out of the pool and the per-page grid serves
-    instead: a ``memref_slice`` of an HBM ref must be whole tiles in
-    its two minor dims even where it takes them whole, which a 16-bit
-    page is only when H is a multiple of 8 (12, 6 and 3 are refused:
-    "Slice shape along dimension 2 must be aligned to tiling (8)")
-    and an int8 pool's ``(ps, H)`` scale planes never are.  Chosen
-    from shapes alone; the tests read it to aim at the group
+    are copied per DMA group (as many whole pages as ``_GROUP_BYTES``
+    holds, at least one, at most a row's table), ``F`` of them are
+    folded per turn of the inner loop (two where G is even: a turn's
+    fixed cost is paid half as often, and a row's last turn folds at
+    most one page it did not need), ``R`` rows are walked per grid
+    step — or ``None`` where Mosaic cannot cut whole pages out of the
+    pool and the per-page grid serves instead: a ``memref_slice`` of
+    an HBM ref must be whole tiles in its two minor dims even where it
+    takes them whole.
+
+    ``H`` is the pool's head count (the key/value heads), ``flat``
+    which of the two page layouts it has:
+
+    * ``(page_size, H, 2*dh)`` (as many key/value heads as query
+      heads): a 16-bit page is whole tiles only when H is a multiple
+      of 8 (12, 6 and 3 are refused: "Slice shape along dimension 2
+      must be aligned to tiling (8)"), and an int8 pool's ``(ps, H)``
+      scale planes never are;
+    * ``(page_size, H*2*dh)`` (``flat``: grouped-query pools, few
+      key/value heads): whole tiles when the tokens fill the sublanes
+      (8 rows of 32 bits, 16 of 16) and the heads' lanes are a
+      multiple of 128 — 4 heads of 128 in bf16 at 16-token pages walk.
+
+    Chosen from shapes alone; the tests read it to aim at the group
     boundaries."""
     import numpy as np
     kv_dtype = np.dtype(kv_dtype)
-    if kv_dtype == np.int8 or (kv_dtype.itemsize < 4 and H % 8):
+    if kv_dtype == np.int8:
+        return None
+    if flat:
+        if page_size % (32 // kv_dtype.itemsize) or (H * 2 * dh) % 128:
+            return None
+    elif kv_dtype.itemsize < 4 and H % 8:
         return None
     page_bytes = page_size * H * 2 * dh * kv_dtype.itemsize
     G = max(1, min(PP, _GROUP_BYTES // page_bytes))
@@ -185,12 +217,50 @@ def _fold(kv, sc, q, m, l, acc, k0, pos, dh, cdt):
     return m_new, l, acc
 
 
+def _fold_flat(kv, q, m, l, acc, k0, pos, dh, cdt):
+    """``_fold`` for a grouped-query page in the flat layout: ``kv``
+    (n, Hkv*2*dh) holds n tokens, each key/value head's k then v on the
+    lanes; ``q`` (Hq, dh) the row's ``_scaled`` queries, query head i
+    reading key/value head ``i // (Hq / Hkv)``; ``m`` / ``l`` / ``acc``
+    tuples of one (1, 1), (1, 1) and (1, dh) float32 per query head.
+    Tokens lie on the sublanes, so a head's scores are an (n, 1)
+    column, its k and v whole (n, dh) lane tiles cut out once and read
+    by each of the heads that share them.  The same recurrence, the
+    same roundings, the same order of operations as ``_fold``."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    kv = kv.astype(f32)
+    Hq = len(m)
+    rep = Hq // (kv.shape[1] // (2 * dh))
+    live = k0 + jax.lax.broadcasted_iota(
+        jnp.int32, (kv.shape[0], 1), 0) <= pos
+    m2, l2, acc2 = [], [], []
+    for i in range(Hq):
+        c0 = (i // rep) * 2 * dh
+        k, v = kv[:, c0:c0 + dh], kv[:, c0 + dh:c0 + 2 * dh]
+        s = jnp.sum(k * q[i:i + 1, :], axis=-1, keepdims=True)   # (n, 1)
+        if not _scale_folds(dh):
+            s = s / jnp.sqrt(f32(dh))
+        s = jnp.where(live, s, -1e30)
+        m_new = jnp.maximum(m[i], jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m[i] - m_new)
+        l2.append(l[i] * alpha + jnp.sum(p, axis=0, keepdims=True))
+        p = p.astype(cdt).astype(f32)
+        acc2.append(acc[i] * alpha
+                    + jnp.sum(p * v, axis=0, keepdims=True))     # (1, dh)
+        m2.append(m_new)
+    return tuple(m2), tuple(l2), tuple(acc2)
+
+
 def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
-                 page_size, dh, T, PP, G, F, R):
+                 page_size, dh, T, PP, G, F, R, flat):
     """Grid over blocks of R rows; the pool stays in HBM.  Per row a
     loop over groups of G pages, bounded by the row's own position:
     group g+1 (or the next row's first group) is copied into one VMEM
-    slot while group g is folded, F pages a turn, out of the other."""
+    slot while group g is folded, F pages a turn, out of the other.
+    ``flat`` pools (grouped-query) fold with ``_fold_flat``."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -253,6 +323,10 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
                         # by its source
                         copy(0, slot, c * F + f).wait()
                 kv = buf[slot, pl.ds(c * F, F)]
+                if flat:
+                    return _fold_flat(kv.reshape(F * ps, kv.shape[-1]),
+                                      q, *carry, (g * G + c * F) * ps,
+                                      pos, dh, q_ref.dtype)
                 return _fold(kv.reshape(F * ps, H, 2 * dh), None, q,
                              *carry, (g * G + c * F) * ps, pos, dh,
                              q_ref.dtype)
@@ -262,10 +336,21 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
                                           turn, (m, l, acc))
             return m, l, acc, 1 - slot
 
-        init = (jnp.full((H, 1), -jnp.inf, f32), jnp.zeros((H, 1), f32),
-                jnp.zeros((H, 2 * dh), f32), slot)
+        if flat:
+            init = ((jnp.full((1, 1), -jnp.inf, f32),) * H,
+                    (jnp.zeros((1, 1), f32),) * H,
+                    (jnp.zeros((1, dh), f32),) * H, slot)
+        else:
+            init = (jnp.full((H, 1), -jnp.inf, f32),
+                    jnp.zeros((H, 1), f32),
+                    jnp.zeros((H, 2 * dh), f32), slot)
         _, l, acc, slot = jax.lax.fori_loop(0, n_groups, group, init)
-        o_ref[r] = (acc[:, dh:] / l).astype(o_ref.dtype)
+        if flat:
+            for i in range(H):
+                o_ref[r, pl.ds(i, 1), :] = (acc[i] / l[i]).astype(
+                    o_ref.dtype)
+        else:
+            o_ref[r] = (acc[:, dh:] / l).astype(o_ref.dtype)
         return slot
 
     if F > 1:
@@ -280,7 +365,7 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
 
 
 def _page_kernel(bt_ref, pos_ref, q_ref, kv_ref, *rest, page_size, dh,
-                 int8):
+                 int8, flat):
     """Grid (T, PP), the page walk innermost: the BlockSpec index map
     streams page ``bt[t, j]`` per grid step and the online-softmax
     state lives in VMEM scratch across a row's steps.  Only for the
@@ -306,14 +391,30 @@ def _page_kernel(bt_ref, pos_ref, q_ref, kv_ref, *rest, page_size, dh,
     # the row may attend to (its copy has been paid all the same)
     @pl.when(j * page_size <= pos)
     def _page():
-        m_ref[...], l_ref[...], acc_ref[...] = _fold(
-            kv_ref[0], s_ref[0] if int8 else None,
-            _scaled(q_ref[0], dh), m_ref[...], l_ref[...],
-            acc_ref[...], j * page_size, pos, dh, q_ref.dtype)
+        if flat:
+            # the state's rows, one query head each, as _fold_flat
+            # carries them
+            rows = range(q_ref.shape[1])
+            m, l, acc = _fold_flat(
+                kv_ref[0], _scaled(q_ref[0], dh),
+                tuple(m_ref[pl.ds(i, 1), :] for i in rows),
+                tuple(l_ref[pl.ds(i, 1), :] for i in rows),
+                tuple(acc_ref[pl.ds(i, 1), :] for i in rows),
+                j * page_size, pos, dh, q_ref.dtype)
+            for i in rows:
+                m_ref[pl.ds(i, 1), :] = m[i]
+                l_ref[pl.ds(i, 1), :] = l[i]
+                acc_ref[pl.ds(i, 1), :] = acc[i]
+        else:
+            m_ref[...], l_ref[...], acc_ref[...] = _fold(
+                kv_ref[0], s_ref[0] if int8 else None,
+                _scaled(q_ref[0], dh), m_ref[...], l_ref[...],
+                acc_ref[...], j * page_size, pos, dh, q_ref.dtype)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _out():
-        o_ref[0] = (acc_ref[:, dh:] / l_ref[...]).astype(o_ref.dtype)
+        acc = acc_ref[...] if flat else acc_ref[:, dh:]
+        o_ref[0] = (acc / l_ref[...]).astype(o_ref.dtype)
 
 
 # bounded cache of built pallas_call closures, keyed on every
@@ -324,39 +425,50 @@ _CALL_CACHE_MAX = 32
 
 
 def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
-           int8, interpret):
+           int8, interpret, Hkv=None):
+    """The ``pallas_call`` for one geometry.  ``Hkv`` given: a flat
+    grouped-query pool ``(pages, page_size, Hkv*2*dh)`` under ``H``
+    query heads; None: ``(pages, page_size, H, 2*dh)``."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     key = (T, H, dh, PP, page_size, num_pages, str(kv_dtype),
-           str(q_dtype), int8, interpret)
+           str(q_dtype), int8, interpret, Hkv)
     fn = _call_cache.get(key)
     if fn is not None:
         return fn
 
-    geometry = walk_geometry(H, dh, page_size, PP, kv_dtype)
+    flat = Hkv is not None
+    # a page as the pool holds it, and a row's queries: zero-extended
+    # over the v lanes for the (H, 2*dh) fold, bare for the flat one
+    page = (page_size, Hkv * 2 * dh) if flat else (page_size, H, 2 * dh)
+    qw = dh if flat else 2 * dh
+    zeros = (0,) * len(page)
+    geometry = walk_geometry(Hkv if flat else H, dh, page_size, PP,
+                             kv_dtype, flat=flat)
     if geometry is not None:
         G, F, R = geometry
         R = min(R, T)
         grid = (-(-T // R),)
         in_specs = [
-            pl.BlockSpec((R, H, 2 * dh), lambda b, bt, pos: (b, 0, 0)),
+            pl.BlockSpec((R, H, qw), lambda b, bt, pos: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
         ]
         out_specs = pl.BlockSpec((R, H, dh),
                                  lambda b, bt, pos: (b, 0, 0))
-        scratch = [pltpu.VMEM((2, G, page_size, H, 2 * dh), kv_dtype),
+        scratch = [pltpu.VMEM((2, G) + page, kv_dtype),
                    pltpu.SemaphoreType.DMA((2, G))]
         body = functools.partial(_walk_kernel, page_size=page_size,
-                                 dh=dh, T=T, PP=PP, G=G, F=F, R=R)
+                                 dh=dh, T=T, PP=PP, G=G, F=F, R=R,
+                                 flat=flat)
     else:
         grid = (T, PP)
         in_specs = [
-            pl.BlockSpec((1, H, 2 * dh), lambda t, j, bt, pos: (t, 0, 0)),
-            pl.BlockSpec((1, page_size, H, 2 * dh),
-                         lambda t, j, bt, pos: (bt[t * PP + j], 0, 0, 0)),
+            pl.BlockSpec((1, H, qw), lambda t, j, bt, pos: (t, 0, 0)),
+            pl.BlockSpec((1,) + page,
+                         lambda t, j, bt, pos: (bt[t * PP + j],) + zeros),
         ]
         if int8:
             # scale block: (2, ps, H) — two (ps, heads) planes indexed
@@ -368,9 +480,9 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
                                  lambda t, j, bt, pos: (t, 0, 0))
         scratch = [pltpu.VMEM((H, 1), jnp.float32),
                    pltpu.VMEM((H, 1), jnp.float32),
-                   pltpu.VMEM((H, 2 * dh), jnp.float32)]
+                   pltpu.VMEM((H, qw), jnp.float32)]
         body = functools.partial(_page_kernel, page_size=page_size,
-                                 dh=dh, int8=int8)
+                                 dh=dh, int8=int8, flat=flat)
     fn = pl.pallas_call(
         body,
         out_shape=jax.ShapeDtypeStruct((T, H, dh), jnp.float32),
@@ -394,7 +506,10 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
     q : (T, H, dh) compute-dtype queries, one per decode row.
     pool_kv : (num_pages, page_size, H, 2*dh) page pool — the
         ``PagedKVCache`` layout (k and v halves fused on the last
-        axis); cfg dtype, or int8 when ``pool_s`` is given.
+        axis); cfg dtype, or int8 when ``pool_s`` is given.  Or the
+        flat grouped-query layout (num_pages, page_size, Hkv*2*dh),
+        Hkv dividing H: query head i reads key/value head
+        ``i // (H / Hkv)``.
     pool_s : (num_pages, 2, page_size, H) f32 dequant scales for the
         int8-KV pool (``models/gpt.py _kv_quantize`` values in the
         round-22 tile-shaped plane layout — plane 0 k, plane 1 v),
@@ -430,11 +545,30 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
         raise ValueError("paged_attention: pool page_size %d != %d"
                          % (pool_kv.shape[1], page_size))
     int8 = pool_s is not None
+    Hkv = None
+    if pool_kv.ndim == 3:
+        Hkv = pool_kv.shape[2] // (2 * dh)
+        if Hkv * 2 * dh != pool_kv.shape[2] or H % max(Hkv, 1):
+            raise ValueError(
+                "paged_attention: a flat pool's pages are (page_size, "
+                "Hkv*2*dh) with Hkv dividing the %d query heads; got "
+                "%r at dh=%d" % (H, tuple(pool_kv.shape), dh))
+        if int8 or mesh is not None:
+            raise ValueError("paged_attention: a flat (grouped-query) "
+                             "pool has no int8 scale planes and no "
+                             "mesh lowering")
+    elif pool_kv.shape[2] != H:
+        raise ValueError("paged_attention: pool of %d heads under %d "
+                         "query heads (grouped-query pools are flat: "
+                         "(pages, page_size, Hkv*2*dh))"
+                         % (pool_kv.shape[2], H))
     # q zero-extended over the v half of a page's lanes (the kernel's
-    # one full-width product then contracts q with k alone)
+    # one full-width product then contracts q with k alone); the flat
+    # fold cuts k out of the page and takes q as it is
     args = [block_tables.reshape(-1).astype(jnp.int32),
             row_pos.astype(jnp.int32),
-            jnp.concatenate([q, jnp.zeros_like(q)], axis=-1), pool_kv]
+            q if Hkv else jnp.concatenate([q, jnp.zeros_like(q)],
+                                          axis=-1), pool_kv]
     if int8:
         args.append(pool_s)
 
@@ -449,7 +583,7 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
 
     def call(interp):
         fn = _build(T, H // tp, dh, PP, page_size, num_pages,
-                    pool_kv.dtype, q.dtype, int8, interp)
+                    pool_kv.dtype, q.dtype, int8, interp, Hkv)
         if tp_axis is None:
             return fn
         from jax.sharding import PartitionSpec as P
@@ -486,8 +620,16 @@ def paged_attention_reference(q, pool_kv, pool_s, block_tables,
     L = PP * page_size
     with jax.named_scope("paged_attn"):
         with jax.named_scope("gather"):
-            ckv = pool_kv[block_tables].transpose(0, 3, 1, 2, 4) \
-                .reshape(T * H, L, 2 * dh)
+            if pool_kv.ndim == 3:
+                # flat grouped-query pool: each key/value head's view
+                # repeated for the query heads that share it
+                Hkv = pool_kv.shape[2] // (2 * dh)
+                ckv = pool_kv[block_tables].reshape(T, L, Hkv, 2 * dh)
+                ckv = jnp.repeat(ckv.transpose(0, 2, 1, 3), H // Hkv,
+                                 axis=1).reshape(T * H, L, 2 * dh)
+            else:
+                ckv = pool_kv[block_tables].transpose(0, 3, 1, 2, 4) \
+                    .reshape(T * H, L, 2 * dh)
             cs = None
             if pool_s is not None:
                 # retiled plane layout (num_pages, 2, ps, H): gather

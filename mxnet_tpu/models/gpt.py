@@ -256,6 +256,75 @@ def _kv_quantize(k, v):
             jnp.stack([sk, sv], axis=-1))
 
 
+# ------------------------------------------------ the serving protocol --
+# What ``serving/engine.py``'s step program is built from, for this
+# family: it asks the model for its embedding, its block and its
+# sampling rows' logits, and hands the block the attention over its own
+# paged K/V (``attend(q, k, v)`` writes the rows' keys and values, then
+# reads each row's sequence back).  ``models/falcon_h1.py`` has the same
+# three under the same names.
+
+def serve_embed(params, cfg, tokens, row_pos):
+    """(T,) ids at positions ``row_pos`` -> (T, D) rows."""
+    import jax.numpy as jnp
+    cdt = jnp.dtype(cfg.dtype)
+    x = _embed(params, tokens, cdt)                # (T, D)
+    x = x + params["pos_emb"][row_pos].astype(cdt)
+    return T._layer_norm(x, params["emb_ln"]["g"].astype(cdt),
+                         params["emb_ln"]["b"].astype(cdt))
+
+
+def serve_block(layer, cfg, x, row_pos, attend, state=None):
+    """One post-LN block on (T, D) rows; ``attend(q, k, v)`` over
+    (T, H, dh) each returns (T, H, dh) float32.  ``state`` is the slot
+    state of families that keep one; this one keeps pages alone."""
+    import jax
+    import jax.numpy as jnp
+    cdt = jnp.dtype(cfg.dtype)
+    D, H = cfg.d_model, cfg.n_heads
+    dh = D // H
+    n = x.shape[0]
+
+    def dn(w):
+        return w.astype(cdt)
+    with jax.named_scope("qkv"):
+        qkv = _qkv(layer, x, cdt)                  # (T, 3D)
+        q = qkv[:, :D].reshape(n, H, dh)
+        k = qkv[:, D:2 * D].reshape(n, H, dh)
+        v = qkv[:, 2 * D:].reshape(n, H, dh)
+    attn = attend(q, k, v)
+    with jax.named_scope("attn_out"):
+        attn = attn.reshape(n * H, dh)             # (T*H, dh) f32
+        attn = attn.astype(cdt)
+        attn = _wmm(attn.reshape(n, D), layer["wo"], cdt) + \
+            dn(layer["bo"])
+        x = T._layer_norm(x + attn, dn(layer["ln1"]["g"]),
+                          dn(layer["ln1"]["b"]))
+    with jax.named_scope("ffn"):
+        if "moe" in layer:
+            from ..parallel.moe import moe_ffn
+            h, _ = moe_ffn(x[:, None, :], layer["moe"],
+                           n_experts=cfg.n_experts,
+                           top_k=cfg.expert_top_k,
+                           capacity_factor=cfg.capacity_factor,
+                           dtype=cdt)
+            h = h[:, 0, :]
+        else:
+            h = jax.nn.gelu(
+                _wmm(x, layer["w1"], cdt) + dn(layer["b1"]),
+                approximate=True)
+            h = _wmm(h, layer["w2"], cdt) + dn(layer["b2"])
+        return T._layer_norm(x + h, dn(layer["ln2"]["g"]),
+                             dn(layer["ln2"]["b"]))
+
+
+def serve_logits(params, cfg, x, slot_rows):
+    """Float32 logits at the (S, n) sampling rows: (S, n, V).  The head
+    runs over all rows and the sampling rows are picked from it."""
+    import jax.numpy as jnp
+    return _lm_head(params, x, jnp.dtype(cfg.dtype))[slot_rows]
+
+
 def _attend_rows(q, ckv, cs, pos, dh):
     """Single-token attention over a fused (R, L, 2*dh) KV view.
 

@@ -96,6 +96,29 @@ per-row skip suffices, and the only fence is speculative decode
 ``overlap=False`` (the default) is bit-for-bit the round-20 engine:
 same compiled program, same host schedule, same commit order.
 
+The step program is built from a MODEL MODULE's three functions,
+``serve_embed`` / ``serve_block`` / ``serve_logits``: ``models/gpt.py``
+for a ``TransformerConfig``, or the module a config object names itself
+(``cfg.serving``; ``models/falcon_h1.py``).  The engine reads the family
+from the config it is given — no option selects it — and supplies what a
+block keeps between steps: ``attend(q, k, v)``, which writes the rows'
+keys and values into their pages and reads each row's sequence back
+through the block table, and, for a family whose sequences keep state
+that is no page (``slot_state_shapes``: Falcon-H1's convolution window
+and SSM state), a SLOT-STATE backend over one ``(num_slots + 1, ...)``
+pool per layer beside the pages (``serving/paged_kv.py``).  A slot's
+state starts from zero at the slot's first prefill chunk — the planner
+fills a per-slot ``fresh`` mask, staged with the rows, and the program
+masks the pool's old content as it reads it: no dispatch of its own —
+carries across chunks and decode steps, and is rebuilt by recomputation
+after a preemption (``resume_input`` re-prefills from position 0).
+Dead rows point at the scratch slot ``num_slots`` as they point at the
+scratch page.  Such a family's context is bounded by the pool
+(``max_seq``), not by a position table, and the engine refuses for it,
+by name, what would need snapshots or rollback of that state:
+``prefix_cache``, ``spec_K``, the KV tier, ``admit_prefilled``,
+``kv_int8``, ``tp > 1`` (ROADMAP B-m6).
+
 Exactness: under f32 greedy, engine outputs are token-identical to
 ``models/gpt.py generate`` per request, whatever the batch mix,
 admission order, page reuse, preemptions, swap-outs, kernel choice,
@@ -130,7 +153,8 @@ import numpy as np
 from .. import profiler
 from ..models import gpt as G
 from . import drafters
-from .paged_kv import PagedKVCache
+from .paged_kv import (PagedKVCache, kv_geometry, slot_state_shapes,
+                       write_rows)
 from .prefix_cache import PrefixCache
 from .tier_store import HostTierStore
 
@@ -226,6 +250,10 @@ class Request:
     n_prefilled: int = 0                  # input rows already fed
     n_cached: int = 0                     # positions written to cache
     pending: Optional[int] = None         # sampled, not yet in cache
+    # admissions so far: a plan records the one it was built in, so a
+    # step still in flight across a preemption AND the re-admission is
+    # told from the request's new life (neither carried nor committed)
+    admit_seq: int = 0
     # shared-prefix bookkeeping (round 10; empty when the engine runs
     # without a prefix cache)
     prefix_entries: List[Any] = dataclasses.field(default_factory=list)
@@ -334,6 +362,11 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
     needed for the spec tree's structure only — float vs weight-only
     int8).
 
+    For a family with per-slot state the program takes one more input
+    after ``slot_rows``: ``slot_fresh``, (num_slots + 1,) bool, the
+    slots whose state starts from zero in this step; the state pools
+    ride in ``pools`` and are donated with the pages.
+
     With ``overlap`` (round 21, latency-hiding scheduling) the
     program takes two extra inputs: ``prev_tok``, the PREVIOUS step's
     device-resident ``(S, n_sample)`` argmax matrix, and ``tok_src``,
@@ -362,22 +395,22 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
     if fn is not None:
         return fn
 
-    cdt = jnp.dtype(cfg.dtype)
-    D, H = cfg.d_model, cfg.n_heads
-    dh = D // H
-    T = n_rows
+    # the model code behind the config: its embedding, its block and
+    # its sampling rows' logits (``serve_*`` of models/gpt.py, or of
+    # the module a config names itself); the engine supplies what a
+    # block keeps between steps — the paged K/V behind ``attend`` and,
+    # for a family with ``slot_state_shapes``, the per-slot state
+    model = getattr(cfg, "serving", G)
+    state_names = tuple(slot_state_shapes(cfg))
 
     def _body(params, pools, tokens, row_slot, row_pos, row_live, bt,
-              slot_rows):
+              slot_rows, slot_fresh=None):
         # the named scopes are metadata of the compiled operations (a
         # device trace groups by them; no layer index, so the layers
         # add up under one name): the program computes what it did
         # without them
         with jax.named_scope("embed"):
-            x = G._embed(params, tokens, cdt)          # (T, D)
-            x = x + params["pos_emb"][row_pos].astype(cdt)
-            x = G.T._layer_norm(x, params["emb_ln"]["g"].astype(cdt),
-                                params["emb_ln"]["b"].astype(cdt))
+            x = model.serve_embed(params, cfg, tokens, row_pos)
 
         # dead rows write to the scratch page and read garbage the
         # host never looks at; bt carries one extra all-zero row
@@ -392,42 +425,40 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
 
         new_pools = []
         for layer, pool in zip(params["layers"], pools):
-            def dn(w):
-                return w.astype(cdt)
-            with jax.named_scope("qkv"):
-                qkv = G._qkv(layer, x, cdt)            # (T, 3D)
-                q = qkv[:, :D].reshape(T, H, dh)
-                k = qkv[:, D:2 * D].reshape(T, H, dh)
-                v = qkv[:, 2 * D:].reshape(T, H, dh)
-
-            with jax.named_scope("kv_write"):
-                if kv_int8:
-                    kvq, skv = G._kv_quantize(k, v)    # (T, H, 2dh/2)
-                    pool_kv = pool["kv"].at[page, off].set(kvq)
-                    # retiled scale planes (paged_kv.py): the (N, 2,
-                    # ps, H) pool takes row r's scales at [page_r, :,
-                    # off_r] — a (T, 2, H) update, so _kv_quantize's
-                    # (T, H, 2) transposes once here
-                    pool_s = pool["s"].at[page, :, off].set(
-                        skv.transpose(0, 2, 1))
-                    new_pools.append({"kv": pool_kv, "s": pool_s})
-                else:
-                    newkv = jnp.concatenate([k, v], axis=-1).astype(cdt)
-                    pool_kv = pool["kv"].at[page, off].set(newkv)
-                    pool_s = None
-                    new_pools.append({"kv": pool_kv})
-            if kernel == "pallas":
-                # fused block-table walk (kernels/paged_attention.py):
-                # each row's live pages are copied HBM->VMEM once,
-                # online-softmax accumulation, int8 dequant in the
-                # inner loop — no gathered view is ever materialized.
-                # With a mesh the call shard_maps over tp: each device
-                # walks its own H/tp heads slice of the pools (round 22)
-                from ..kernels.paged_attention import paged_attention
-                attn = paged_attention(q, pool_kv, pool_s, row_pages,
-                                       row_pos, page_size=page_size,
-                                       mesh=mesh)
-            else:
+            def attend(q, k, v, pool=pool):
+                """Write the rows' k/v into their pages, then each
+                row's attention over its own block table: (T, H, dh)
+                float32.  Appends the layer's updated pools."""
+                with jax.named_scope("kv_write"):
+                    if kv_int8:
+                        kvq, skv = G._kv_quantize(k, v)  # (T, H, 2dh/2)
+                        pool_kv = pool["kv"].at[page, off].set(kvq)
+                        # retiled scale planes (paged_kv.py): the (N,
+                        # 2, ps, H) pool takes row r's scales at
+                        # [page_r, :, off_r] — a (T, 2, H) update, so
+                        # _kv_quantize's (T, H, 2) transposes once here
+                        pool_s = pool["s"].at[page, :, off].set(
+                            skv.transpose(0, 2, 1))
+                        new_pools.append({"kv": pool_kv, "s": pool_s})
+                    else:
+                        pool_kv = write_rows(pool["kv"], page, off, k, v)
+                        pool_s = None
+                        new_pools.append({"kv": pool_kv})
+                if kernel == "pallas":
+                    # fused block-table walk
+                    # (kernels/paged_attention.py): each row's live
+                    # pages are copied HBM->VMEM once, online-softmax
+                    # accumulation, int8 dequant in the inner loop —
+                    # no gathered view is ever materialized.  With a
+                    # mesh the call shard_maps over tp: each device
+                    # walks its own H/tp heads slice of the pools
+                    # (round 22)
+                    from ..kernels.paged_attention import \
+                        paged_attention
+                    return paged_attention(q, pool_kv, pool_s,
+                                           row_pages, row_pos,
+                                           page_size=page_size,
+                                           mesh=mesh)
                 # block-table gather + _attend_rows — ONE copy of the
                 # gather lives in kernels/paged_attention.py, shared
                 # with the tests' oracle, so the engine path and the
@@ -436,48 +467,35 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
                 # k/v, same as the contiguous DUS order)
                 from ..kernels.paged_attention import \
                     paged_attention_reference
-                attn = paged_attention_reference(
+                return paged_attention_reference(
                     q, pool_kv, pool_s, row_pages, row_pos,
                     page_size=page_size)
-            with jax.named_scope("attn_out"):
-                attn = attn.reshape(T * H, dh)         # (T*H, dh) f32
-                attn = attn.astype(cdt)
-                attn = G._wmm(attn.reshape(T, D), layer["wo"], cdt) + \
-                    dn(layer["bo"])
-                x = G.T._layer_norm(x + attn, dn(layer["ln1"]["g"]),
-                                    dn(layer["ln1"]["b"]))
-            with jax.named_scope("ffn"):
-                if "moe" in layer:
-                    from ..parallel.moe import moe_ffn
-                    h, _ = moe_ffn(x[:, None, :], layer["moe"],
-                                   n_experts=cfg.n_experts,
-                                   top_k=cfg.expert_top_k,
-                                   capacity_factor=cfg.capacity_factor,
-                                   dtype=cdt)
-                    h = h[:, 0, :]
-                else:
-                    h = jax.nn.gelu(
-                        G._wmm(x, layer["w1"], cdt) + dn(layer["b1"]),
-                        approximate=True)
-                    h = G._wmm(h, layer["w2"], cdt) + dn(layer["b2"])
-                x = G.T._layer_norm(x + h, dn(layer["ln2"]["g"]),
-                                    dn(layer["ln2"]["b"]))
+
+            # a slot's state that is no page: the layer's (num_slots
+            # + 1, ...) pools, the rows' slots (dead rows the scratch
+            # slot) and which slots start from zero this step
+            state = model.SlotState(
+                {name: pool[name] for name in state_names}, row_slot,
+                slot_fresh, n_rows - num_slots * n_sample) \
+                if state_names else None
+            x = model.serve_block(layer, cfg, x, row_pos, attend, state)
+            if state_names:
+                new_pools[-1].update(state.pools)
 
         with jax.named_scope("head"):
-            logits = G._lm_head(params, x, cdt)        # (T, V) f32
-        # (S, n_sample) argmaxes: column 0 is the slot's sampling row
-        # (the old slot_last_row), columns 1.. are its draft-verify
-        # rows; dead columns point at row 0 and the host never reads
-        # them
+            # (S, n_sample, V) f32: column 0 is the slot's sampling
+            # row (the old slot_last_row), columns 1.. are its
+            # draft-verify rows; dead columns point at row 0 and the
+            # host never reads them
+            slot_logits = model.serve_logits(params, cfg, x, slot_rows)
         with jax.named_scope("sample"):
-            slot_logits = logits[slot_rows]            # (S, n_s, V)
             next_tok = jnp.argmax(slot_logits,
                                   axis=-1).astype(jnp.int32)
         return next_tok, new_pools
 
     if overlap:
         def step(params, pools, tokens, row_slot, row_pos, row_live,
-                 bt, slot_rows, prev_tok, tok_src):
+                 bt, slot_rows, *rest):
             # device-carried inputs: rows with tok_src >= 0 read the
             # previous step's argmax for that slot straight off the
             # device (column 0 = the slot's sampling row); everything
@@ -485,12 +503,14 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
             # padding — keeps its host-fed token.  An exact int32
             # select: carried steps compute bit-identically to the
             # serial schedule that would have fed the same token.
+            # (``rest``: a stateful family's ``slot_fresh`` first.)
+            prev_tok, tok_src = rest[-2:]
             eff = jnp.where(
                 tok_src >= 0,
                 prev_tok[jnp.clip(tok_src, 0, num_slots - 1), 0],
                 tokens)
             return _body(params, pools, eff, row_slot, row_pos,
-                         row_live, bt, slot_rows)
+                         row_live, bt, slot_rows, *rest[:-2])
     else:
         step = _body
 
@@ -517,7 +537,7 @@ class _StepBuffers:
     (round-21 satellite: no fresh numpy allocations per step)."""
 
     __slots__ = ("tokens", "row_slot", "row_pos", "row_live",
-                 "tok_src", "slot_rows", "bt")
+                 "tok_src", "slot_rows", "bt", "fresh")
 
     def __init__(self, n_rows, num_slots, spec_K, pages_per_slot):
         T, S = n_rows, num_slots
@@ -528,6 +548,9 @@ class _StepBuffers:
         self.tok_src = np.full(T, -1, np.int32)
         self.slot_rows = np.zeros((S, 1 + spec_K), np.int32)
         self.bt = np.zeros((S + 1, pages_per_slot), np.int32)
+        # slots whose state that is no page starts from zero this step
+        # (staged only for a family that keeps such state)
+        self.fresh = np.zeros(S + 1, bool)
 
     def reset(self, num_slots):
         self.tokens.fill(0)
@@ -536,6 +559,7 @@ class _StepBuffers:
         self.row_live.fill(False)
         self.tok_src.fill(-1)
         self.slot_rows.fill(0)
+        self.fresh.fill(False)
 
 
 class _Plan:
@@ -549,7 +573,8 @@ class _Plan:
                  "was_decode", "prefill_mid", "n_dec_rows",
                  "n_pre_rows", "n_rows_used", "decode_rids",
                  "prefill_spans", "carried", "fenced", "empty",
-                 "pipelined", "kv_pages")
+                 "pipelined", "kv_pages", "state_slots", "resets",
+                 "admit")
 
     def __init__(self):
         self.buf = None
@@ -568,6 +593,15 @@ class _Plan:
         self.empty = True           # no live rows
         self.pipelined = False      # built for the overlap path
         self.kv_pages = 0           # K/V pages the step's attention reads
+        self.state_slots = 0        # slots whose state the step updates
+        self.resets = 0             # of them, started from zero
+        self.admit = {}             # rid -> its admit_seq at build
+
+    def current(self, req):
+        """Whether ``req`` still lives the admission this plan's rows
+        were built in (and holds a slot)."""
+        return req.slot is not None and req.state == "running" \
+            and self.admit.get(req.rid) == req.admit_seq
 
 
 def _planner_main(engine_ref, ctl, go, ready):
@@ -854,7 +888,9 @@ class ServingEngine:
     ----------
     params, cfg : the GPT decode params/config (float or
         ``quantize_decode_params`` weight-only int8 — same formats as
-        ``generate``).
+        ``generate``), or another family's: a config object that names
+        its model module (``models/falcon_h1.py FalconH1Config``) with
+        that module's parameter tree.
     num_slots : concurrent sequences per iteration (the decode batch).
     page_size : tokens per KV page.
     num_pages : pool capacity; default fully provisions every slot
@@ -951,6 +987,10 @@ class ServingEngine:
                  tier_bytes=None, overlap=None, device=None):
         if not cfg.causal:
             cfg = dataclasses.replace(cfg, causal=True)
+        # a family whose sequences keep state that is no page (its
+        # serving module's ``slot_state_shapes``): one state per slot
+        # and layer beside the paged K/V, see ``_build_plan``
+        self._stateful = bool(slot_state_shapes(cfg))
         if num_slots < 1:
             raise ValueError("ServingEngine: num_slots must be >= 1")
         if prefill_chunk < 1:
@@ -976,6 +1016,36 @@ class ServingEngine:
             tp = int(mesh.shape["tp"])
         if tp < 1:
             raise ValueError("ServingEngine: tp must be >= 1")
+        if tier_bytes is None:
+            env = os.environ.get("MXNET_SERVE_TIER_BYTES", "")
+            try:
+                tier_bytes = int(env) if env else 0
+            except ValueError:
+                raise ValueError(
+                    "MXNET_SERVE_TIER_BYTES=%r: expected int" % env)
+        if self._stateful:
+            # each of these moves, shares or rolls back a sequence's
+            # cache as PAGES; a slot's recurrent state is none, and
+            # has no snapshot, rollback or sharded layout yet
+            # (ROADMAP B-m)
+            for on, what in (
+                    (prefix_cache, "prefix_cache=True: a shared prefix "
+                     "is K/V pages; the state after it has no snapshot "
+                     "to restore"),
+                    (spec_K > 0, "spec_K > 0: rejected drafts roll "
+                     "back by page pointer; the state has no rollback"),
+                    (tier_bytes, "tier_bytes > 0 (KV tier / swap): a "
+                     "victim's pages swap out and in; its state has no "
+                     "snapshot, resume is by recomputation"),
+                    (kv_int8, "kv_int8=True: no int8 layout for a "
+                     "grouped-query pool"),
+                    (tp > 1, "tp > 1: no sharded layout for the "
+                     "key/value heads and the per-slot state")):
+                if on:
+                    raise ValueError(
+                        "ServingEngine: %s keeps per-slot recurrent "
+                        "state; refused with %s"
+                        % (type(cfg).__name__, what))
         if device is not None and tp > 1:
             raise ValueError("ServingEngine: device= places a tp=1 "
                              "engine; a tp>1 engine is placed by its "
@@ -1010,6 +1080,11 @@ class ServingEngine:
         # every backend — the live_axis argument in parallel/mesh.py)
         self.mesh = mesh if tp > 1 else None
         if pages_per_slot is None:
+            if cfg.max_len is None:
+                raise ValueError(
+                    "ServingEngine: %s has no position table; give "
+                    "pages_per_slot (the context bound is the pool's)"
+                    % type(cfg).__name__)
             pages_per_slot = -(-cfg.max_len // page_size)
         # the attention view may be wider than cfg.max_len (its tail
         # is masked scratch); positions are bounded by submit()'s
@@ -1055,7 +1130,8 @@ class ServingEngine:
         self.n_rows = num_slots * (1 + self.spec_K) + prefill_chunk
         self.cache = PagedKVCache(cfg, num_pages, page_size,
                                   kv_int8=self.kv_int8,
-                                  mesh=self.mesh, device=device)
+                                  mesh=self.mesh, device=device,
+                                  num_slots=num_slots)
         # one attention, two lowerings; where none is asked for, the
         # platform the pools were just placed on says which is fast
         # (the step program runs where its pools live)
@@ -1068,20 +1144,14 @@ class ServingEngine:
         # walk, on a pool it can cut pages out of) or reads the whole
         # (rows x pages_per_slot) window: what kv_pages_read books
         from ..kernels.paged_attention import walk_geometry
+        kv_heads, head_dim, flat_kv = kv_geometry(cfg)
         self._walks_pages = kernel == "pallas" and walk_geometry(
-            cfg.n_heads // tp, cfg.d_model // cfg.n_heads, page_size,
-            pages_per_slot, self.cache.pools[0]["kv"].dtype) is not None
+            kv_heads // tp, head_dim, page_size, pages_per_slot,
+            self.cache.pools[0]["kv"].dtype, flat=flat_kv) is not None
         # host-DRAM KV tier (round 18): explicit argument >
         # MXNET_SERVE_TIER_BYTES env > off.  0/None disables — every
         # pre-tier behavior (drop on pressure, recompute on resume)
         # is preserved bit for bit with the tier off.
-        if tier_bytes is None:
-            env = os.environ.get("MXNET_SERVE_TIER_BYTES", "")
-            try:
-                tier_bytes = int(env) if env else 0
-            except ValueError:
-                raise ValueError(
-                    "MXNET_SERVE_TIER_BYTES=%r: expected int" % env)
         self.tier = HostTierStore(tier_bytes) if tier_bytes else None
         # shared-prefix page reuse (round 10): content-keyed trie over
         # the pool; the allocator's pressure callback evicts (round
@@ -1130,6 +1200,17 @@ class ServingEngine:
                       "host_hidden_ms": 0.0, "overlap_steps": 0,
                       "overlap_fences": 0, "kv_pages_window": 0,
                       "kv_pages_read": 0}
+        if self._stateful:
+            # slot-states read and written (the live slots of each
+            # dispatched step), those started from zero, and the bytes
+            # the recurrence REQUIRES: one read and one write of a live
+            # slot's state per layer.  What the program moves is no
+            # less: ``slot_scan``'s single-row pass reads and rewrites
+            # the whole (num_slots + 1) pool every step and a chunk's
+            # slot is touched once more in its loop, so the two agree
+            # only while every slot is live with one row
+            self.stats.update(ssm_state_updates=0, ssm_state_resets=0,
+                              ssm_state_bytes=0)
         # -------- round 21: scheduler/planner shared state ---------
         # One lock (_mu) guards everything BOTH the engine thread and
         # the planner thread touch: queue/slots/pages/prefix/stats and
@@ -1195,7 +1276,8 @@ class ServingEngine:
         # the final sampled token never enters the cache, so cache
         # positions top out at total - 1 <= max_len (same contract as
         # generate: P + max_new <= cfg.max_len)
-        if total > self.cfg.max_len:
+        # (a model with no position table is bound by the pool alone)
+        if self.cfg.max_len is not None and total > self.cfg.max_len:
             raise ValueError("submit: %d tokens > cfg.max_len=%d"
                              % (total, self.cfg.max_len))
         now = time.perf_counter()
@@ -1234,6 +1316,12 @@ class ServingEngine:
         Raises if no slot is free — the caller (the decode worker
         loop) checks ``free_slots`` first and re-tries later rather
         than queueing device pages behind a full engine."""
+        if self._stateful:
+            raise ValueError(
+                "admit_prefilled: %s keeps per-slot recurrent state; "
+                "the disaggregated hand-off moves K/V pages and has no "
+                "snapshot of that state to install"
+                % type(self.cfg).__name__)
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         generated = [int(t) for t in generated]
         if not generated:
@@ -1243,9 +1331,10 @@ class ServingEngine:
         if prompt.size < 1:
             raise ValueError("admit_prefilled: empty prompt")
         total = prompt.size + max_new_tokens
-        if total > self.max_seq or total > self.cfg.max_len:
+        if total > self.max_seq or (self.cfg.max_len is not None
+                                    and total > self.cfg.max_len):
             raise ValueError(
-                "admit_prefilled: %d tokens > max_seq %d / max_len %d"
+                "admit_prefilled: %d tokens > max_seq %d / max_len %r"
                 % (total, self.max_seq, self.cfg.max_len))
         with self._mu:
             free = [i for i, r in enumerate(self._slots)
@@ -1272,6 +1361,7 @@ class ServingEngine:
             req.pages = list(pages)
             req.slot = free[0]
             req.state = "running"
+            req.admit_seq += 1
             self.requests[rid] = req
             self._slots[req.slot] = req
             self._bt_set(req.slot, req.pages)
@@ -1541,6 +1631,7 @@ class ServingEngine:
                 req.prefix_hit_tokens = skip
             req.slot = free_slots[0]
             req.state = "running"
+            req.admit_seq += 1
             req.n_prefilled = skip
             req.n_cached = skip
             req.pending = None
@@ -1600,6 +1691,7 @@ class ServingEngine:
         req.chain_upto = 0
         req.slot = slot
         req.state = "running"
+        req.admit_seq += 1
         req.n_cached = entry.meta["n_cached"]
         req.pending = entry.meta["pending"]
         # a decode-phase victim resumes fully prefilled; a victim
@@ -1715,6 +1807,15 @@ class ServingEngine:
             return _NO_SPAN
         return profiler.span("serving_step", cat="operator")
 
+    def _span_args(self, sp, plan):
+        """What the step's ``engine.step`` span says of its plan."""
+        args = {"decode": plan.n_dec_rows, "prefill": plan.n_pre_rows,
+                "dead": self.n_rows - plan.n_rows_used,
+                "pages": plan.kv_pages}
+        if self._stateful:
+            args["resets"] = plan.resets
+        sp.set(**args)
+
     def _step_serial(self, sp):
         """One fully-serial iteration — the round-20 schedule exactly:
         build (phases A+B, under the lock), dispatch, block on the
@@ -1722,8 +1823,7 @@ class ServingEngine:
         call's ``engine.step`` span."""
         with profiler.span("engine.plan"), self._mu:
             plan = self._build_plan(overlap=False)
-        sp.set(decode=plan.n_dec_rows, prefill=plan.n_pre_rows,
-               dead=self.n_rows - plan.n_rows_used, pages=plan.kv_pages)
+        self._span_args(sp, plan)
         with self._operator_span():
             next_tok = self._dispatch(plan)
             with profiler.span("engine.wait") as wait:
@@ -1762,8 +1862,7 @@ class ServingEngine:
                 finished += self._step_serial(sp)
             self._maybe_plan_ahead()
             return finished
-        sp.set(decode=plan.n_dec_rows, prefill=plan.n_pre_rows,
-               dead=self.n_rows - plan.n_rows_used, pages=plan.kv_pages)
+        self._span_args(sp, plan)
         old, old_tok = self._inflight, self._inflight_tok
         if not plan.empty:
             with self._operator_span():
@@ -1898,7 +1997,7 @@ class ServingEngine:
         carried = {}                   # rid -> device-carried position
         if inflight is not None:
             for req in inflight.samplers:
-                if req.slot is None or req.state != "running":
+                if not inflight.current(req):
                     continue           # preempted/cancelled mid-flight
                 if len(req.generated) + 1 >= req.max_new_tokens:
                     # the in-flight token predictably finishes this
@@ -1908,8 +2007,11 @@ class ServingEngine:
                 pos = inflight.decode_pos[req.rid] + 1
                 self._ensure_page(req, pos)
                 carried[req.rid] = pos
+        # (a sampler preempted and admitted again since is no longer
+        # the in-flight step's: it prefills from its first token)
         inflight_rids = set() if inflight is None else \
-            {req.rid for req in inflight.samplers}
+            {req.rid for req in inflight.samplers
+             if inflight.current(req)}
         for req in list(self._slots):
             if req is not None and req.pending is not None \
                     and req.rid not in inflight_rids:
@@ -1954,8 +2056,7 @@ class ServingEngine:
         # step's argmax for this slot, read on device via tok_src
         if inflight is not None:
             for req in inflight.samplers:
-                if req.rid not in carried or req.slot is None \
-                        or req.state != "running":
+                if req.rid not in carried:
                     continue
                 pos = carried[req.rid]
                 row_slot[r] = req.slot
@@ -1964,6 +2065,7 @@ class ServingEngine:
                 tok_src[r] = req.slot
                 slot_rows[req.slot, 0] = r
                 samplers.append(req)
+                plan.admit[req.rid] = req.admit_seq
                 plan.decode_pos[req.rid] = pos
                 plan.was_decode[req.rid] = True
                 plan.carried += 1
@@ -1982,6 +2084,7 @@ class ServingEngine:
             row_live[r] = True
             slot_rows[req.slot, 0] = r
             samplers.append(req)
+            plan.admit[req.rid] = req.admit_seq
             plan.decode_pos[req.rid] = req.n_cached
             plan.was_decode[req.rid] = True
             self.stats["decode_rows"] += 1
@@ -2008,6 +2111,15 @@ class ServingEngine:
             inp = req.resume_input
             p0 = req.n_prefilled
             sampled = False
+            if pre.get(req.rid, 0):
+                plan.state_slots += 1
+                if p0 == 0:
+                    # the slot's first chunk (a new request, or one
+                    # resumed after a preemption, recomputed from its
+                    # first token): its state starts from zero, masked
+                    # inside the step program
+                    buf.fresh[req.slot] = True
+                    plan.resets += 1
             for _ in range(pre.get(req.rid, 0)):
                 p = req.n_prefilled
                 tokens[r] = inp[p]
@@ -2019,6 +2131,7 @@ class ServingEngine:
                 if req.n_prefilled == inp.size:
                     slot_rows[req.slot, 0] = r
                     samplers.append(req)
+                    plan.admit[req.rid] = req.admit_seq
                     plan.decode_pos[req.rid] = p
                     plan.was_decode[req.rid] = False
                     sampled = True
@@ -2028,12 +2141,14 @@ class ServingEngine:
                 # the rows THIS plan wrote (recorded now — by commit
                 # time the planner may have pushed n_prefilled on)
                 plan.prefill_mid.append((req, req.n_prefilled))
+                plan.admit[req.rid] = req.admit_seq
             if tracing and req.n_prefilled > p0:
                 plan.prefill_spans.append((req.rid, p0,
                                            req.n_prefilled))
 
         plan.n_rows_used = r
         plan.n_pre_rows = sum(pre.values())
+        plan.state_slots += plan.n_dec_rows
         plan.empty = r == 0
         if r or not overlap:
             # an empty pipelined plan is never dispatched — don't book
@@ -2049,6 +2164,11 @@ class ServingEngine:
                 if self._walks_pages else window
             self.stats["kv_pages_window"] += window
             self.stats["kv_pages_read"] += plan.kv_pages
+            if self._stateful:
+                self.stats["ssm_state_updates"] += plan.state_slots
+                self.stats["ssm_state_resets"] += plan.resets
+                self.stats["ssm_state_bytes"] += 2 * plan.state_slots \
+                    * self.cfg.n_layers * self.cache.bytes_per_slot_state
             self.stats["peak_pages"] = max(self.stats["peak_pages"],
                                            self.cache.pages_in_use)
             self.stats["slot_occupancy_sum"] += \
@@ -2072,6 +2192,8 @@ class ServingEngine:
             staged = [jnp.asarray(buf.tokens), jnp.asarray(buf.row_slot),
                       jnp.asarray(buf.row_pos), jnp.asarray(buf.row_live),
                       jnp.asarray(buf.bt), jnp.asarray(buf.slot_rows)]
+            if self._stateful:
+                staged.append(jnp.asarray(buf.fresh))
             if self.overlap:
                 prev = self._inflight_tok
                 if prev is None:
@@ -2100,7 +2222,7 @@ class ServingEngine:
         finished = []
         spec_spans = []                    # trace: (rid, drafted, accepted)
         for req in plan.samplers:
-            if req.slot is None or req.state != "running":
+            if not plan.current(req):
                 continue                   # preempted/cancelled
             was_decode = plan.was_decode[req.rid]
             # rows written this step are now cached (the recorded
@@ -2179,7 +2301,7 @@ class ServingEngine:
         # at build time (by now the planner may have pushed
         # n_prefilled past what THIS step's rows actually wrote)
         for req, p1 in plan.prefill_mid:
-            if req.slot is None or req.state != "running":
+            if not plan.current(req):
                 continue
             req.n_cached = max(req.n_cached, p1)
             if self.prefix is not None:

@@ -5,7 +5,24 @@ Layout (per decoder layer):
     kv pool : (num_pages, page_size, H, 2*dh)   cfg.dtype | int8
     s pool  : (num_pages, 2, page_size, H)      f32        (kv_int8)
 
-i.e. each page holds ``page_size`` consecutive token positions of ONE
+``H`` and ``dh`` are the model's key/value heads and head size
+(``kv_geometry``: ``cfg.n_kv_heads`` / ``cfg.head_dim`` where the config
+has them, else the ``n_heads`` heads of ``d_model // n_heads`` every
+query head has).  A grouped-query pool — fewer key/value heads than
+query heads — is FLAT, ``(num_pages, page_size, H*2*dh)``, each head's
+k then v side by side on the last axis: with few heads the 4-d page's
+two minor dims are no whole tiles and the page walk could not cut
+pages out of it (``kernels/paged_attention.py walk_geometry``).
+
+A model whose sequences keep state that is no page (``slot_state_shapes``
+of its serving module: a recurrent state, a convolution window) gets a
+second, NON-paged pool per layer and name, ``(num_slots + 1, ...)``:
+row ``s`` is slot ``s``'s, the last row the scratch slot that dead rows
+point at, as they point at the scratch page.  These pools ride in the
+layer's dict beside ``kv`` and are donated and updated in place by the
+step program with it; no allocator: a slot's row is its own.
+
+Each page holds ``page_size`` consecutive token positions of ONE
 sequence, all heads, k and v halves fused in the last axis — the same
 fused k|v layout the contiguous decode caches use ((B*H, L, 2*dh), see
 ``models/gpt.py _decode_one``), just chopped along the token axis so
@@ -74,7 +91,32 @@ from __future__ import annotations
 from collections import deque
 from typing import Any, Dict
 
-__all__ = ["PagedKVCache", "contiguous_kv_bytes"]
+__all__ = ["PagedKVCache", "contiguous_kv_bytes", "kv_geometry",
+           "slot_state_shapes", "write_rows"]
+
+
+def kv_geometry(cfg):
+    """``(heads, head size, flat)`` of a config's K/V pages: its
+    key/value heads and explicit head size where it names them, else
+    one per query head of ``d_model // n_heads``; ``flat`` where the
+    key/value heads are shared by groups of query heads (the page is
+    then ``(page_size, heads*2*head_size)``)."""
+    H = getattr(cfg, "n_kv_heads", None) or cfg.n_heads
+    dh = getattr(cfg, "head_dim", None) or cfg.d_model // cfg.n_heads
+    return H, dh, H != cfg.n_heads
+
+
+def write_rows(pool_kv, page, off, k, v):
+    """The float K/V pool with row r's ``k[r]`` / ``v[r]`` ((T, H, dh)
+    each) written at position ``off[r]`` of page ``page[r]``: k then v
+    side by side on the last axis, per head in the 4-d page, all heads
+    in a row in the flat one.  The page's layout is known here and in
+    the kernel that reads it (``kernels/paged_attention.py``)."""
+    import jax.numpy as jnp
+    new = jnp.concatenate([k, v], axis=-1).astype(pool_kv.dtype)
+    if pool_kv.ndim == 3:
+        new = new.reshape(new.shape[0], -1)
+    return pool_kv.at[page, off].set(new)
 
 
 def _dtype_size(dtype):
@@ -154,13 +196,22 @@ def _make_export(cfg, kv_int8, bucket, mesh=None):
     return fn
 
 
+def slot_state_shapes(cfg):
+    """What one slot keeps per layer that is no page, ``{name: (shape,
+    dtype)}``: the ``slot_state_shapes`` of the model module a config
+    names (``cfg.serving``), or nothing."""
+    shapes = getattr(getattr(cfg, "serving", None), "slot_state_shapes",
+                     None)
+    return shapes(cfg) if shapes else {}
+
+
 def contiguous_kv_bytes(cfg, batch, total, kv_int8=False):
     """HBM the contiguous allocator holds for a (batch, total)-shaped
     decode: B*H*total*2*dh elements per layer (+ the f32 scale pair
     per (row, token) when int8) — the baseline for the paged-vs-
     contiguous comparison in benchmark/serve_bench.py."""
-    dh = cfg.d_model // cfg.n_heads
-    rows = batch * cfg.n_heads * total
+    H, dh, _ = kv_geometry(cfg)
+    rows = batch * H * total
     per_row = 2 * dh * (1 if kv_int8 else _dtype_size(cfg.dtype))
     if kv_int8:
         per_row += 2 * 4                      # f32 scale pair
@@ -181,7 +232,7 @@ class PagedKVCache:
     S_POOL_SPEC = (None, None, None, "tp")
 
     def __init__(self, cfg, num_pages, page_size, kv_int8=False,
-                 mesh=None, device=None):
+                 mesh=None, device=None, num_slots=None):
         import jax
         import jax.numpy as jnp
 
@@ -196,8 +247,14 @@ class PagedKVCache:
         self.kv_int8 = kv_int8
         self.mesh = mesh
         self.tp = 1
-        H = cfg.n_heads
-        dh = cfg.d_model // H
+        H, dh, flat = kv_geometry(cfg)
+        if flat and (kv_int8 or mesh is not None):
+            raise ValueError(
+                "PagedKVCache: a grouped-query pool (%d key/value heads "
+                "under %d query heads) has no int8 scale planes and no "
+                "heads-sharded placement" % (H, cfg.n_heads))
+        page = (page_size, H * 2 * dh) if flat \
+            else (page_size, H, 2 * dh)
         cdt = jnp.dtype(cfg.dtype)
         place = lambda x, spec=None: x       # noqa: E731
         if device is not None:
@@ -222,17 +279,29 @@ class PagedKVCache:
         for _ in range(cfg.n_layers):
             if kv_int8:
                 self.pools.append({
-                    "kv": place(jnp.zeros(
-                        (num_pages, page_size, H, 2 * dh), jnp.int8)),
+                    "kv": place(jnp.zeros((num_pages,) + page,
+                                          jnp.int8)),
                     "s": place(jnp.zeros(
                         (num_pages, 2, page_size, H), jnp.float32),
                         self.S_POOL_SPEC),
                 })
             else:
                 self.pools.append({
-                    "kv": place(jnp.zeros(
-                        (num_pages, page_size, H, 2 * dh), cdt)),
+                    "kv": place(jnp.zeros((num_pages,) + page, cdt)),
                 })
+        # what a slot keeps beside its pages (module docstring): one
+        # (num_slots + 1, ...) pool per layer and name, zeros
+        self.slot_state = slot_state_shapes(cfg)
+        if self.slot_state:
+            if num_slots is None or mesh is not None:
+                raise ValueError(
+                    "PagedKVCache: per-slot state %s needs num_slots "
+                    "and has no sharded placement"
+                    % sorted(self.slot_state))
+            for pool in self.pools:
+                for name, (shape, dtype) in self.slot_state.items():
+                    pool[name] = place(jnp.zeros(
+                        (num_slots + 1,) + tuple(shape), dtype))
         # page 0 is scratch — never allocated
         self._free = deque(range(1, num_pages))
         self._in_use = 0
@@ -357,13 +426,20 @@ class PagedKVCache:
     @property
     def bytes_per_page(self):
         """Device bytes one page costs across all layers."""
-        H = self.cfg.n_heads
-        dh = self.cfg.d_model // H
+        H, dh, _ = kv_geometry(self.cfg)
         per_tok = H * 2 * dh * (1 if self.kv_int8
                                 else _dtype_size(self.cfg.dtype))
         if self.kv_int8:
             per_tok += H * 2 * 4
         return per_tok * self.page_size * self.cfg.n_layers
+
+    @property
+    def bytes_per_slot_state(self):
+        """Device bytes of ONE layer's state of ONE slot, all names
+        together (0 for a model that keeps pages alone)."""
+        import numpy as np
+        return sum(int(np.prod(shape)) * _dtype_size(dtype)
+                   for shape, dtype in self.slot_state.values())
 
     @property
     def bytes_held(self):

@@ -91,6 +91,32 @@ def test_paged_attention_compiles(v5e, geom, kv_dtype, q_dtype):
             v5e[0], q, kv, sc, bt, pos)
 
 
+# grouped-query pools are flat, (pages, ps, Hkv*2*dh): the benchmark's
+# falcon_h1_34b_l6 cell is 128 step rows, 20 query heads over 4
+# key/value heads of 128, 48 16-token pages a row, a (3073, 16, 1024)
+# bf16 pool
+_GQA = dict(T=128, Hq=20, Hkv=4, dh=128, ps=16, PP=48, NP=3073)
+
+
+@pytest.mark.parametrize("geom,dtype,walks", [
+    (_GQA, "bfloat16", True),
+    (dict(_GQA, T=37, PP=7, NP=353), "float32", True),
+    # 8-token bf16 pages are half a tile: the per-page grid
+    (dict(_GQA, T=32, ps=8, NP=353), "bfloat16", False),
+], ids=["cell-bf16", "odd-f32", "half-tile-bf16"])
+def test_grouped_paged_attention_compiles(v5e, geom, dtype, walks):
+    from mxnet_tpu.kernels.paged_attention import (paged_attention,
+                                                   walk_geometry)
+    g = geom
+    assert (walk_geometry(g["Hkv"], g["dh"], g["ps"], g["PP"], dtype,
+                          flat=True) is not None) == walks
+    _compile(lambda q, kv, bt, pos: paged_attention(
+        q, kv, None, bt, pos, page_size=g["ps"]), v5e[0],
+        _sds((g["T"], g["Hq"], g["dh"]), dtype),
+        _sds((g["NP"], g["ps"], g["Hkv"] * 2 * g["dh"]), dtype),
+        _sds((g["T"], g["PP"]), "int32"), _sds((g["T"],), "int32"))
+
+
 def test_flash_fwd_bwd_compiles(v5e):
     from mxnet_tpu.kernels import flash_attention as fa
     qkv = _sds((1, 4096, 12, 64), "bfloat16")

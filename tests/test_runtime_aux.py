@@ -49,6 +49,9 @@ def test_library_load_python_ext(tmp_path):
         from mxnet_tpu.ops import registry
         assert registry.op_exists("test_ext_double")
     assert str(ext) in mx.library.loaded_libs()
+    # leave the registry as the package built it (test_amp sweeps it)
+    from mxnet_tpu.ops import registry
+    registry._OPS.pop("test_ext_double", None)
 
 
 def test_library_load_missing():
