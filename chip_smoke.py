@@ -11,6 +11,11 @@ own means:
 * ``serve_full``     — the ``full`` serving preset through ``ServingEngine``
   ``submit()``/``run()``, both attention kernels; float32 token identity
   against ``models.gpt.generate()``.
+* ``serve_schedules`` — the same seeded requests through a serial and a
+  pipelined ``ServingEngine`` (``overlap=False`` / ``True``; a TPU engine
+  told nothing takes the pipelined one): identical tokens, for the ``full``
+  preset as deployed and for Falcon-H1 (a recurrent state per slot) at the
+  benchmark configuration's rehearsal size, more requests than slots.
 * ``kernels``        — the three Pallas kernels, each proven COMPILED (a
   ``tpu_custom_call`` in the compiled program) and compared on the chip
   with its plain-jnp reference.
@@ -266,6 +271,7 @@ def _serve(ctx, params, cfg, prompts, **kw):
     eng = ServingEngine(params, cfg, **dict(ctx.sz["engine"], **kw))
     rids = [eng.submit(p, n) for p, n in prompts]
     outs = eng.run()
+    eng.close()
     gen = []
     for rid, (p, n) in zip(rids, prompts):
         out = np.asarray(outs[rid])
@@ -330,6 +336,75 @@ def leg_serve_full(ctx):
     ctx.note("f32/highest: xla, pallas and generate() token-identical on "
              "%d requests, %d tokens" % (len(prompts), total))
     ctx.shared["f32_tp1"] = f32["xla"]
+
+
+# ----------------------------------------------------- serve_schedules ---
+
+def _falcon_toy():
+    """Falcon-H1 at the toy size of the benchmark's configuration file
+    (its ``rehearse`` group: the published multipliers and flags, two
+    layers), seeded weights, the configuration's own dtype; one chunk
+    holds a prompt of every slot, so that a request's prompt is one
+    chunk whichever step admits it (the chunked scan sums in another
+    order than the step-by-step one)."""
+    import jax
+    from mxnet_tpu.models import falcon_h1
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "chipbench", "configs",
+                           "falcon_h1_34b_l6.json")) as f:
+        c = json.load(f)
+    toy = dict(c, **c["rehearse"])
+    cfg = falcon_h1.FalconH1Config.from_hf(toy, dtype=c["dtype"])
+    params = falcon_h1.init_params(jax.random.PRNGKey(0), cfg)
+    engine = dict(toy["engine"])
+    longest = 24
+    engine["prefill_chunk"] = engine["num_slots"] * longest
+    rng = np.random.RandomState(2)
+    prompts = [(rng.randint(1, cfg.vocab_size, P).astype(np.int32), N)
+               for P, N in [(5, 12), (longest, 9), (11, 30), (17, 6),
+                            (3, 21), (20, 14), (8, 8), (13, 25),
+                            (longest, 5), (6, 17)]]
+    return params, cfg, engine, prompts
+
+
+def leg_serve_schedules(ctx):
+    import jax
+    from mxnet_tpu.kernels.platform import platform_of
+    from mxnet_tpu.serving import ServingEngine
+
+    # what an engine told nothing runs: pipelined on a TPU, serial on a
+    # CPU; with speculation serial on either
+    params, cfg = _gpt(ctx, "bfloat16", w8=True)
+    on_tpu = platform_of(params) == "tpu"
+    for kw, want in (({}, on_tpu), ({"spec_K": 2}, False)):
+        eng = ServingEngine(params, cfg, **dict(ctx.sz["engine"], **kw))
+        if eng.overlap is not want:
+            raise AssertionError(
+                "ServingEngine(%r) on %s chose overlap=%r"
+                % (kw, jax.devices()[0].platform, eng.overlap))
+        eng.close()
+    ctx.note("an engine told nothing chose the %s schedule (spec_K=2: "
+             "serial)" % ("pipelined" if on_tpu else "serial"))
+
+    # the preset as deployed, more requests than slots (slots reused)
+    prompts = _prompts(ctx, n=3 * ctx.sz["engine"]["num_slots"])
+    gen = {ov: _serve(ctx, params, cfg, prompts, overlap=ov)
+           for ov in (False, True)}
+    _identical("bf16+w8 serial vs pipelined", gen[False], gen[True])
+    ctx.note("full preset, bf16+w8: serial and pipelined token-identical "
+             "on %d requests over %d slots, %d tokens"
+             % (len(prompts), ctx.sz["engine"]["num_slots"],
+                sum(n for _, n in prompts)))
+    del params
+
+    params, cfg, engine, prompts = _falcon_toy()
+    gen = {ov: _serve(ctx, params, cfg, prompts, overlap=ov, **engine)
+           for ov in (False, True)}
+    _identical("falcon_h1 serial vs pipelined", gen[False], gen[True])
+    ctx.note("falcon_h1 (toy, %s): serial and pipelined token-identical "
+             "on %d requests over %d slots, %d tokens"
+             % (cfg.dtype, len(prompts), engine["num_slots"],
+                sum(n for _, n in prompts)))
 
 
 # ------------------------------------------------------------- kernels ---
@@ -589,6 +664,7 @@ def leg_multichip(ctx):
 
 LEGS = [("train_resnet50", leg_train_resnet50),
         ("serve_full", leg_serve_full),
+        ("serve_schedules", leg_serve_schedules),
         ("kernels", leg_kernels),
         ("multichip", leg_multichip)]
 

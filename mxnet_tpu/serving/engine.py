@@ -79,7 +79,9 @@ degrades to the pre-tier behavior when the tier refuses or the entry
 was LRU-aged: exactness NEVER depends on the tier, only latency does.
 
 Round 21 hides the host scheduler behind device execution (ROADMAP
-item 4, ``overlap=True`` / ``MXNET_SERVE_OVERLAP=1``): the step
+item 4, ``overlap=True``; since PR 29 what an engine built without
+``overlap=`` runs where its pools live on a TPU and it does not
+speculate): the step
 program grows a per-row ``tok_src`` selector so a decode row's input
 token can come from the PREVIOUS step's device-resident argmax matrix
 instead of a host-fed value — step N+1 dispatches against step N's
@@ -92,9 +94,12 @@ invalidates the speculatively dispatched step reconciles EXACTLY:
 the stale row's writes land at positions beyond every committed read
 range (the same argument that makes preemption recompute-exact), so
 per-row skip suffices, and the only fence is speculative decode
-(drafters need committed host tokens — those steps run serially).
-``overlap=False`` (the default) is bit-for-bit the round-20 engine:
-same compiled program, same host schedule, same commit order.
+(drafters need committed host tokens — those steps run serially,
+which is why a ``spec_K > 0`` engine is serial unless told otherwise).
+``overlap=False`` (what ``overlap=None`` resolves to off the TPU: on
+XLA:CPU the "device" is the host's own cores, nothing to hide behind)
+is bit-for-bit the round-20 engine: same compiled program, same host
+schedule, same commit order.  No environment variable takes part.
 
 The step program is built from a MODEL MODULE's three functions,
 ``serve_embed`` / ``serve_block`` / ``serve_logits``: ``models/gpt.py``
@@ -966,6 +971,17 @@ class ServingEngine:
         None reads ``MXNET_SERVE_TIER_BYTES`` (off unless set); 0
         disables — the engine then behaves bit-identically to round
         17 (drop on pressure, recompute on resume).
+    overlap : the step loop's schedule.  True pipelines it (module
+        docstring, round 21): ``step()`` launches step N+1 against
+        step N's device-resident tokens, then reads step N back and
+        commits it, and a planner thread builds step N+2's batch
+        while N+1 computes — a finish is reported one call after the
+        step that produced its last token; tokens are identical.
+        False runs plan, launch, read-back, commit in turn.  None
+        (the default) reads it from what the engine can observe:
+        pipelined where its pools were placed on a TPU and
+        ``spec_K == 0`` (with speculation every sampling step fences
+        the pipeline), serial anywhere else.
     rid_start : first request id this engine assigns (a cluster gives
         each replica a disjoint block so rids — and their trace
         swimlanes — are unique cluster-wide).
@@ -1132,13 +1148,14 @@ class ServingEngine:
                                   kv_int8=self.kv_int8,
                                   mesh=self.mesh, device=device,
                                   num_slots=num_slots)
-        # one attention, two lowerings; where none is asked for, the
-        # platform the pools were just placed on says which is fast
-        # (the step program runs where its pools live)
+        # what the engine is not told it reads from the platform its
+        # pools were just placed on (the step program runs where its
+        # pools live).  One attention, two lowerings: the walk is the
+        # fast one on a TPU, the gather everywhere else
+        from ..kernels.platform import platform_of
+        on_tpu = platform_of(self.cache.pools) == "tpu"
         if kernel is None:
-            from ..kernels.platform import platform_of
-            kernel = "pallas" \
-                if platform_of(self.cache.pools) == "tpu" else "xla"
+            kernel = "pallas" if on_tpu else "xla"
         self.kernel = kernel
         # whether attention walks each row's own pages (the Pallas
         # walk, on a pool it can cut pages out of) or reads the whole
@@ -1161,14 +1178,17 @@ class ServingEngine:
             if prefix_cache else None
         if self.prefix is not None:
             self.cache.pressure_cb = self.prefix.evict
-        # latency-hiding overlap (round 21): explicit argument >
-        # MXNET_SERVE_OVERLAP env > off.  overlap=False is bit-for-bit
-        # the round-20 serial engine (same step program, same
-        # schedule); overlap=True pipelines the host scheduler with
-        # device execution — see the module docstring.
+        # one step loop, two schedules (round 21; the module
+        # docstring): pipelined, the host plans, stages and launches
+        # step N+1 while step N computes; serial, it alternates with
+        # the device.  Where none is asked for, the pipelined one where
+        # there is a device to hide behind (PR 29: the chip waited
+        # 4 ms of a 12 ms step for the host): on XLA:CPU the "device"
+        # is the host's own cores.  With speculation every sampling
+        # step would fence (``_build_plan``: the drafters read
+        # committed host tokens), so such an engine stays serial.
         if overlap is None:
-            overlap = os.environ.get("MXNET_SERVE_OVERLAP",
-                                     "0") == "1"
+            overlap = on_tpu and self.spec_K == 0
         self.overlap = bool(overlap)
         self._copy_fn = None              # jitted COW page copy
         if self.prefix is not None:

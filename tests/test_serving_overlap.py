@@ -22,11 +22,24 @@ Exactness pins:
 
 Slow tier, group o (own group: every scenario pays a second compiled
 step variant — the ``tok_src`` program — on top of the serial one).
+
+Fast tier (PR 29: the pipelined schedule is what an engine built
+without ``overlap=`` runs on a TPU, so tier-1 guards it): the identity
+pin once more at the smallest sizes, the engine's own choice of
+schedule, and the benchmark's two serving cells rehearsed with the
+engine forced pipelined.
 """
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
 import mxnet_tpu as mx  # noqa: F401  (conftest device setup)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _cfg(**kw):
@@ -76,14 +89,15 @@ def _drain_engine(eng, chaos=None, cancel_rid=None):
 
 
 def _engine_run(params, cfg, overlap, eos=None, chaos=None,
-                spec_K=0):
+                spec_K=0, lens=(3, 11, 7, 19, 5, 13),
+                maxnew=(9, 4, 1, 7, 12, 6), **engine):
     from mxnet_tpu.serving import ServingEngine
     rng = np.random.RandomState(7)
-    prompts = _mixed(rng, cfg.vocab_size)
-    maxnew = [9, 4, 1, 7, 12, 6]
-    eng = ServingEngine(params, cfg, num_slots=3, page_size=8,
-                        prefill_chunk=6, prefix_cache=True,
-                        spec_K=spec_K, overlap=overlap)
+    prompts = _mixed(rng, cfg.vocab_size, lens)
+    engine = engine or dict(num_slots=3, page_size=8, prefill_chunk=6,
+                            prefix_cache=True)
+    eng = ServingEngine(params, cfg, spec_K=spec_K, overlap=overlap,
+                        **engine)
     rids = [eng.submit(p, m, eos_id=eos)
             for p, m in zip(prompts, maxnew)]
     _drain_engine(eng, chaos=chaos, cancel_rid=rids[1])
@@ -292,37 +306,169 @@ def test_disagg_cluster_overlap_identity():
         cl.close()
 
 
-def test_overlap_env_var_and_validation():
-    """Fast tier: ``MXNET_SERVE_OVERLAP`` resolves the default, the
-    explicit kwarg wins over the env, and close() is idempotent —
-    all without compiling anything (no steps run)."""
-    import os
+# --------------------------------------------------------- fast tier ---
+
+def _small_run(params, cfg, overlap, **kw):
+    """``_engine_run`` at the smallest sizes that still mix the row
+    kinds: two slots over five requests (slot reuse), prompts longer
+    than a chunk, no prefix cache."""
+    return _engine_run(params, cfg, overlap, lens=(3, 9, 5, 12, 4),
+                       maxnew=(7, 4, 1, 6, 9), num_slots=2, page_size=4,
+                       prefill_chunk=4, **kw)
+
+
+@pytest.fixture(scope="module")
+def small():
+    import jax
+    from mxnet_tpu.models import transformer as T
+    cfg = _cfg(max_len=32, d_model=32, n_heads=2, n_layers=1, d_ff=64)
+    return T.init_params(jax.random.PRNGKey(0), cfg), cfg
+
+
+@pytest.mark.parametrize("scenario", ["plain", "eos", "preempt",
+                                      "cancel"])
+def test_pipelined_tokens_are_the_serial_ones(small, scenario):
+    """``test_overlap_bit_identical_to_serial`` in the fast tier, one
+    layer wide: the schedule a TPU engine takes by itself gives the
+    serial schedule's tokens through an eos stop, a preemption and a
+    cancel, leaks no page, and did pipeline — every committed step was
+    dispatched pipelined (the cold start's too: it is built by the
+    same planner code, inline) and planning time was hidden."""
+    params, cfg = small
+    kw = {"chaos": scenario} if scenario in ("preempt", "cancel") else {}
+    if scenario == "eos":
+        # the third token of the longest answer: it stops there, one
+        # step behind a row already dispatched for its fourth
+        plain, _, _ = _small_run(params, cfg, overlap=False)
+        kw = {"eos": max(plain.values(), key=lambda r: len(r[1]))[1][2]}
+    a, held_a, sa = _small_run(params, cfg, overlap=False, **kw)
+    b, held_b, sb = _small_run(params, cfg, overlap=True, **kw)
+    _assert_equiv(a, b, scenario)
+    # the scenario happened, in both runs
+    for res, st in ((a, sa), (b, sb)):
+        if scenario == "eos":
+            assert max(len(g) for _, g in res.values()) < 9
+        assert st["preemptions"] == (scenario == "preempt")
+        assert [state for state, _ in res.values()].count(
+            "cancelled") == (scenario == "cancel")
+    assert held_a == 0 and held_b == 0, (scenario, held_a, held_b)
+    assert sa["overlap_steps"] == 0 and sa["host_hidden_ms"] == 0.0
+    assert sb["steps"] > 0 and sb["overlap_steps"] == sb["steps"]
+    assert sb["overlap_fences"] == 0
+    assert sb["host_hidden_ms"] > 0.0
+
+
+# (pools on, spec_K, overlap=, MXNET_SERVE_OVERLAP) -> pipelined?
+_CHOICES = {
+    "cpu": ("cpu", 0, None, None, False),
+    "tpu": ("tpu", 0, None, None, True),
+    "tpu_speculating": ("tpu", 2, None, None, False),
+    "cpu_told_pipelined": ("cpu", 0, True, None, True),
+    "tpu_told_serial": ("tpu", 0, False, None, False),
+    "tpu_speculating_told_pipelined": ("tpu", 2, True, None, True),
+    "cpu_env_1": ("cpu", 0, None, "1", False),
+    "tpu_env_0": ("tpu", 0, None, "0", True),
+    "env_0_told_pipelined": ("cpu", 0, True, "0", True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CHOICES))
+def test_engine_chooses_its_schedule(small, monkeypatch, case):
+    """``overlap=None`` is read from what the engine can observe: the
+    platform its pools were placed on (``kernels/platform.platform_of``,
+    the test that chooses ``kernel``) and whether it speculates.  An
+    explicit ``overlap=`` wins either way, and ``MXNET_SERVE_OVERLAP``
+    no longer takes part.  Nothing compiles: no step runs."""
+    from mxnet_tpu.kernels import platform
     from mxnet_tpu.serving import ServingEngine
-    params, cfg = _setup()
+    params, cfg = small
+    plat, spec_K, overlap, env, pipelined = _CHOICES[case]
+    if plat != "cpu":
+        monkeypatch.setattr(platform, "platform_of", lambda *a: plat)
+    if env is None:
+        monkeypatch.delenv("MXNET_SERVE_OVERLAP", raising=False)
+    else:
+        monkeypatch.setenv("MXNET_SERVE_OVERLAP", env)
+    eng = ServingEngine(params, cfg, num_slots=2, page_size=4,
+                        prefill_chunk=4, spec_K=spec_K, overlap=overlap)
+    assert platform.platform_of(eng.cache.pools) == plat
+    assert eng.overlap is pipelined
+    # the schedule and the kernel are read from the same observation
+    assert eng.kernel == ("pallas" if plat == "tpu" else "xla")
+    assert eng._planner is None            # spawned by the first step
+    eng.close()
+    eng.close()                            # idempotent
 
-    def make(**kw):
-        return ServingEngine(params, cfg, num_slots=2, page_size=8,
-                             prefill_chunk=8, **kw)
 
-    old = os.environ.get("MXNET_SERVE_OVERLAP")
-    try:
-        os.environ["MXNET_SERVE_OVERLAP"] = "1"
-        eng = make()
-        assert eng.overlap
-        eng.close()
-        eng = make(overlap=False)
-        assert not eng.overlap
-        eng.close()
-        os.environ["MXNET_SERVE_OVERLAP"] = "0"
-        eng = make()
-        assert not eng.overlap
-        eng.close()
-        eng = make(overlap=True)
-        assert eng.overlap
-        eng.close()
-        eng.close()                        # idempotent
-    finally:
-        if old is None:
-            os.environ.pop("MXNET_SERVE_OVERLAP", None)
-        else:
-            os.environ["MXNET_SERVE_OVERLAP"] = old
+_FORCE_PIPELINED = """
+import sys
+sys.path[:0] = [{root!r}, {bench!r}]
+from mxnet_tpu.serving import engine as E
+init = E.ServingEngine.__init__
+seen = []
+def forced(self, *a, **kw):
+    init(self, *a, **dict(kw, overlap=True))
+    seen.append(self)
+E.ServingEngine.__init__ = forced
+import run
+rc = run.main({argv!r})
+eng = seen[0]
+print("schedule " + __import__("json").dumps(
+    {{"overlap": eng.overlap, "steps": eng.stats["steps"],
+      "overlap_steps": eng.stats["overlap_steps"]}}), file=sys.stderr)
+sys.exit(rc)
+"""
+
+
+@pytest.mark.parametrize("cell", ["bert_large_decoder.decode_heavy",
+                                  "falcon_h1_34b_l6.chat_decode"])
+def test_serving_cells_rehearse_pipelined(cell):
+    """The benchmark's serving cells follow the engine's choice with no
+    benchmark file edited, so their harness has to be schedule-agnostic:
+    it reads ``Request.generated`` after each ``step()`` and never the
+    call's return value.  ``chipbench/run.py --rehearse`` with every
+    engine forced pipelined (here the CPU would choose serial) reaches
+    ``correct`` against the reference, no request failed, every step of
+    the run pipelined."""
+    argv = ["--workload", cell, "--seed", str(2 ** 31 + 29),
+            "--seconds", "1", "--trace", "0", "--rehearse"]
+    code = _FORCE_PIPELINED.format(
+        root=ROOT, bench=os.path.join(ROOT, "chipbench"), argv=argv)
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=600, cwd=ROOT,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert r.returncode == 0, r.stderr[-2000:]
+    line = json.loads(r.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True, line["compared"]
+    assert line["attempted"] > 0 and line["failed"] == 0
+    sched = json.loads(next(ln for ln in r.stderr.splitlines()
+                            if ln.startswith("schedule "))[9:])
+    assert sched["overlap"] is True
+    assert sched["steps"] > 0
+    assert sched["overlap_steps"] == sched["steps"]
+
+
+def test_pipelined_step_share_reader(small):
+    """The benchmark's reader of ``overlap_steps`` over ``steps``
+    (``chipbench/layer_metrics/pipelined_step_share.serve.py``): their
+    ratio in percent over a window's counter deltas; nothing, without
+    raising, from a program that books no such counter or from a window
+    in which no step was committed; 0 and 100 from a serial and a
+    pipelined engine's own ``stats``."""
+    import importlib.util
+    path = os.path.join(ROOT, "chipbench", "layer_metrics",
+                        "pipelined_step_share.serve.py")
+    spec = importlib.util.spec_from_file_location("pipelined_share", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    read = lambda counters: reader.read({}, {}, counters, None)  # noqa: E731
+    assert read({"steps": 400, "dead_rows": 17000}) is None
+    assert read({"steps": 0, "overlap_steps": 0}) is None
+    assert read({"overlap_steps": 3}) is None
+    assert read({"steps": 2400, "overlap_steps": 0}) == 0.0
+    assert read({"steps": 3400, "overlap_steps": 3397}) == \
+        pytest.approx(99.91, abs=0.01)
+    params, cfg = small
+    for overlap, share in ((False, 0.0), (True, 100.0)):
+        _, _, stats = _small_run(params, cfg, overlap=overlap)
+        assert read(stats) == share
