@@ -255,34 +255,6 @@ def bench_gpt_serve_decode_step():
     return serve_bench.run_gate_decode_step("full")
 
 
-def bench_gpt_serve_overlap_step():
-    """Overlapped decode-step-time gate (round 21): engine-internal
-    step-time p50 (ms) of the SAME closed-loop decode-heavy pallas
-    run as ``gpt_serve_decode_step_ms``, with the pipelined scheduler
-    on (``overlap=True``, best-of-3) — the pair pins the overlap
-    lever from both sides: this number regressing while the serial
-    one holds means the tok_src selector / double-buffered admission
-    got expensive; both regressing means the kernel did.  The run
-    itself hard-fails (RuntimeError) unless the engine actually HID
-    host work behind the device (``host_hidden_ms`` > 0 over > 0
-    pipelined steps) — a silently-serial run would pin nothing.
-    Direction "lower": v <= hi.  Only meaningful on chip — off-TPU
-    the "device" step shares the host with the planner, so the delta
-    prices host scheduling, not the hidden bubble.  Reproducibility
-    is enforced like the goodput gate's: the row must carry its seed
-    + workload sha or the gate refuses to report."""
-    sys.path.insert(0, os.path.join(REPO, "benchmark"))
-    import serve_bench
-    row = serve_bench.run_gate_overlap_step("full")
-    if not row.get("workload_sha") or "seed" not in row:
-        raise RuntimeError(
-            "gpt_serve_overlap_step_ms: result row carries no "
-            "seed/workload sha — the measurement is not "
-            "reproducible; refusing to gate it (got keys %s)"
-            % sorted(row))
-    return row["step_p50_ms"]
-
-
 def bench_gpt_serve_prefix_hit():
     """Shared-prefix KV reuse gate (round 10): TTFT (ms) of a request
     whose whole prompt sits in the prefix cache — the engine maps the
@@ -558,8 +530,6 @@ BENCHES = {
     "gpt_serve_prefix_hit_ttft_ms": (bench_gpt_serve_prefix_hit,
                                      "lower"),
     "gpt_serve_decode_step_ms": (bench_gpt_serve_decode_step, "lower"),
-    "gpt_serve_overlap_step_ms": (bench_gpt_serve_overlap_step,
-                                  "lower"),
     "gpt_serve_disagg_remote_hit_ttft_ms":
         (bench_gpt_serve_disagg_remote_hit, "lower"),
     "gpt_serve_put_remote_hit_ttft_ms":

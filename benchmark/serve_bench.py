@@ -310,7 +310,7 @@ def _bucket_width_at(v, bounds):
 def run_engine(params, cfg, p, workload, num_pages=None,
                page_size=None, closed_loop_k=None, metrics=False,
                cross_check=True, kernel=None, spec_K=0,
-               spec_drafter="ngram", overlap=None, tp=1):
+               spec_drafter="ngram", tp=1):
     """Open-loop (Poisson ``workload``) or closed-loop (``k`` always in
     flight, workload gives the request shapes) engine run.
 
@@ -336,8 +336,7 @@ def run_engine(params, cfg, p, workload, num_pages=None,
                            num_pages=num_pages, section="engine")
     eng = ServingEngine(params, cfg, metrics=bool(metrics),
                         kernel=kernel, spec_K=spec_K,
-                        spec_drafter=spec_drafter, overlap=overlap,
-                        tp=tp, **geo)
+                        spec_drafter=spec_drafter, tp=tp, **geo)
     # pre-warm the step program outside the clock (and drop the
     # warmup's footprint from the reported stats/registry — the
     # compile time would otherwise own the TTFT tail)
@@ -441,15 +440,6 @@ def run_engine(params, cfg, p, workload, num_pages=None,
            / max(1, eng.stats["steps"]),
            "preemptions": eng.stats["preemptions"],
            "steps": eng.stats["steps"], "kernel": eng.kernel}
-    if eng.overlap:
-        steps = max(1, eng.stats["steps"])
-        out.update({
-            "overlap": True,
-            "overlap_steps": eng.stats["overlap_steps"],
-            "overlap_fences": eng.stats["overlap_fences"],
-            "host_hidden_ms_total": eng.stats["host_hidden_ms"],
-            "host_hidden_ms_per_step":
-                eng.stats["host_hidden_ms"] / steps})
     if spec_K:
         out.update({
             "spec_K": spec_K,
@@ -2092,89 +2082,6 @@ def run_gate_decode_step(preset="full"):
     return best
 
 
-def run_overlap_ablation(params, cfg, p):
-    """Round-21 section: the serial-vs-overlapped decode-step
-    comparison on both kernels — one closed-loop decode-heavy run per
-    (kernel, overlap) cell (k = num_slots, metrics on, external
-    cross-check off), step time from the engine's own
-    ``serving_step_ms`` histogram plus the overlap engine's
-    ``host_hidden_ms`` counter (host planner+drain work that ran while
-    the device executed).  spec_K stays 0 on purpose: the overlap
-    scheduler FENCES to serial equivalence under speculation, so a
-    spec run would just measure the fence.  NOTE off-TPU the "device"
-    step also runs on the host, so the step-time delta prices host
-    SCHEDULING, not the chip-side bubble (docs/perf.md
-    "Latency-hiding overlap")."""
-    wl = _decode_heavy_workload(p)
-    rows = []
-    for kern in ("xla", "pallas"):
-        for ov in (False, True):
-            r = run_engine(params, cfg, p, wl,
-                           closed_loop_k=p.num_slots, metrics=True,
-                           cross_check=False, kernel=kern, overlap=ov)
-            r.update(section="overlap",
-                     config="overlap_%s_%s"
-                     % (kern, "on" if ov else "off"))
-            rows.append(r)
-    return rows
-
-
-_overlap_step_gate_cache = {}
-
-
-def run_gate_overlap_step(preset="full", seed=0):
-    """The ``gpt_serve_overlap_step_ms`` gate: engine-internal
-    step-time p50 of the OVERLAPPED closed-loop decode-heavy run with
-    ``kernel="pallas"`` — the same run shape as
-    ``gpt_serve_decode_step_ms`` with ``overlap=True``, so the pair
-    pins the pipelined scheduler's step cost against the serial
-    baseline's.  Best-of-3 per side (jitter-stripped like every
-    decode gate).  Hard-fails unless the overlap run actually HID
-    host work behind the device (``host_hidden_ms`` > 0) and took
-    pipelined steps — a gate number from a run that silently fell
-    back to serial would pin nothing.
-
-    The row carries ``seed`` + ``workload_sha`` (sha256 over every
-    prompt and output length of the decode-heavy workload) so the
-    recorded number is reproducible from the checked-in seed."""
-    import hashlib
-    key = (preset, seed)
-    if key in _overlap_step_gate_cache:
-        return _overlap_step_gate_cache[key]
-    p = PRESETS[preset]
-    params, cfg = _model(p)
-    wl = _decode_heavy_workload(p, seed=seed)
-    sha = hashlib.sha256()
-    for _, prompt, n in wl:
-        sha.update(prompt.tobytes())
-        sha.update(np.int64(n).tobytes())
-    best = {}
-    for ov in (False, True):
-        best[ov] = min(
-            (run_engine(params, cfg, p, wl,
-                        closed_loop_k=p.num_slots, metrics=True,
-                        cross_check=False, kernel="pallas",
-                        overlap=ov)
-             for _ in range(3)),
-            key=lambda r: r["step_p50_ms"])
-    on = best[True]
-    if on["host_hidden_ms_total"] <= 0.0 or on["overlap_steps"] <= 0:
-        raise RuntimeError(
-            "run_gate_overlap_step: the overlap=True run hid no host "
-            "work (host_hidden_ms=%.3f, overlap_steps=%d) — the "
-            "pipelined scheduler fell back to serial, refusing to "
-            "record a gate number for it"
-            % (on["host_hidden_ms_total"], on["overlap_steps"]))
-    row = {"step_p50_ms": on["step_p50_ms"],
-           "serial_step_p50_ms": best[False]["step_p50_ms"],
-           "host_hidden_ms_per_step": on["host_hidden_ms_per_step"],
-           "overlap_steps": on["overlap_steps"],
-           "overlap_fences": on["overlap_fences"],
-           "seed": seed, "workload_sha": sha.hexdigest()[:16]}
-    _overlap_step_gate_cache[key] = row
-    return row
-
-
 # ------------------------------------------------------------------ main ---
 
 def run_gate(preset="full"):
@@ -2275,12 +2182,6 @@ def main(argv=None):
                          "identity + toggle reconciliation "
                          "hard-enforced); runs ALONE like the other "
                          "cross-process sections")
-    ap.add_argument("--overlap-ablation", action="store_true",
-                    help="run the round-21 serial-vs-overlapped "
-                         "decode-step ablation section (closed loop, "
-                         "decode-heavy shapes, both kernels): step "
-                         "p50 per cell + host work hidden behind the "
-                         "device per pipelined step")
     ap.add_argument("--spec-sweep", action="store_true",
                     help="run the accept-rate x K sweep section "
                          "(e2e Poisson workload at spec_K = 0/2/4)")
@@ -2665,27 +2566,6 @@ def main(argv=None):
               "%.2f ms%s" % (ab[0]["step_p50_ms"],
                              ab[1]["step_p50_ms"], interp_note),
               flush=True)
-
-    if args.overlap_ablation:
-        ov = run_overlap_ablation(params, cfg, p)
-        rows.extend(ov)
-        for r in ov:
-            print(json.dumps(r), flush=True)
-        by = {r["config"]: r for r in ov}
-        import jax
-        host_note = "" if jax.devices()[0].platform == "tpu" else \
-            " (off-TPU the device step ALSO runs on the host, so " \
-            "the serial-vs-overlapped delta prices host scheduling, " \
-            "not the chip-side bubble the overlap hides)"
-        for kern in ("xla", "pallas"):
-            off = by["overlap_%s_off" % kern]
-            on = by["overlap_%s_on" % kern]
-            print("overlap ablation [%s]: step p50 serial %.2f ms vs "
-                  "overlapped %.2f ms; host hidden %.2f ms/step over "
-                  "%d pipelined steps%s"
-                  % (kern, off["step_p50_ms"], on["step_p50_ms"],
-                     on["host_hidden_ms_per_step"],
-                     on["overlap_steps"], host_note), flush=True)
 
     if args.spec_sweep:
         sp = run_spec_sweep(params, cfg, p, wl, num_pages=pages,

@@ -12,8 +12,10 @@ own means:
   ``submit()``/``run()``, both attention kernels; float32 token identity
   against ``models.gpt.generate()``.
 * ``serve_schedules`` — the same seeded requests through a serial and a
-  pipelined ``ServingEngine`` (``overlap=False`` / ``True``; a TPU engine
-  told nothing takes the pipelined one): identical tokens, for the ``full``
+  pipelined ``ServingEngine``: the schedule the engine reads from its
+  platform (pipelined on a TPU) against the other one, which it is given
+  by substituting that observation while it is built, the attention
+  lowering held to the engine's own: identical tokens, for the ``full``
   preset as deployed and for Falcon-H1 (a recurrent state per slot) at the
   benchmark configuration's rehearsal size, more requests than slots.
 * ``kernels``        — the three Pallas kernels, each proven COMPILED (a
@@ -264,11 +266,24 @@ def _prompts(ctx, n=None):
             for P, N in shapes]
 
 
-def _serve(ctx, params, cfg, prompts, **kw):
+def _serve(ctx, params, cfg, prompts, pools_seen_on=None, **kw):
     """Submit every prompt, drain, and return the generated tokens per
-    request after checking count and range."""
+    request after checking count and range.  ``pools_seen_on``: the
+    platform the engine is to read as its pools' while it is built (it
+    takes its schedule from there and has no argument for it)."""
+    from mxnet_tpu.kernels import platform
     from mxnet_tpu.serving import ServingEngine
-    eng = ServingEngine(params, cfg, **dict(ctx.sz["engine"], **kw))
+    real = platform.platform_of
+    if pools_seen_on is not None:
+        platform.platform_of = lambda *operands: pools_seen_on
+    try:
+        eng = ServingEngine(params, cfg, **dict(ctx.sz["engine"], **kw))
+    finally:
+        platform.platform_of = real
+    if pools_seen_on is not None and \
+            eng.overlap is not (pools_seen_on == "tpu"):
+        raise AssertionError("engine built as on %r chose overlap=%r"
+                             % (pools_seen_on, eng.overlap))
     rids = [eng.submit(p, n) for p, n in prompts]
     outs = eng.run()
     eng.close()
@@ -386,10 +401,16 @@ def leg_serve_schedules(ctx):
     ctx.note("an engine told nothing chose the %s schedule (spec_K=2: "
              "serial)" % ("pipelined" if on_tpu else "serial"))
 
+    # serial against pipelined: each engine built as on that platform,
+    # both with the attention lowering this platform's engines take
+    both = {False: "cpu", True: "tpu"}
+    kernel = "pallas" if on_tpu else "xla"
+
     # the preset as deployed, more requests than slots (slots reused)
     prompts = _prompts(ctx, n=3 * ctx.sz["engine"]["num_slots"])
-    gen = {ov: _serve(ctx, params, cfg, prompts, overlap=ov)
-           for ov in (False, True)}
+    gen = {ov: _serve(ctx, params, cfg, prompts, pools_seen_on=plat,
+                      kernel=kernel)
+           for ov, plat in both.items()}
     _identical("bf16+w8 serial vs pipelined", gen[False], gen[True])
     ctx.note("full preset, bf16+w8: serial and pipelined token-identical "
              "on %d requests over %d slots, %d tokens"
@@ -398,8 +419,9 @@ def leg_serve_schedules(ctx):
     del params
 
     params, cfg, engine, prompts = _falcon_toy()
-    gen = {ov: _serve(ctx, params, cfg, prompts, overlap=ov, **engine)
-           for ov in (False, True)}
+    gen = {ov: _serve(ctx, params, cfg, prompts, pools_seen_on=plat,
+                      kernel=kernel, **engine)
+           for ov, plat in both.items()}
     _identical("falcon_h1 serial vs pipelined", gen[False], gen[True])
     ctx.note("falcon_h1 (toy, %s): serial and pipelined token-identical "
              "on %d requests over %d slots, %d tokens"

@@ -310,8 +310,7 @@ class ServingCluster:
                  affinity_slack=None,
                  affinity_capacity=4096, retain_results=4096,
                  kernel=None, spec_K=0, spec_drafter="ngram",
-                 spec_ngram=2, tp=1, mesh=None, tier_bytes=None,
-                 overlap=None):
+                 spec_ngram=2, tp=1, mesh=None, tier_bytes=None):
         if replicas < 1:
             raise ValueError("ServingCluster: replicas must be >= 1")
         self.num_slots = num_slots
@@ -387,7 +386,7 @@ class ServingCluster:
             prefix_cache=prefix_cache, metrics=bool(metrics),
             kernel=kernel, spec_K=spec_K, spec_drafter=spec_drafter,
             spec_ngram=spec_ngram, tp=tp, mesh=mesh,
-            tier_bytes=tier_bytes, overlap=overlap)
+            tier_bytes=tier_bytes)
         # kept for add_replica (autoscaler scale-up): a replica added
         # mid-run must be built from the SAME params/config as the
         # originals (references only — params are already placed)
@@ -1251,7 +1250,6 @@ class ServingCluster:
                 rep.thread.join(timeout)
         for rep in self.replicas:
             if rep.engine is not None:
-                # overlap engines carry a planner thread; join it out
                 rep.engine.close()
         self._monitor.join(timeout)
 
@@ -1517,8 +1515,7 @@ class DisaggServingCluster:
                  pages_per_slot=None, prefill_chunk=8, kv_int8=False,
                  kernel=None, spec_K=0, metrics=None, registry=None,
                  watchdog_s=None, spawn=True, host="127.0.0.1",
-                 port=0, ready_timeout=None, tier_bytes=None,
-                 overlap=None):
+                 port=0, ready_timeout=None, tier_bytes=None):
         if prefill < 1 or decode < 1:
             raise ValueError("DisaggServingCluster: needs >= 1 "
                              "prefill and >= 1 decode worker")
@@ -1535,8 +1532,7 @@ class DisaggServingCluster:
             num_slots=num_slots, page_size=page_size,
             num_pages=num_pages, pages_per_slot=pages_per_slot,
             prefill_chunk=prefill_chunk, kv_int8=kv_int8,
-            kernel=kernel, spec_K=spec_K, tier_bytes=tier_bytes,
-            overlap=overlap)
+            kernel=kernel, spec_K=spec_K, tier_bytes=tier_bytes)
         # mirror of the workers' engine limits, so an invalid request
         # fails the submit() call instead of poisoning a worker
         pps = pages_per_slot if pages_per_slot is not None \
@@ -3417,7 +3413,7 @@ class _DisaggWorker:
         """Decode role: admit handed-off requests whose pages are all
         installed, as slots free up.  Installs themselves run AFTER
         the step (round 21 — off the dispatch critical path, hidden
-        behind the launched step's device time under overlap); when
+        behind the launched step's device time at depth 1); when
         the engine is idle there is nothing to hide behind, so
         install eagerly here."""
         if self.eng._inflight is None and not any(
@@ -3685,8 +3681,6 @@ class _DisaggWorker:
             "swap_outs": eng.stats["swap_outs"],
             "swap_ins": eng.stats["swap_ins"],
             "overlap_steps": eng.stats["overlap_steps"],
-            "overlap_fences": eng.stats["overlap_fences"],
-            "host_hidden_ms": eng.stats["host_hidden_ms"],
             # inlined (not eng.tier.stats()): this fn is the
             # stats_req reply path, so the dict build must be
             # call-free — proto-reply-pairing's exception-edge rule

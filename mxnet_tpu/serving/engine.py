@@ -79,27 +79,27 @@ degrades to the pre-tier behavior when the tier refuses or the entry
 was LRU-aged: exactness NEVER depends on the tier, only latency does.
 
 Round 21 hides the host scheduler behind device execution (ROADMAP
-item 4, ``overlap=True``; since PR 29 what an engine built without
-``overlap=`` runs where its pools live on a TPU and it does not
-speculate): the step
-program grows a per-row ``tok_src`` selector so a decode row's input
-token can come from the PREVIOUS step's device-resident argmax matrix
-instead of a host-fed value — step N+1 dispatches against step N's
-device output before the host has read step N back — and a planner
-thread builds step N+1's admission / prefix match / page allocation /
-row batch into a second preallocated buffer set while step N runs on
-device.  The host consumes tokens one step behind (stop conditions,
+item 4).  There is one step loop and its pipeline depth is 0 or 1, read
+in ``__init__`` from where the pools live, as ``kernel`` is: depth 1
+(``eng.overlap``) on a TPU without speculation, depth 0 anywhere else —
+on XLA:CPU the "device" is the host's own cores, nothing to hide
+behind, and the drafters of a ``spec_K > 0`` engine read committed host
+tokens.  No argument and no environment variable takes part.  At depth
+1 the step program grows a per-row ``tok_src`` selector so a decode
+row's input token can come from the PREVIOUS step's device-resident
+argmax matrix instead of a host-fed value: ``step()`` builds step N+1's
+admission / prefix match / page allocation / row batch into a second
+preallocated buffer set and dispatches it against step N's device
+output before the host has read step N back, all on the caller's
+thread (the engine starts none: PR 29 measured a planner thread's build
+0.15 ms dearer than the inline one, the interpreter's lock being the
+caller's).  The host consumes tokens one step behind (stop conditions,
 commits, metrics); a committed stop/eos/cancel/preemption that
-invalidates the speculatively dispatched step reconciles EXACTLY:
-the stale row's writes land at positions beyond every committed read
-range (the same argument that makes preemption recompute-exact), so
-per-row skip suffices, and the only fence is speculative decode
-(drafters need committed host tokens — those steps run serially,
-which is why a ``spec_K > 0`` engine is serial unless told otherwise).
-``overlap=False`` (what ``overlap=None`` resolves to off the TPU: on
-XLA:CPU the "device" is the host's own cores, nothing to hide behind)
-is bit-for-bit the round-20 engine: same compiled program, same host
-schedule, same commit order.  No environment variable takes part.
+invalidates the speculatively dispatched step reconciles EXACTLY: the
+stale row's writes land at positions beyond every committed read range
+(the same argument that makes preemption recompute-exact), so per-row
+skip suffices.  Depth 0 is bit-for-bit the round-20 engine: same
+compiled program, same host schedule, same commit order.
 
 The step program is built from a MODEL MODULE's three functions,
 ``serve_embed`` / ``serve_block`` / ``serve_logits``: ``models/gpt.py``
@@ -150,7 +150,6 @@ import itertools
 import os
 import threading
 import time
-import weakref
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -536,8 +535,8 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
 class _StepBuffers:
     """One preallocated set of host-side step inputs.  The engine
     owns TWO and rotates: while step N (built into set A) executes on
-    device, the planner builds step N+1 into set B — and even the
-    serial path rotates, so no step's host buffers are ever mutated
+    device, ``_build_plan`` builds step N+1 into set B — and depth 0
+    rotates too, so no step's host buffers are ever mutated
     while a dispatch that snapshot them could still be staging
     (round-21 satellite: no fresh numpy allocations per step)."""
 
@@ -569,22 +568,22 @@ class _StepBuffers:
 
 class _Plan:
     """One fully-built step: the row batch plus everything the commit
-    needs recorded AT BUILD TIME.  Under overlap the commit runs one
-    step later than the build, after the planner has already advanced
-    ``n_prefilled`` for the NEXT plan — so commits must never read
-    live scheduler positions; they read these records."""
+    needs recorded AT BUILD TIME.  At depth 1 the commit runs one
+    step later than the build, after the next plan's build has already
+    advanced ``n_prefilled`` — so commits must never read live
+    scheduler positions; they read these records."""
 
     __slots__ = ("buf", "samplers", "spec_plan", "decode_pos",
                  "was_decode", "prefill_mid", "n_dec_rows",
                  "n_pre_rows", "n_rows_used", "decode_rids",
-                 "prefill_spans", "carried", "fenced", "empty",
+                 "prefill_spans", "carried", "empty",
                  "pipelined", "kv_pages", "state_slots", "resets",
                  "admit")
 
     def __init__(self):
         self.buf = None
         self.samplers = []          # requests sampling a token
-        self.spec_plan = {}         # rid -> drafts (serial plans only)
+        self.spec_plan = {}         # rid -> drafts (depth 0 only)
         self.decode_pos = {}        # rid -> its sampling row's pos
         self.was_decode = {}        # rid -> fed a decode row?
         self.prefill_mid = []       # (req, n_prefilled) mid-prefill
@@ -594,9 +593,8 @@ class _Plan:
         self.decode_rids = []       # trace
         self.prefill_spans = []     # trace: (rid, row_lo, row_hi)
         self.carried = 0            # rows fed from device prev_tok
-        self.fenced = False         # spec fence: nothing built
         self.empty = True           # no live rows
-        self.pipelined = False      # built for the overlap path
+        self.pipelined = False      # built by a depth-1 engine
         self.kv_pages = 0           # K/V pages the step's attention reads
         self.state_slots = 0        # slots whose state the step updates
         self.resets = 0             # of them, started from zero
@@ -607,41 +605,6 @@ class _Plan:
         were built in (and holds a slot)."""
         return req.slot is not None and req.state == "running" \
             and self.admit.get(req.rid) == req.admit_seq
-
-
-def _planner_main(engine_ref, ctl, go, ready):
-    """Overlap planner thread body.  A module-level function holding
-    only a WEAK engine reference: a bound-method target would keep
-    the engine alive through the thread frame and the finalizer below
-    could never fire.  Protocol: the engine thread sets ``go`` after
-    each commit; the planner builds the next plan under the engine
-    lock, publishes it, and sets ``ready`` (the Event pair is the
-    happens-before edge for the unlocked ``_plan`` handoff)."""
-    while True:
-        go.wait()
-        go.clear()
-        if ctl["stop"]:
-            return
-        eng = engine_ref()
-        if eng is None:
-            return
-        # the build that hides behind the step on the device: the same
-        # name as the engine thread's phase, told apart by ``hidden``
-        # (what _build_plan books into stats["host_hidden_ms"])
-        with eng._mu, profiler.span(
-                "engine.plan", hidden=int(eng._inflight is not None)):
-            plan = eng._build_plan(overlap=True)
-        eng._plan = plan
-        ready.set()
-        del eng
-
-
-def _stop_planner(ctl, go):
-    """weakref.finalize target: unpark and retire the planner when
-    the engine is collected (captures the control dict + event, never
-    the engine)."""
-    ctl["stop"] = True
-    go.set()
 
 
 _engine_seq = itertools.count()
@@ -971,17 +934,6 @@ class ServingEngine:
         None reads ``MXNET_SERVE_TIER_BYTES`` (off unless set); 0
         disables — the engine then behaves bit-identically to round
         17 (drop on pressure, recompute on resume).
-    overlap : the step loop's schedule.  True pipelines it (module
-        docstring, round 21): ``step()`` launches step N+1 against
-        step N's device-resident tokens, then reads step N back and
-        commits it, and a planner thread builds step N+2's batch
-        while N+1 computes — a finish is reported one call after the
-        step that produced its last token; tokens are identical.
-        False runs plan, launch, read-back, commit in turn.  None
-        (the default) reads it from what the engine can observe:
-        pipelined where its pools were placed on a TPU and
-        ``spec_K == 0`` (with speculation every sampling step fences
-        the pipeline), serial anywhere else.
     rid_start : first request id this engine assigns (a cluster gives
         each replica a disjoint block so rids — and their trace
         swimlanes — are unique cluster-wide).
@@ -1000,7 +952,7 @@ class ServingEngine:
                  kv_int8=False, prefix_cache=False, metrics=None,
                  registry=None, rid_start=0, kernel=None, spec_K=0,
                  spec_drafter="ngram", spec_ngram=2, tp=1, mesh=None,
-                 tier_bytes=None, overlap=None, device=None):
+                 tier_bytes=None, device=None):
         if not cfg.causal:
             cfg = dataclasses.replace(cfg, causal=True)
         # a family whose sequences keep state that is no page (its
@@ -1178,18 +1130,15 @@ class ServingEngine:
             if prefix_cache else None
         if self.prefix is not None:
             self.cache.pressure_cb = self.prefix.evict
-        # one step loop, two schedules (round 21; the module
-        # docstring): pipelined, the host plans, stages and launches
-        # step N+1 while step N computes; serial, it alternates with
-        # the device.  Where none is asked for, the pipelined one where
-        # there is a device to hide behind (PR 29: the chip waited
-        # 4 ms of a 12 ms step for the host): on XLA:CPU the "device"
-        # is the host's own cores.  With speculation every sampling
-        # step would fence (``_build_plan``: the drafters read
-        # committed host tokens), so such an engine stays serial.
-        if overlap is None:
-            overlap = on_tpu and self.spec_K == 0
-        self.overlap = bool(overlap)
+        # the step loop's pipeline depth (round 21; the module
+        # docstring), a fact the engine reads and no argument: 1, the
+        # host plans, stages and launches step N+1 while step N
+        # computes, where there is a device to hide behind (PR 29: the
+        # chip waited 4 ms of a 12 ms step for the host); 0, it
+        # alternates with the device, on XLA:CPU, where the "device" is
+        # the host's own cores, and with speculation, whose drafters
+        # read committed host tokens
+        self.overlap = on_tpu and self.spec_K == 0
         self._copy_fn = None              # jitted COW page copy
         if self.prefix is not None:
             # pre-compile the COW program now (scratch-onto-scratch is
@@ -1216,10 +1165,8 @@ class ServingEngine:
                       "prefix_hit_tokens": 0, "cow_copies": 0,
                       "spec_drafted": 0, "spec_accepted": 0,
                       "swap_outs": 0, "swap_ins": 0,
-                      "slot_occupancy_sum": 0.0,
-                      "host_hidden_ms": 0.0, "overlap_steps": 0,
-                      "overlap_fences": 0, "kv_pages_window": 0,
-                      "kv_pages_read": 0}
+                      "slot_occupancy_sum": 0.0, "overlap_steps": 0,
+                      "kv_pages_window": 0, "kv_pages_read": 0}
         if self._stateful:
             # slot-states read and written (the live slots of each
             # dispatched step), those started from zero, and the bytes
@@ -1231,14 +1178,10 @@ class ServingEngine:
             # only while every slot is live with one row
             self.stats.update(ssm_state_updates=0, ssm_state_resets=0,
                               ssm_state_bytes=0)
-        # -------- round 21: scheduler/planner shared state ---------
-        # One lock (_mu) guards everything BOTH the engine thread and
-        # the planner thread touch: queue/slots/pages/prefix/stats and
-        # the request fields they mutate.  The plan handoff itself
-        # (_plan / _plan_pending / _inflight*) is engine-thread-owned
-        # or sequenced by the _plan_go/_plan_ready Event pair and
-        # deliberately stays OUTSIDE the lock — pylocklint sees those
-        # groups as consistently unguarded.
+        # One lock (_mu) guards what the caller of step() shares with
+        # the threads that submit, cancel, preempt and admit_prefilled:
+        # queue/slots/pages/prefix/stats and the request fields they
+        # mutate.  The in-flight step (_inflight) is step()'s own.
         self._mu = threading.Lock()
         self._bufs = (
             _StepBuffers(self.n_rows, num_slots, self.spec_K,
@@ -1250,15 +1193,9 @@ class ServingEngine:
         # alloc/free time (satellite: no full rebuild per step); row
         # num_slots stays all-scratch for dead rows
         self._bt = np.zeros((num_slots + 1, pages_per_slot), np.int32)
-        self._inflight = None        # _Plan currently on device
-        self._inflight_tok = None    # its device-resident next_tok
-        self._plan = None            # planner -> engine handoff slot
-        self._plan_pending = False   # engine-thread-only flag
-        self._plan_go = threading.Event()
-        self._plan_ready = threading.Event()
-        self._planner = None         # lazily spawned on first overlap
-        self._planner_ctl = None
-        self._finalizer = None
+        # the step on the device at depth 1: (its _Plan, its
+        # device-resident next_tok)
+        self._inflight = None
         self._tok0 = None            # lazy zeros for the first prev_tok
         if metrics is None:
             # an explicitly supplied registry is a request for
@@ -1426,8 +1363,9 @@ class ServingEngine:
                     self._obs.trace.flush()
 
     # ----------------------------------------------------- plumbing --
-    # (Every helper below mutates scheduler state the planner thread
-    # also reads/writes — callers hold the engine lock.)
+    # (Every helper below mutates scheduler state that submit, cancel
+    # and preempt reach from other threads — callers hold the engine
+    # lock.)
 
     def _bt_set(self, slot, pages):
         """Patch the canonical block table's row for ``slot`` to
@@ -1787,12 +1725,16 @@ class ServingEngine:
     def step(self):
         """One engine iteration.  Returns the list of request ids
         whose COMMIT landed during this call (possibly empty); False
-        when there is nothing left to do.  ``overlap=False`` runs the
-        round-20 serial schedule; ``overlap=True`` runs the pipelined
-        schedule — dispatch step N+1 against step N's device-resident
-        tokens, then drain/commit step N — so a request's finish is
-        reported one call after the step that produced its last
-        token.
+        when there is nothing left to do.  One body, whose pipeline
+        depth the engine read from its platform (``self.overlap``):
+        build the next plan, stage and launch it; then, at depth 0,
+        read that step back and commit it; at depth 1, read back and
+        commit the step that WAS in flight — launched by the call
+        before, against whose device-resident tokens this call's was
+        dispatched — and leave this call's in flight, so a request's
+        finish is reported one call after the step that produced its
+        last token.  Tokens are the same at either depth.  All of it
+        runs on the caller's thread.
 
         Every call that finds work is one ``engine.step``
         ``profiler.span`` whose children are its phases —
@@ -1800,20 +1742,29 @@ class ServingEngine:
         ``engine.wait``, ``engine.commit`` — whether or not metrics
         are on (docs/observability.md, "Spans on the device's
         clock")."""
-        if self.overlap:
-            self._ensure_planner()
-            # _take_plan's own idle test, made before the span opens:
-            # a call that finds no work is no step
-            if not self._plan_pending:
-                with self._mu:
-                    if self._inflight is None and self._idle():
-                        return False
-        elif self._idle():
-            return False
+        with self._mu:
+            # made before the span opens: a call that finds no work is
+            # no step
+            if self._inflight is None and self._idle():
+                return False
         with profiler.span("engine.step",
                            step=self.stats["steps"]) as sp:
-            return self._step_overlap(sp) if self.overlap \
-                else self._step_serial(sp)
+            with profiler.span("engine.plan"), self._mu:
+                plan = self._build_plan()
+            self._span_args(sp, plan)
+            launched = None
+            if not (plan.empty and self.overlap):
+                # (depth 1 dispatches no empty plan: every live request
+                # rides the in-flight step, which is all there is to
+                # drain)
+                with self._operator_span():
+                    launched = plan, self._dispatch(plan)
+            if self.overlap:
+                # this call's step stays in flight; the one to read
+                # back is the step the call before left there
+                launched, self._inflight = self._inflight, launched
+            return [] if launched is None \
+                else self._drain(*launched, sp.t0)
 
     def _idle(self):
         return not self._queue and all(r is None for r in self._slots)
@@ -1836,177 +1787,43 @@ class ServingEngine:
             args["resets"] = plan.resets
         sp.set(**args)
 
-    def _step_serial(self, sp):
-        """One fully-serial iteration — the round-20 schedule exactly:
-        build (phases A+B, under the lock), dispatch, block on the
-        readback, commit (phase C, under the lock).  ``sp`` is the
-        call's ``engine.step`` span."""
-        with profiler.span("engine.plan"), self._mu:
-            plan = self._build_plan(overlap=False)
-        self._span_args(sp, plan)
-        with self._operator_span():
-            next_tok = self._dispatch(plan)
-            with profiler.span("engine.wait") as wait:
-                # mxlint: allow(host-sync) -- intentional: the ONE
-                # device sync per step; the host scheduler branches on
-                # the sampled tokens (stop conditions, commits) before
-                # the next step
-                next_tok = np.asarray(next_tok)
-        with profiler.span("engine.commit"), self._mu:
-            return self._commit(plan, next_tok, wait.t1, sp.t0)
-
-    def _step_overlap(self, sp):
-        """One pipelined iteration (round 21).  Call k: take plan k
-        (planner-built while call k-1's dispatch executed, or built
-        inline on a cold start), dispatch it against the in-flight
-        step's device-resident tokens, THEN drain/commit step k-1 —
-        the host-side commit of k-1 and the planner's build of k+1
-        both hide behind step k's device execution."""
-        t0 = sp.t0
-        with profiler.span("engine.plan"):
-            plan = self._take_plan()
-        if plan is None:
-            return False
-        if plan.fenced:
-            # speculation fence: drafting reads fully-committed host
-            # state, so drain the pipeline and run ONE exact serial
-            # step (full round-20 semantics, spec planning included),
-            # then resume pipelining
-            finished = []
-            old, old_tok = self._inflight, self._inflight_tok
-            self._inflight = None
-            self._inflight_tok = None
-            if old is not None:
-                finished += self._drain(old, old_tok, t0)
-            if not self._idle():
-                finished += self._step_serial(sp)
-            self._maybe_plan_ahead()
-            return finished
-        self._span_args(sp, plan)
-        old, old_tok = self._inflight, self._inflight_tok
-        if not plan.empty:
-            with self._operator_span():
-                tdev = self._dispatch(plan)
-            self._inflight = plan
-            self._inflight_tok = tdev
-        else:
-            # nothing to dispatch (every live request rides the
-            # in-flight step) — just drain
-            self._inflight = None
-            self._inflight_tok = None
-        finished = self._drain(old, old_tok, t0) if old is not None \
-            else []
-        self._maybe_plan_ahead()
-        return finished
-
-    def _take_plan(self):
-        """Fetch the next plan: the planner's (if one was signalled —
-        the ``_plan_ready`` wait is the happens-before edge for the
-        unlocked handoff), else build inline under the lock (cold
-        start / post-fence).  None means the engine is idle."""
-        if self._plan_pending:
-            self._plan_ready.wait()
-            self._plan_ready.clear()
-            self._plan_pending = False
-            plan = self._plan
-            self._plan = None
-            return plan
-        with self._mu:
-            if self._inflight is None and self._idle():
-                return None
-            return self._build_plan(overlap=True)
-
     def _drain(self, plan, tok, t0):
         """Block on a dispatched step's sampled tokens and commit it.
-        Under overlap this runs AFTER the next step was dispatched —
-        the readback waits out step N's tail while N+1 executes."""
+        At depth 1 this runs AFTER the next step was dispatched — the
+        readback waits out step N's tail while N+1 executes."""
         with profiler.span("engine.wait") as wait:
             # mxlint: allow(host-sync) -- intentional: the ONE device
-            # sync per step — under overlap one step BEHIND dispatch
-            # (the latency-hiding point); the host branches on step
-            # N's tokens (stop conditions, commits) while step N+1
+            # sync per step — at depth 1 one step BEHIND dispatch (the
+            # latency-hiding point); the host branches on step N's
+            # tokens (stop conditions, commits) while step N+1
             # executes
             next_tok = np.asarray(tok)
         with profiler.span("engine.commit"), self._mu:
             return self._commit(plan, next_tok, wait.t1, t0)
 
-    def _maybe_plan_ahead(self):
-        """Signal the planner to build the next plan while the
-        just-dispatched step executes.  The pending flag and the go/
-        ready Events sequence the handoff; the work check itself
-        takes the lock (queue/slots are shared)."""
-        with self._mu:
-            work = bool(self._queue) or self._inflight is not None \
-                or any(r is not None for r in self._slots)
-        if work:
-            self._plan_pending = True
-            self._plan_go.set()
-
-    def _ensure_planner(self):
-        """Lazily spawn (or respawn after close()) the planner
-        thread.  A fresh control dict per spawn keeps a stale
-        finalizer from stopping the new thread."""
-        if self._planner is not None and self._planner.is_alive():
-            return
-        ctl = {"stop": False}
-        self._planner_ctl = ctl
-        self._plan_go.clear()
-        self._plan_ready.clear()
-        self._plan_pending = False
-        self._plan = None
-        t = threading.Thread(
-            target=_planner_main,
-            args=(weakref.ref(self), ctl, self._plan_go,
-                  self._plan_ready),
-            daemon=True, name="serving-engine-planner")
-        self._finalizer = weakref.finalize(self, _stop_planner, ctl,
-                                           self._plan_go)
-        self._planner = t
-        t.start()
-
     def close(self):
-        """Stop the planner thread (idempotent; serial engines no-op).
-        Garbage collection alone also stops it via the finalizer, but
-        an explicit close joins the thread out."""
-        ctl = self._planner_ctl
-        t = self._planner
-        self._planner = None
-        self._planner_ctl = None
-        if ctl is not None:
-            ctl["stop"] = True
-            self._plan_go.set()
-        if t is not None and t.is_alive():
-            t.join(timeout=5)
+        """Let go of the in-flight step (idempotent).  The engine
+        starts no thread, so there is nothing to stop or join; callers
+        that own engines (clusters, the benchmark's driver) call this
+        when they are done with one."""
+        self._inflight = None
 
     # mxlint: requires(ServingEngine._mu)
-    def _build_plan(self, overlap=False):
+    def _build_plan(self):
         """Phases A+B of the engine step — admission, page
         allocation, speculation planning, and the fixed-shape row
         batch — built into the next rotated buffer set and recorded
-        as a ``_Plan``.  ``overlap=True`` additionally plans CARRIED
-        decode rows for the in-flight step's samplers: their input
-        token is the in-flight step's device-resident argmax
-        (``tok_src``), their position the in-flight sampling position
-        + 1 — the pipelined dispatch never waits for the readback.
-        Everything the later commit needs is recorded here at build
-        time (the planner may build k+1 before k's commit runs)."""
-        t_b0 = time.perf_counter()
-        hidden = overlap and self._inflight is not None
+        as a ``_Plan``.  Where a step is in flight (depth 1) it
+        additionally plans CARRIED decode rows for that step's
+        samplers: their input token is the in-flight step's
+        device-resident argmax (``tok_src``), their position the
+        in-flight sampling position + 1 — the dispatch never waits for
+        the readback.  Everything the later commit needs is recorded
+        here at build time (plan k+1 is built before k's commit
+        runs)."""
         plan = _Plan()
-        plan.pipelined = bool(overlap)
-        inflight = self._inflight if overlap else None
-        if overlap and self.spec_K > 0 and (
-                (inflight is not None and inflight.samplers)
-                or any(r is not None and r.pending is not None
-                       for r in self._slots)):
-            # speculation fence: the drafters read req.generated,
-            # which for any in-flight sampler is one token behind the
-            # device — don't build, let the caller drain and run one
-            # serial step.  Pure-prefill phases (no samplers, no
-            # pending) still pipeline under spec_K > 0.
-            plan.fenced = True
-            self.stats["overlap_fences"] += 1
-            return plan
+        plan.pipelined = self.overlap
+        inflight = self._inflight[0] if self._inflight else None
         self._admit()
 
         # ---- phase A: secure pages.  _ensure_page may PREEMPT the
@@ -2037,8 +1854,8 @@ class ServingEngine:
                     and req.rid not in inflight_rids:
                 self._ensure_page(req, req.n_cached)
         # speculation planning (drafting + draft-depth pages) is part
-        # of phase A for the same reason (pipelined builds reach here
-        # only with spec_K == 0 — the fence above — so this is {})
+        # of phase A for the same reason (a speculating engine is at
+        # depth 0: the drafters read committed host tokens)
         spec_plan = self._plan_speculation()
         plan.spec_plan = spec_plan
         budget = self.prefill_chunk
@@ -2072,7 +1889,7 @@ class ServingEngine:
         slot_rows, tok_src = buf.slot_rows, buf.tok_src
         samplers = plan.samplers
         r = 0
-        # carried decode rows (overlap only): input = the in-flight
+        # carried decode rows (depth 1 only): input = the in-flight
         # step's argmax for this slot, read on device via tok_src
         if inflight is not None:
             for req in inflight.samplers:
@@ -2159,7 +1976,7 @@ class ServingEngine:
             if not sampled:
                 # still mid-prefill: the commit advances n_cached to
                 # the rows THIS plan wrote (recorded now — by commit
-                # time the planner may have pushed n_prefilled on)
+                # time the next build may have pushed n_prefilled on)
                 plan.prefill_mid.append((req, req.n_prefilled))
                 plan.admit[req.rid] = req.admit_seq
             if tracing and req.n_prefilled > p0:
@@ -2170,10 +1987,10 @@ class ServingEngine:
         plan.n_pre_rows = sum(pre.values())
         plan.state_slots += plan.n_dec_rows
         plan.empty = r == 0
-        if r or not overlap:
-            # an empty pipelined plan is never dispatched — don't book
-            # a phantom batch (the serial path dispatches dead batches
-            # only when the idle check already found work)
+        if r or not self.overlap:
+            # an empty plan is never dispatched at depth 1 — don't book
+            # a phantom batch (depth 0 dispatches dead batches only
+            # when the idle check already found work)
             self.stats["dead_rows"] += T - r
             # how far the attention's reads follow the rows: the
             # window is every row's whole table; the walk reads up to
@@ -2193,11 +2010,6 @@ class ServingEngine:
                                            self.cache.pages_in_use)
             self.stats["slot_occupancy_sum"] += \
                 sum(r_ is not None for r_ in self._slots) / float(S)
-        dt = time.perf_counter() - t_b0
-        if hidden:
-            # this build ran while a dispatched step executed on
-            # device: its host time is off the critical path
-            self.stats["host_hidden_ms"] += dt * 1e3
         return plan
 
     def _dispatch(self, plan):
@@ -2215,8 +2027,9 @@ class ServingEngine:
             if self._stateful:
                 staged.append(jnp.asarray(buf.fresh))
             if self.overlap:
-                prev = self._inflight_tok
-                if prev is None:
+                if self._inflight is not None:
+                    prev = self._inflight[1]
+                else:
                     if self._tok0 is None:
                         self._tok0 = jnp.zeros(
                             (self.num_slots, 1 + self.spec_K), jnp.int32)
@@ -2230,10 +2043,10 @@ class ServingEngine:
     # mxlint: requires(ServingEngine._mu)
     def _commit(self, plan, next_tok, now, t_step0):
         """Phase C: consume a completed step's sampled tokens — stop
-        conditions, retirement, metrics.  Under overlap this runs one
+        conditions, retirement, metrics.  At depth 1 this runs one
         step after the plan was built (and after the NEXT plan was
-        already built), so it reads no live planner state: every
-        position it needs was recorded on the plan at build time."""
+        already built), so it reads no live scheduler position: every
+        one it needs was recorded on the plan at build time."""
         obs = self._obs
         tracing = obs is not None and profiler.is_recording()
         self.stats["steps"] += 1
@@ -2318,7 +2131,7 @@ class ServingEngine:
                                               args=rargs)
         # slots that fed prefill rows but did not finish their input
         # this step just advance n_cached — to the position recorded
-        # at build time (by now the planner may have pushed
+        # at build time (by now the next build may have pushed
         # n_prefilled past what THIS step's rows actually wrote)
         for req, p1 in plan.prefill_mid:
             if not plan.current(req):

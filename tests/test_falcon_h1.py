@@ -20,6 +20,7 @@ import sys
 
 import numpy as np
 import pytest
+from conftest import pools_seen_on
 
 import jax
 import jax.numpy as jnp
@@ -164,12 +165,17 @@ def test_slot_scan_and_conv_match_the_literal_recurrence(case):
 
 # ---------------------------------------------------------- the engine ---
 
-def _engine(model, **kw):
+def _engine(model, overlap=False, kernel="xla", **kw):
+    """``overlap``: the schedule an engine takes where its pools live
+    on a TPU, reached on the CPU by substituting that observation."""
     params, cfg = model
     args = dict(num_slots=3, page_size=8, pages_per_slot=8,
                 prefill_chunk=8)
     args.update(kw)
-    return ServingEngine(params, cfg, **args)
+    with pools_seen_on("tpu" if overlap else "cpu"):
+        eng = ServingEngine(params, cfg, kernel=kernel, **args)
+    assert eng.overlap is overlap
+    return eng
 
 
 def _held_to_reference(ref, model, eng, rids):

@@ -4,7 +4,7 @@
 The sweep runs >= 200 seeded schedules (7 scripted workloads x 2
 strategies x 20 seeds = 280; round 18 added the tier workload — spill
 racing match racing preemption; round 21 added the overlap workload —
-planner thread racing steps, submits, and a mid-pipeline cancel)
+the pipelined step loop racing submits and a mid-pipeline cancel)
 through
 ``tools.analysis.interleave``: every
 schedule serializes the cluster's threads onto one runnable-at-a-time
@@ -24,6 +24,7 @@ bit-identical yield-trace hashes.
 """
 import numpy as np
 import pytest
+from conftest import pools_seen_on
 
 import mxnet_tpu as mx  # noqa: F401  (conftest device setup)
 
@@ -63,9 +64,11 @@ def env():
     # variant — warm it too, same engine geometry as the workloads
     # (wl_overlap_plan must never compile under the scheduler)
     from mxnet_tpu.serving import ServingEngine
-    eng = ServingEngine(params, cfg, num_slots=2, page_size=4,
-                        prefill_chunk=6, prefix_cache=True,
-                        overlap=True)
+    with pools_seen_on("tpu"):
+        eng = ServingEngine(params, cfg, num_slots=2, page_size=4,
+                            prefill_chunk=6, prefix_cache=True,
+                            kernel="xla")
+    assert eng.overlap
     eng.submit(np.arange(1, 7, dtype=np.int32), 4)
     eng.run()
     eng.close()
@@ -290,9 +293,9 @@ def wl_tier_spill(params, cfg, ref):
 
 
 def wl_overlap_plan(params, cfg, ref):
-    """Round 21: the overlap pipeline's planner thread racing steps,
-    submits, and cancels.  One overlap=True replica — every step's
-    plan is built by the planner under the engine lock while the
+    """Round 21: the pipelined step loop racing submits and cancels.
+    One replica whose engine is pipelined (its pools seen on a TPU) —
+    every step's plan is built under the engine lock while the
     previous step executes — with a submit burst arriving through a
     second thread and a cancel landing at whatever pipeline depth the
     schedule picks.  Every completed request must be exact (the
@@ -303,8 +306,9 @@ def wl_overlap_plan(params, cfg, ref):
     from mxnet_tpu.serving import ServingCluster
     from mxnet_tpu.serving import cluster as cluster_mod
     wl = _prompts_mixed(5)
-    cl = ServingCluster(params, cfg, replicas=1, num_slots=2,
-                        page_size=4, prefill_chunk=6, overlap=True)
+    with pools_seen_on("tpu"):
+        cl = ServingCluster(params, cfg, replicas=1, num_slots=2,
+                            page_size=4, prefill_chunk=6, kernel="xla")
     try:
         assert cl.replicas[0].engine.overlap
         first = [cl.submit(p, n) for p, n in wl[:2]]

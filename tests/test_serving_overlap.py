@@ -1,41 +1,46 @@
-"""Latency-hiding overlap (round 21): the double-buffered engine
-pipeline with device-carried sampling must be BIT-IDENTICAL to the
-serial schedule — and to ``models/gpt.py generate`` — under every
-stop condition that can invalidate a speculatively dispatched step.
+"""Latency-hiding overlap (round 21): the engine's step loop at
+pipeline depth 1, with device-carried sampling, must be BIT-IDENTICAL
+to depth 0 — and to ``models/gpt.py generate`` — under every stop
+condition that can invalidate a speculatively dispatched step.
+
+The depth is no argument: an engine reads it from the platform its
+pools live on (1 on a TPU without speculation, 0 elsewhere), so on the
+CPU these tests reach the TPU's schedule by substituting that
+observation while the engine is built (``conftest.pools_seen_on``) and
+naming the CPU's attention lowering themselves (``kernel="xla"``).
 
 Exactness pins:
 
-* overlap ON vs OFF, mixed prompt/output lengths, through eos stops,
-  mid-pipeline preemption, and a cancel racing the planner thread —
-  identical states and tokens for every non-cancelled request, zero
-  leaked pages/refs either way;
+* depth 1 vs depth 0, mixed prompt/output lengths, through eos stops,
+  mid-pipeline preemption, and a cancel between two steps — identical
+  states and tokens for every non-cancelled request, zero leaked
+  pages/refs either way;
 * a cancelled request's committed tokens may legitimately differ by
   pipeline depth (the cancel lands one step earlier or later), but
   the shorter transcript must prefix the longer — a wrong carried
   token would break the prefix, not just the length;
-* ``spec_K > 0`` engines fence the pipeline (carried argmaxes can't
-  feed the draft matcher, which needs host tokens) and must degrade
-  to exact serial behaviour;
 * both cluster flavors (replicated ``ServingCluster`` and the
-  process-split ``DisaggServingCluster``) stay generate-identical
-  with ``overlap=True`` threaded through their engine kwargs.
+  ``DisaggServingCluster`` protocol) stay generate-identical with
+  pipelined engines.
 
 Slow tier, group o (own group: every scenario pays a second compiled
 step variant — the ``tok_src`` program — on top of the serial one).
 
-Fast tier (PR 29: the pipelined schedule is what an engine built
-without ``overlap=`` runs on a TPU, so tier-1 guards it): the identity
-pin once more at the smallest sizes, the engine's own choice of
-schedule, and the benchmark's two serving cells rehearsed with the
-engine forced pipelined.
+Fast tier (PR 29: the pipelined schedule is what an engine runs on a
+TPU, so tier-1 guards it): the identity pin once more at the smallest
+sizes, the engine's own choice of schedule, that the schedule is no
+argument and the engine starts no thread (PR 30), and the benchmark's
+two serving cells rehearsed with the engine seen on a TPU.
 """
 import json
 import os
 import subprocess
 import sys
+import threading
 
 import numpy as np
 import pytest
+from conftest import pools_seen_on
 
 import mxnet_tpu as mx  # noqa: F401  (conftest device setup)
 
@@ -88,16 +93,24 @@ def _drain_engine(eng, chaos=None, cancel_rid=None):
     return steps
 
 
-def _engine_run(params, cfg, overlap, eos=None, chaos=None,
-                spec_K=0, lens=(3, 11, 7, 19, 5, 13),
-                maxnew=(9, 4, 1, 7, 12, 6), **engine):
+def _engine(params, cfg, overlap, **kw):
+    """A ``ServingEngine`` on the CPU's gather at the depth asked for:
+    the one a TPU's pools give it (``overlap``), or the CPU's own."""
     from mxnet_tpu.serving import ServingEngine
+    with pools_seen_on("tpu" if overlap else "cpu"):
+        eng = ServingEngine(params, cfg, kernel="xla", **kw)
+    assert eng.overlap is overlap
+    return eng
+
+
+def _engine_run(params, cfg, overlap, eos=None, chaos=None,
+                lens=(3, 11, 7, 19, 5, 13),
+                maxnew=(9, 4, 1, 7, 12, 6), **engine):
     rng = np.random.RandomState(7)
     prompts = _mixed(rng, cfg.vocab_size, lens)
     engine = engine or dict(num_slots=3, page_size=8, prefill_chunk=6,
                             prefix_cache=True)
-    eng = ServingEngine(params, cfg, spec_K=spec_K, overlap=overlap,
-                        **engine)
+    eng = _engine(params, cfg, overlap, **engine)
     rids = [eng.submit(p, m, eos_id=eos)
             for p, m in zip(prompts, maxnew)]
     _drain_engine(eng, chaos=chaos, cancel_rid=rids[1])
@@ -134,9 +147,8 @@ def test_overlap_bit_identical_to_serial(scenario):
     """The core pin: overlapped engine vs serial engine on the same
     mixed-length burst, with the speculatively dispatched step
     invalidated by eos stops, a mid-pipeline preemption, or a cancel
-    racing the planner — identical outcomes, zero leaks, and the
-    overlapped run actually pipelined (overlap_steps > 0) while
-    hiding host time (host_hidden_ms > 0)."""
+    between two steps — identical outcomes, zero leaks, and every
+    committed step of the overlapped run was dispatched pipelined."""
     params, cfg = _setup()
     kw = {"plain": {}, "eos": {"eos": 5},
           "preempt": {"chaos": "preempt"},
@@ -145,8 +157,7 @@ def test_overlap_bit_identical_to_serial(scenario):
     b, held_b, st = _engine_run(params, cfg, overlap=True, **kw)
     _assert_equiv(a, b, scenario)
     assert held_a == 0 and held_b == 0, (scenario, held_a, held_b)
-    assert st["overlap_steps"] > 0
-    assert st["host_hidden_ms"] > 0.0
+    assert st["steps"] > 0 and st["overlap_steps"] == st["steps"]
 
 
 @pytest.mark.slow
@@ -154,48 +165,17 @@ def test_overlap_matches_generate():
     """Single-request overlapped decode is token-identical to plain
     ``generate`` (the carried argmax is the same argmax the host
     would have fed back)."""
-    from mxnet_tpu.serving import ServingEngine
     params, cfg = _setup()
     rng = np.random.RandomState(11)
     for p, m in zip(_mixed(rng, cfg.vocab_size, (3, 11, 7)),
                     (8, 5, 6)):
         ref = _ref(params, cfg, p, m)
-        eng = ServingEngine(params, cfg, num_slots=2, page_size=8,
-                            prefill_chunk=8, overlap=True)
+        eng = _engine(params, cfg, True, num_slots=2, page_size=8,
+                      prefill_chunk=8)
         rid = eng.submit(p, m)
         out = eng.run()[rid]
         eng.close()
         assert np.array_equal(ref[:out.size], out), (ref, out)
-
-
-@pytest.mark.slow
-def test_overlap_spec_engine_fences_to_serial():
-    """spec_K > 0: the draft matcher needs host-visible tokens, so
-    every decode step with live samplers fences the pipeline — the
-    overlapped engine must produce bit-identical output to the serial
-    one, and the fence counter must prove the fencing actually
-    happened (not that overlap silently disabled itself)."""
-    from mxnet_tpu.serving import ServingEngine
-    params, cfg = _setup()
-    rng = np.random.RandomState(7)
-    prompts = _mixed(rng, cfg.vocab_size)
-    maxnew = [9, 4, 1, 7, 12, 6]
-
-    def run(overlap):
-        eng = ServingEngine(params, cfg, num_slots=3, page_size=8,
-                            prefill_chunk=6, spec_K=2,
-                            overlap=overlap)
-        for p, m in zip(prompts, maxnew):
-            eng.submit(p, m)
-        out = {k: v.tolist() for k, v in eng.run().items()}
-        st = dict(eng.stats)
-        eng.close()
-        return out, st
-
-    sa, _ = run(False)
-    sb, st = run(True)
-    assert sa == sb
-    assert st["overlap_fences"] > 0
 
 
 @pytest.mark.slow
@@ -204,14 +184,13 @@ def test_overlap_eos_invalidates_speculative_step_no_leak():
     speculative step: the junk row the dead slot computed must never
     be committed, the slot's pages must come back, and a follow-up
     request reusing the slot must still be exact."""
-    from mxnet_tpu.serving import ServingEngine
     params, cfg = _setup()
     rng = np.random.RandomState(3)
     p = rng.randint(1, cfg.vocab_size, 6).astype(np.int32)
     full = _ref(params, cfg, p, 12)[p.size:]
     eos = int(full[2])                     # stop after 3 tokens
-    eng = ServingEngine(params, cfg, num_slots=2, page_size=8,
-                        prefill_chunk=8, overlap=True)
+    eng = _engine(params, cfg, True, num_slots=2, page_size=8,
+                  prefill_chunk=8)
     rid = eng.submit(p, 12, eos_id=eos)
     eng.run()
     got = list(eng.requests[rid].generated)
@@ -227,18 +206,18 @@ def test_overlap_eos_invalidates_speculative_step_no_leak():
 
 @pytest.mark.slow
 def test_cluster_overlap_identity_and_cancel_race():
-    """Replicated cluster with overlap=True: mixed-length burst is
+    """Replicated cluster of pipelined engines: mixed-length burst is
     generate-identical, a cancel fired from another thread mid-flight
     retires cleanly, and the drain leaves zero refs/pages on every
     replica."""
-    import threading
     from mxnet_tpu.serving import ServingCluster
     params, cfg = _setup()
     rng = np.random.RandomState(5)
     prompts = _mixed(rng, cfg.vocab_size)
     maxnew = [6, 4, 8, 5, 7, 3]
-    cl = ServingCluster(params, cfg, replicas=2, num_slots=2,
-                        page_size=8, prefill_chunk=6, overlap=True)
+    with pools_seen_on("tpu"):
+        cl = ServingCluster(params, cfg, replicas=2, num_slots=2,
+                            page_size=8, prefill_chunk=6, kernel="xla")
     try:
         rids = [cl.submit(p, n) for p, n in zip(prompts, maxnew)]
         victim = rids[2]
@@ -275,20 +254,34 @@ def test_cluster_overlap_identity_and_cancel_race():
 
 @pytest.mark.slow
 def test_disagg_cluster_overlap_identity():
-    """Process-split cluster (1 prefill + 1 decode OS process) with
-    overlap=True threaded through the worker engine kwargs: outputs
-    stay generate-identical, the decode worker actually pipelines
-    (overlap_steps > 0 in its stats snapshot), and no worker leaks
-    pages, refs, or staged streams."""
+    """The disaggregated protocol (1 prefill + 1 decode worker) over
+    pipelined engines: outputs stay generate-identical, the decode
+    worker actually pipelines (every step, by its stats snapshot), and
+    no worker leaks pages, refs, or staged streams.  The workers are
+    threads of this process (``spawn=False``): a spawned worker builds
+    its engine in a process of its own, where this test's observation
+    cannot be substituted."""
+    import socket
     from mxnet_tpu.serving import DisaggServingCluster
+    from mxnet_tpu.serving.cluster import _DisaggWorker
     params, cfg = _setup()
     rng = np.random.RandomState(9)
     prompts = _mixed(rng, cfg.vocab_size, (5, 9, 17, 3, 12))
     nnew = [6, 4, 8, 5, 7]
-    cl = DisaggServingCluster(params, cfg, prefill=1, decode=1,
-                              num_slots=4, page_size=4,
-                              metrics=True, watchdog_s=60.0,
-                              overlap=True)
+    with socket.socket() as sock:
+        sock.bind(("127.0.0.1", 0))
+        port = sock.getsockname()[1]
+    workers = [threading.Thread(
+        target=lambda *a: _DisaggWorker(*a).run(), daemon=True,
+        args=(role + "0", role, "127.0.0.1", port))
+        for role in ("prefill", "decode")]
+    with pools_seen_on("tpu"):
+        for w in workers:
+            w.start()
+        cl = DisaggServingCluster(params, cfg, prefill=1, decode=1,
+                                  num_slots=4, page_size=4,
+                                  metrics=True, watchdog_s=60.0,
+                                  kernel="xla", spawn=False, port=port)
     try:
         rids = [cl.submit(p, n) for p, n in zip(prompts, nnew)]
         for rid, p, n in zip(rids, prompts, nnew):
@@ -304,6 +297,8 @@ def test_disagg_cluster_overlap_identity():
             assert ws["active_requests"] == 0, (name, ws)
     finally:
         cl.close()
+        for w in workers:
+            w.join(60)
 
 
 # --------------------------------------------------------- fast tier ---
@@ -332,8 +327,8 @@ def test_pipelined_tokens_are_the_serial_ones(small, scenario):
     layer wide: the schedule a TPU engine takes by itself gives the
     serial schedule's tokens through an eos stop, a preemption and a
     cancel, leaks no page, and did pipeline — every committed step was
-    dispatched pipelined (the cold start's too: it is built by the
-    same planner code, inline) and planning time was hidden."""
+    dispatched pipelined (the cold start's too), none of a depth-0
+    engine's."""
     params, cfg = small
     kw = {"chaos": scenario} if scenario in ("preempt", "cancel") else {}
     if scenario == "eos":
@@ -352,64 +347,124 @@ def test_pipelined_tokens_are_the_serial_ones(small, scenario):
         assert [state for state, _ in res.values()].count(
             "cancelled") == (scenario == "cancel")
     assert held_a == 0 and held_b == 0, (scenario, held_a, held_b)
-    assert sa["overlap_steps"] == 0 and sa["host_hidden_ms"] == 0.0
+    assert sa["steps"] > 0 and sa["overlap_steps"] == 0
     assert sb["steps"] > 0 and sb["overlap_steps"] == sb["steps"]
-    assert sb["overlap_fences"] == 0
-    assert sb["host_hidden_ms"] > 0.0
 
 
-# (pools on, spec_K, overlap=, MXNET_SERVE_OVERLAP) -> pipelined?
+# (pools on, spec_K) -> pipelined?
 _CHOICES = {
-    "cpu": ("cpu", 0, None, None, False),
-    "tpu": ("tpu", 0, None, None, True),
-    "tpu_speculating": ("tpu", 2, None, None, False),
-    "cpu_told_pipelined": ("cpu", 0, True, None, True),
-    "tpu_told_serial": ("tpu", 0, False, None, False),
-    "tpu_speculating_told_pipelined": ("tpu", 2, True, None, True),
-    "cpu_env_1": ("cpu", 0, None, "1", False),
-    "tpu_env_0": ("tpu", 0, None, "0", True),
-    "env_0_told_pipelined": ("cpu", 0, True, "0", True),
+    "cpu": ("cpu", 0, False),
+    "tpu": ("tpu", 0, True),
+    "tpu_speculating": ("tpu", 2, False),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_CHOICES))
 def test_engine_chooses_its_schedule(small, monkeypatch, case):
-    """``overlap=None`` is read from what the engine can observe: the
+    """The schedule is read from what the engine can observe: the
     platform its pools were placed on (``kernels/platform.platform_of``,
-    the test that chooses ``kernel``) and whether it speculates.  An
-    explicit ``overlap=`` wins either way, and ``MXNET_SERVE_OVERLAP``
-    no longer takes part.  Nothing compiles: no step runs."""
+    the test that chooses ``kernel``) and whether it speculates.
+    Nothing compiles: no step runs."""
     from mxnet_tpu.kernels import platform
     from mxnet_tpu.serving import ServingEngine
     params, cfg = small
-    plat, spec_K, overlap, env, pipelined = _CHOICES[case]
+    plat, spec_K, pipelined = _CHOICES[case]
     if plat != "cpu":
         monkeypatch.setattr(platform, "platform_of", lambda *a: plat)
-    if env is None:
-        monkeypatch.delenv("MXNET_SERVE_OVERLAP", raising=False)
-    else:
-        monkeypatch.setenv("MXNET_SERVE_OVERLAP", env)
     eng = ServingEngine(params, cfg, num_slots=2, page_size=4,
-                        prefill_chunk=4, spec_K=spec_K, overlap=overlap)
+                        prefill_chunk=4, spec_K=spec_K)
     assert platform.platform_of(eng.cache.pools) == plat
     assert eng.overlap is pipelined
     # the schedule and the kernel are read from the same observation
     assert eng.kernel == ("pallas" if plat == "tpu" else "xla")
-    assert eng._planner is None            # spawned by the first step
     eng.close()
-    eng.close()                            # idempotent
 
 
-_FORCE_PIPELINED = """
+@pytest.mark.parametrize("owner", ["engine", "cluster", "disagg"])
+def test_schedule_is_not_an_argument(small, owner):
+    """``overlap=`` went with the loop it selected (PR 30): the engine
+    and both clusters refuse it like any unknown argument, before
+    anything is built (no worker is spawned)."""
+    from mxnet_tpu import serving
+    params, cfg = small
+    cls = {"engine": serving.ServingEngine,
+           "cluster": serving.ServingCluster,
+           "disagg": serving.DisaggServingCluster}[owner]
+    for value in (True, False, None):
+        with pytest.raises(TypeError, match="overlap"):
+            cls(params, cfg, num_slots=2, page_size=4, prefill_chunk=4,
+                overlap=value)
+
+
+def test_engine_starts_no_thread(small):
+    """A pipelined run, from construction to the last commit, leaves
+    the process's threads as it found them (the planner thread went
+    with PR 30: the plan is built inside ``step()``), ``close()`` has
+    nothing to join, and calling it again is harmless."""
+    params, cfg = small
+    before = threading.enumerate()
+    eng = _engine(params, cfg, True, num_slots=2, page_size=4,
+                  prefill_chunk=4)
+    rng = np.random.RandomState(7)
+    for p, m in zip(_mixed(rng, cfg.vocab_size, (3, 9, 5)), (7, 4, 6)):
+        eng.submit(p, m)
+    while eng.step() is not False:
+        assert threading.enumerate() == before
+    assert eng.stats["steps"] > 0
+    assert eng.stats["overlap_steps"] == eng.stats["steps"]
+    eng.close()
+    eng.close()
+    assert threading.enumerate() == before
+    assert eng._inflight is None
+    assert eng.step() is False
+
+
+@pytest.mark.parametrize("overlap", [False, True],
+                         ids=["serial", "pipelined"])
+def test_submit_between_steps_rides_the_next_plan(small, overlap):
+    """A request submitted between two ``step()`` calls has its rows in
+    the plan the next call dispatches, every time, at either depth: the
+    plan is built inside the call, after whatever came before it (with
+    a planner thread the next plan was already being built: a race)."""
+    params, cfg = small
+    eng = _engine(params, cfg, overlap, num_slots=4, page_size=4,
+                  prefill_chunk=4)
+    plans = []
+    dispatch = eng._dispatch
+
+    def recording(plan):
+        plans.append(plan)
+        return dispatch(plan)
+    eng._dispatch = recording
+    rng = np.random.RandomState(3)
+    for n in (3, 4, 2, 4):
+        rid = eng.submit(rng.randint(1, cfg.vocab_size, n)
+                         .astype(np.int32), 12)
+        seen = len(plans)
+        assert eng.step() is not False
+        assert len(plans) == seen + 1
+        plan, req = plans[-1], eng.requests[rid]
+        assert rid in plan.admit and req.slot is not None
+        rows = (plan.buf.row_slot == req.slot) & plan.buf.row_live
+        assert rows.sum() == n             # its whole prompt, one chunk
+        assert eng.step() is not False     # and a step with no newcomer
+    eng.run()
+    eng.close()
+    assert eng.cache.pages_in_use == 0
+
+
+_ON_A_TPU = """
 import sys
-sys.path[:0] = [{root!r}, {bench!r}]
+sys.path[:0] = [{root!r}, {bench!r}, {tests!r}]
+from conftest import pools_seen_on
 from mxnet_tpu.serving import engine as E
 init = E.ServingEngine.__init__
 seen = []
-def forced(self, *a, **kw):
-    init(self, *a, **dict(kw, overlap=True))
+def on_a_tpu(self, *a, **kw):
+    with pools_seen_on("tpu"):
+        init(self, *a, **dict(kw, kernel="xla"))
     seen.append(self)
-E.ServingEngine.__init__ = forced
+E.ServingEngine.__init__ = on_a_tpu
 import run
 rc = run.main({argv!r})
 eng = seen[0]
@@ -427,13 +482,14 @@ def test_serving_cells_rehearse_pipelined(cell):
     benchmark file edited, so their harness has to be schedule-agnostic:
     it reads ``Request.generated`` after each ``step()`` and never the
     call's return value.  ``chipbench/run.py --rehearse`` with every
-    engine forced pipelined (here the CPU would choose serial) reaches
+    engine seen on a TPU (the CPU's own engines are serial) reaches
     ``correct`` against the reference, no request failed, every step of
     the run pipelined."""
     argv = ["--workload", cell, "--seed", str(2 ** 31 + 29),
             "--seconds", "1", "--trace", "0", "--rehearse"]
-    code = _FORCE_PIPELINED.format(
-        root=ROOT, bench=os.path.join(ROOT, "chipbench"), argv=argv)
+    code = _ON_A_TPU.format(
+        root=ROOT, bench=os.path.join(ROOT, "chipbench"),
+        tests=os.path.join(ROOT, "tests"), argv=argv)
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=600, cwd=ROOT,
                        env=dict(os.environ, JAX_PLATFORMS="cpu"))

@@ -30,9 +30,11 @@ Injection is scoped, not global: :func:`patch` swaps the ``threading``
 and ``time`` module objects *of* ``mxnet_tpu.serving.cluster`` AND
 ``mxnet_tpu.serving.engine`` for scheduler-aware shims, so jax /
 numpy internals keep their real primitives.  (The engine joined the
-sweep in round 21: its overlap mode runs a planner thread against
-the engine lock, so planner-vs-step-vs-cancel interleavings are now
-part of the subject — ``wl_overlap_plan``.)
+sweep in round 21: its lock ``_mu`` is what a pipelined ``step()``
+and the cluster's submit / cancel threads contend for, so
+step-vs-submit-vs-cancel interleavings at pipeline depth 1 are part
+of the subject — ``wl_overlap_plan``.  The engine itself starts no
+thread.)
 
 Strategies
 ----------
@@ -535,8 +537,8 @@ class _TimeShim:
 class patch:
     """Context manager: swap ``mxnet_tpu.serving.cluster``'s and
     ``mxnet_tpu.serving.engine``'s module references to ``threading``
-    / ``time`` for scheduler shims (the engine's overlap planner
-    thread is under sweep since round 21)."""
+    / ``time`` for scheduler shims (the engine's lock is under sweep
+    since round 21)."""
 
     def __init__(self, sched: Scheduler):
         self.sched = sched

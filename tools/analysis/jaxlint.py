@@ -11,10 +11,10 @@ Rules
     compiled-step callables (terminal name matching ``*step_fn``, a
     name bound from ``jax.jit(...)``, or a function defined under
     ``@jax.jit``) are tainted; round 21 adds two sources for the
-    overlap split — calls to ``*_dispatch`` (the dispatch helper
+    engine's dispatch / drain split — calls to ``*_dispatch`` (the helper
     returns the step program's output un-materialized) and the
     ``DEVICE_PARAMS`` registry (a hot-region function that RECEIVES a
-    step result as a parameter, like the overlap ``_drain``, declares
+    step result as a parameter, like the engine's ``_drain``, declares
     it there).  Taint propagates through subscripts, attributes,
     arithmetic, and tuple unpacking; a flagged materialization (e.g.
     ``x = np.asarray(x)``) clears it — the sync happened there,
@@ -65,13 +65,12 @@ HOT_REGIONS: List[Tuple[str, str]] = [
     # round 11: the speculation plan/draft path runs once per engine
     # step on the host — it must stay pure host work (no device syncs
     # beyond step()'s one pragma'd token read-back).
-    # round 21: the overlap split — plan build (planner thread AND
-    # inline cold path), dispatch, deferred drain/commit, and the
-    # planner kick all run once per step; a stray sync in any of them
-    # un-hides exactly the host latency the pipeline exists to hide
+    # round 21: the step's phases — plan build, dispatch, deferred
+    # drain/commit — all run once per step on the caller's thread; a
+    # stray sync in any of them un-hides exactly the host latency the
+    # pipelined loop exists to hide
     ("mxnet_tpu/serving/engine.py",
-     r"(?:.*\.)?(step|_step_serial|_step_overlap|_take_plan|_drain"
-     r"|_maybe_plan_ahead|_build_plan|_dispatch|_commit"
+     r"(?:.*\.)?(step|_drain|_build_plan|_dispatch|_commit"
      r"|_plan_speculation)$"),
     # round 10: the cluster router loop (per-replica worker + routing
     # + completion) and the prefix-cache match/insert/evict paths run
@@ -195,15 +194,15 @@ BENCH_MODULES: List[str] = [
 ]
 
 STEP_FN_RE = re.compile(r".*step_fn$")
-# round 21: the overlap split routes the raw step-program output
+# round 21: the engine's step routes the raw step-program output
 # through ``_dispatch`` (it stages inputs and returns the jitted call's
 # result WITHOUT materializing) — in hot regions a call to it is a
 # device result exactly like a *step_fn call.  Kept separate from
 # STEP_FN_RE so the bench linter's jit-call heuristic is unchanged.
 DEVICE_OUT_RE = re.compile(r".*(?:step_fn|_dispatch)$")
 # hot-region functions that RECEIVE a step-program result as a
-# parameter (the overlap ``_drain`` gets step N's sampled tokens while
-# step N+1 executes): (repo-relative glob, qualname regex, params) —
+# parameter (``_drain`` gets step N's sampled tokens, at pipeline
+# depth 1 while step N+1 executes): (repo-relative glob, qualname regex, params) —
 # the named parameters are seeded device-tainted before linting
 DEVICE_PARAMS: List[Tuple[str, str, Tuple[str, ...]]] = [
     ("mxnet_tpu/serving/engine.py", r"(?:.*\.)?_drain$", ("tok",)),
