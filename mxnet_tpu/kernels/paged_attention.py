@@ -26,8 +26,21 @@ copy hides behind the row before it — into one of two VMEM slots
 while group g is folded out of the other, F pages a turn; a whole
 page ``(ps, H, 2*dh)`` is one contiguous copy.  G, F and R follow
 from the shapes (``walk_geometry``): for the cell's bf16 pool of 16
-heads of 64, pages are 64 KiB, G = 8 (two 512 KiB slots), F = 2,
+heads of 64, pages are 64 KiB, G = 8 (two 512 KiB slots), F = G,
 R = 16.
+
+**The dense fold** (``_fold_dense``, PR 31) is what the walk folds a
+``(ps, H, 2*dh)`` pool with, a whole group a turn.  A page's rows,
+(token, head) by (k | v), go to the MXU as they lie in VMEM: the
+row's zero-extended query times their transpose is ONE tile of scores
+(H, tokens*H), live on its block diagonal; mask, max, exp and sums
+run once on that tile, and p, zero off the diagonal, is the left
+operand of the second product over the same rows.  The column fold
+(``_fold``: a lane reduction a token and head on the XLU, the scores
+one live lane in 128, both products on the VPU) was bound by the
+XLU's reductions; it stays for the per-page grid, whose pools it fits
+(int8 scale planes lie tokens-on-sublanes) and whose pages are too
+small a tile to pay for the MXU's latency.
 
 **Grouped-query pools** (PR 28).  A model with fewer key/value heads
 than query heads keeps FLAT pages, ``(ps, Hkv*2*dh)``: each key/value
@@ -75,7 +88,7 @@ tp∈{2,4} greedy token identity vs tp=1 and ``generate`` is pinned in
 ``tests/test_serving_tp.py`` and the mesh-vs-reference parity in
 ``tests/test_paged_attention.py``.
 
-Numerics (``_fold``): pages in the pool's dtype, scores and the
+Numerics (every fold): pages in the pool's dtype, scores and the
 running max / denominator / accumulator in f32, probabilities rounded
 to the compute dtype before the V sum, ONE normalization at the end
 (acc / l) where the jnp reference normalizes the probabilities before
@@ -83,7 +96,10 @@ the V dot; the page-sequential accumulation orders the L-length
 reductions differently from one batched dot — both are 1–2 ulp
 effects in f32 (measured max |diff| ~2e-7 on randn inputs; same
 caveat class as the paged-vs-contiguous reduction-order note in
-``tests/test_serving.py``).  ``tests/test_paged_attention.py`` pins
+``tests/test_serving.py``).  The dense fold's products are the MXU's:
+exact for 16-bit pages (f32 accumulation, as the VPU's), six bf16
+passes (``Precision.HIGHEST``) for a 32-bit pool, 2–4e-7 of the
+reference on the chip.  ``tests/test_paged_attention.py`` pins
 both feeders against the ``_attend_rows`` reference at a few-ulp
 tolerance across page- and group-boundary cases in interpreter mode,
 and the serving tests pin full greedy TOKEN-identity of the pallas
@@ -92,11 +108,16 @@ actually guarantees.
 
 On the chip: ``tests/test_kernels_mosaic.py`` compiles every pool
 kind for v5e without one; ``chip_smoke.py`` pins both feeders against
-``paged_attention_reference`` there.  Measured (PERF.md, PR 27): in
-``bert_large_decoder.decode_heavy`` the walk is 8 ms of device time a
-step where the gather path was 68 and the per-page grid 43; it is
-bound by the fold's vector arithmetic (one query row per head leaves
-the MXU nothing to do), not by the page copies.
+``paged_attention_reference`` there.  Measured (PERF.md, PR 27 and
+31): in ``bert_large_decoder.decode_heavy`` the gather path was 68 ms
+of device time a step, the per-page grid 43, the walk under the column
+fold 7.3 (bound by the XLU: 64 lane reductions a turn of two pages),
+under the dense fold 5.3: rows 31 pages deep run at 72% of the HBM
+peak, and what binds the cell's ragged rows is the latency of the one
+group of copies in flight and the length of one group's chain through
+the MXU, neither the bytes nor the arithmetic.  A tree of rolls and
+selects in place of the lane reductions was built first and lost
+(x0.42): every roll is an XLU operation too.
 """
 from __future__ import annotations
 
@@ -118,9 +139,11 @@ def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False):
     """``(G, F, R)`` of the walk for one pool geometry — ``G`` pages
     are copied per DMA group (as many whole pages as ``_GROUP_BYTES``
     holds, at least one, at most a row's table), ``F`` of them are
-    folded per turn of the inner loop (two where G is even: a turn's
-    fixed cost is paid half as often, and a row's last turn folds at
-    most one page it did not need), ``R`` rows are walked per grid
+    folded per turn of the inner loop (the whole group under the dense
+    fold, whose fixed cost, the MXU's latency twice over, is then paid
+    once a group; two a turn under the flat fold where G is even: a
+    row's last turn folds at most one page it did not need), ``R``
+    rows are walked per grid
     step — or ``None`` where Mosaic cannot cut whole pages out of the
     pool and the per-page grid serves instead: a ``memref_slice`` of
     an HBM ref must be whole tiles in its two minor dims even where it
@@ -152,7 +175,7 @@ def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False):
         return None
     page_bytes = page_size * H * 2 * dh * kv_dtype.itemsize
     G = max(1, min(PP, _GROUP_BYTES // page_bytes))
-    return G, 2 - G % 2, _ROWS
+    return G, (2 - G % 2 if flat else G), _ROWS
 
 
 def _scale_folds(dh):
@@ -183,9 +206,10 @@ def _fold(kv, sc, q, m, l, acc, k0, pos, dh, cdt):
     consecutive pages, ``ps`` then their tokens together).  Returns
     the three updated.
 
-    One query row per head: both contractions are multiply-and-reduce
-    on the VPU (Mosaic has no matmul form for a batch dim that is not
-    leading, and one query row would leave the MXU idle anyway).
+    The per-page grid's fold (the walk's is ``_fold_dense``).  One
+    query row per head: both contractions are multiply-and-reduce on
+    the VPU (Mosaic has no matmul form for a batch dim that is not
+    leading).
     Products of cdt (or int8) values are exact in f32, so this matches
     an MXU dot with f32 accumulation up to summation order.  Scores
     keep heads on the sublanes — (ps, H, 1), the layout the lane
@@ -215,6 +239,54 @@ def _fold(kv, sc, q, m, l, acc, k0, pos, dh, cdt):
     p = p.astype(cdt).astype(f32)
     acc = acc * alpha + jnp.sum(p * kv, axis=0)         # (H, 2*dh)
     return m_new, l, acc
+
+
+def _fold_dense(kv, q, m, l, acc, k0, pos, dh, cdt):
+    """``_fold`` for the walk, both contractions on the MXU and the
+    scores in ONE tile: ``kv`` (n, H, 2*dh) a group's pages as the pool
+    holds them, ``q`` (H, 2*dh) the row's ``_scaled`` query in the
+    pool's dtype, zero-extended over the v half of the lanes; ``m`` /
+    ``l`` (H, 1) and ``acc`` (H, 2*dh) as ``_fold`` carries them.
+
+    The pages' rows, (token, head) by (k | v), are the MXU's weights
+    as they lie in VMEM: ``q @ rows^T`` is a tile (H, n*H) whose
+    column t*H + h' holds query head h against token t's key head h',
+    and the scores are its block diagonal h' == h, heads on the
+    sublanes, tokens along the lanes.  Mask (the diagonal and the
+    position at once), max, exp, sums and the round of p run once on
+    that tile; p is zero off the diagonal, which makes it the
+    block-diagonal left operand of the second product: ``p @ rows`` is
+    sum_t p[t, h] * kv[t, h, :], the v sum (and p.k on the k half,
+    finite and never read).  H times the products the scores need,
+    on a unit that one query row per head leaves idle otherwise; no
+    lane reduction a token, no page cast to f32 on the VPU.
+
+    Products of cdt values are exact in f32 and the MXU accumulates
+    in f32, so this matches ``_fold`` up to the order of the sums; a
+    32-bit pool multiplies at the highest precision."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    n, H, W = kv.shape
+    rows = kv.reshape(n * H, W)
+    prec = jax.lax.Precision.HIGHEST if kv.dtype.itemsize == 4 else None
+    s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
+                            preferred_element_type=f32, precision=prec)
+    if not _scale_folds(dh):
+        s = s / jnp.sqrt(f32(dh))
+    head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    # column t*H + h': exact in f32 for any H (no vector integer divide)
+    tok = ((col.astype(f32) + 0.5) * (1.0 / H)).astype(jnp.int32)
+    live = (col - tok * H == head) & (k0 + tok <= pos)
+    s = jnp.where(live, s, -1e30)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))   # (H, 1)
+    p = jnp.exp(s - m_new)                                      # (H, n*H)
+    alpha = jnp.exp(m - m_new)
+    l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+    pv = jax.lax.dot_general(p.astype(cdt), rows, (((1,), (0,)), ((), ())),
+                             preferred_element_type=f32, precision=prec)
+    return m_new, l, acc * alpha + pv
 
 
 def _fold_flat(kv, q, m, l, acc, k0, pos, dh, cdt):
@@ -259,8 +331,9 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
     """Grid over blocks of R rows; the pool stays in HBM.  Per row a
     loop over groups of G pages, bounded by the row's own position:
     group g+1 (or the next row's first group) is copied into one VMEM
-    slot while group g is folded, F pages a turn, out of the other.
-    ``flat`` pools (grouped-query) fold with ``_fold_flat``."""
+    slot while group g is folded, F pages a turn, out of the other:
+    ``_fold_dense`` over the whole group at once (F = G), ``flat``
+    pools (grouped-query) two pages a turn with ``_fold_flat``."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -301,6 +374,9 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
         last = last_page(t)
         n_groups = last // G + 1
         q = _scaled(q_ref[r], dh)                  # (H, 2*dh)
+        if not flat:
+            # the MXU's operand: a power of two keeps q exact
+            q = q.astype(buf.dtype)
 
         def group(g, carry):
             m, l, acc, slot = carry
@@ -313,8 +389,8 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
                 start(t_nxt, g_nxt, 1 - slot)
 
             def turn(c, carry):
-                # F pages a turn; one past the row's last was not
-                # copied and holds an older page (or the zeros below):
+                # F pages a turn; those past the row's last were not
+                # copied and hold older pages (or the zeros below):
                 # finite, and masked by position like any tail
                 for f in range(F):
                     @pl.when(g * G + c * F + f <= last)
@@ -323,17 +399,17 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
                         # by its source
                         copy(0, slot, c * F + f).wait()
                 kv = buf[slot, pl.ds(c * F, F)]
-                if flat:
-                    return _fold_flat(kv.reshape(F * ps, kv.shape[-1]),
-                                      q, *carry, (g * G + c * F) * ps,
-                                      pos, dh, q_ref.dtype)
-                return _fold(kv.reshape(F * ps, H, 2 * dh), None, q,
-                             *carry, (g * G + c * F) * ps, pos, dh,
-                             q_ref.dtype)
+                fold = _fold_flat if flat else _fold_dense
+                return fold(kv.reshape((F * ps,) + kv.shape[2:]), q,
+                            *carry, (g * G + c * F) * ps, pos, dh,
+                            q_ref.dtype)
 
-            n_pages = jnp.minimum(G, last + 1 - g * G)
-            m, l, acc = jax.lax.fori_loop(0, (n_pages + F - 1) // F,
-                                          turn, (m, l, acc))
+            if F == G:
+                m, l, acc = turn(0, (m, l, acc))
+            else:
+                n_pages = jnp.minimum(G, last + 1 - g * G)
+                m, l, acc = jax.lax.fori_loop(0, (n_pages + F - 1) // F,
+                                              turn, (m, l, acc))
             return m, l, acc, 1 - slot
 
         if flat:

@@ -890,7 +890,9 @@ class ServingEngine:
         kernel module docstring); greedy token-identity vs
         ``generate`` is pinned for both by ``tests/test_serving``.
         ``stats["kv_pages_read"]`` over ``stats["kv_pages_window"]``
-        says how much of the attention window a step read.
+        says how much of the attention window a step read, and over
+        ``stats["kv_pages_folded"]`` how much of what the walk folded
+        (whole turns of pages) was live.
     spec_K : in-engine speculative decode — each running decode slot
         drafts K tokens per step, the step program verifies all rows'
         drafts in ONE batched forward over the paged cache, accepted
@@ -1111,12 +1113,15 @@ class ServingEngine:
         self.kernel = kernel
         # whether attention walks each row's own pages (the Pallas
         # walk, on a pool it can cut pages out of) or reads the whole
-        # (rows x pages_per_slot) window: what kv_pages_read books
+        # (rows x pages_per_slot) window: what kv_pages_read books.
+        # The walk folds whole turns of F pages: what kv_pages_folded
+        # books (0: no walk)
         from ..kernels.paged_attention import walk_geometry
         kv_heads, head_dim, flat_kv = kv_geometry(cfg)
-        self._walks_pages = kernel == "pallas" and walk_geometry(
+        geometry = kernel == "pallas" and walk_geometry(
             kv_heads // tp, head_dim, page_size, pages_per_slot,
-            self.cache.pools[0]["kv"].dtype, flat=flat_kv) is not None
+            self.cache.pools[0]["kv"].dtype, flat=flat_kv)
+        self._walk_turn = geometry[1] if geometry else 0
         # host-DRAM KV tier (round 18): explicit argument >
         # MXNET_SERVE_TIER_BYTES env > off.  0/None disables — every
         # pre-tier behavior (drop on pressure, recompute on resume)
@@ -1166,7 +1171,8 @@ class ServingEngine:
                       "spec_drafted": 0, "spec_accepted": 0,
                       "swap_outs": 0, "swap_ins": 0,
                       "slot_occupancy_sum": 0.0, "overlap_steps": 0,
-                      "kv_pages_window": 0, "kv_pages_read": 0}
+                      "kv_pages_window": 0, "kv_pages_read": 0,
+                      "kv_pages_folded": 0}
         if self._stateful:
             # slot-states read and written (the live slots of each
             # dispatched step), those started from zero, and the bytes
@@ -1996,9 +2002,15 @@ class ServingEngine:
             # window is every row's whole table; the walk reads up to
             # each row's own position (a dead row its scratch page)
             window = T * self.pages_per_slot
-            plan.kv_pages = int(
-                (row_pos // self.page_size + 1).sum()) \
-                if self._walks_pages else window
+            plan.kv_pages = window
+            if self._walk_turn:
+                # a row's live pages, and those rounded up to the
+                # whole turns the walk's two contractions run over
+                live = row_pos // self.page_size + 1
+                F = self._walk_turn
+                plan.kv_pages = int(live.sum())
+                self.stats["kv_pages_folded"] += int(
+                    ((live + F - 1) // F * F).sum())
             self.stats["kv_pages_window"] += window
             self.stats["kv_pages_read"] += plan.kv_pages
             if self._stateful:

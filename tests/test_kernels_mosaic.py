@@ -67,20 +67,32 @@ def _paged_shapes(T, H, dh, ps, PP, NP, kv_dtype, q_dtype):
      "bfloat16"),
     # a short last row block, and a row table that is one odd group
     (dict(T=37, H=8, dh=64, ps=16, PP=7, NP=353), "float32", "float32"),
+    # the cell's H/tp slice at tp=2: 8 heads, half a packed tile a token
+    (dict(T=160, H=8, dh=64, ps=16, PP=32, NP=3073), "bfloat16",
+     "bfloat16"),
 ], ids=["full-bf16", "full-int8", "full-f32", "tp2-bf16", "tp4-int8",
-        "h16-bf16", "h32-int8", "cell-bf16", "odd-f32"])
+        "h16-bf16", "h32-int8", "cell-bf16", "odd-f32", "cell-tp2-bf16"])
 def test_paged_attention_compiles(v5e, geom, kv_dtype, q_dtype):
-    from mxnet_tpu.kernels.paged_attention import (paged_attention,
+    from mxnet_tpu.kernels.paged_attention import (_GROUP_BYTES,
+                                                   paged_attention,
                                                    walk_geometry)
     q, kv, sc, bt, pos = _paged_shapes(kv_dtype=kv_dtype,
                                        q_dtype=q_dtype, **geom)
     ps = geom["ps"]
     # the walk where Mosaic can cut whole pages out of the pool, the
     # per-page grid elsewhere: both must compile
-    walks = walk_geometry(geom["H"], geom["dh"], ps, geom["PP"],
-                          kv_dtype) is not None
+    geometry = walk_geometry(geom["H"], geom["dh"], ps, geom["PP"],
+                             kv_dtype)
+    walks = geometry is not None
     assert walks == (kv_dtype == "float32" or
                      (kv_dtype == "bfloat16" and geom["H"] % 8 == 0))
+    if walks:
+        # the dense fold: a whole group of pages is one turn, its
+        # scores one tile (the per-page grid keeps the column fold)
+        G, F, _ = geometry
+        assert F == G == min(geom["PP"], _GROUP_BYTES // (
+            ps * geom["H"] * 2 * geom["dh"]
+            * jnp.dtype(kv_dtype).itemsize))
     if sc is None:
         _compile(lambda q, kv, bt, pos: paged_attention(
             q, kv, None, bt, pos, page_size=ps),
@@ -108,8 +120,13 @@ def test_grouped_paged_attention_compiles(v5e, geom, dtype, walks):
     from mxnet_tpu.kernels.paged_attention import (paged_attention,
                                                    walk_geometry)
     g = geom
-    assert (walk_geometry(g["Hkv"], g["dh"], g["ps"], g["PP"], dtype,
-                          flat=True) is not None) == walks
+    geometry = walk_geometry(g["Hkv"], g["dh"], g["ps"], g["PP"], dtype,
+                             flat=True)
+    assert (geometry is not None) == walks
+    if walks:
+        # the flat fold keeps its turns of two pages (one where the
+        # group is an odd count)
+        assert geometry[1] == 2 - geometry[0] % 2
     _compile(lambda q, kv, bt, pos: paged_attention(
         q, kv, None, bt, pos, page_size=g["ps"]), v5e[0],
         _sds((g["T"], g["Hq"], g["dh"]), dtype),

@@ -174,8 +174,8 @@ def test_kernel_mesh_tp_parity():
 
 def _walk_case(name):
     """(kwargs of ``_mk``, pages a group or None, table rows to alter
-    or None, the positions as a function of the walk's G) for one
-    named case.  Tiny pages in groups of two put several group
+    or None, the positions as a function of the walk's G and F) for
+    one named case.  Tiny pages in groups of two put several group
     boundaries inside a five-page table; ``None`` keeps the module's
     own group size."""
     two_pages = 2
@@ -184,54 +184,76 @@ def _walk_case(name):
         # contexts of exactly G*ps and G*ps + 1 tokens, then 2G*ps and
         # 2G*ps + 1: a row ends ON a group's last slot / starts a group
         "group_boundary": (small, two_pages, None,
-                           lambda G: [G * 4 - 1, G * 4, 2 * G * 4 - 1,
+                           lambda G, F: [G * 4 - 1, G * 4, 2 * G * 4 - 1,
                                       2 * G * 4, 4 * G + 1, 19]),
         # the module's own group size (8 pages of 64 KiB), 20-page rows
         "group_boundary_real_pages": (
             dict(T=4, H=8, dh=64, ps=16, PP=20, NP=47), None, None,
-            lambda G: [G * 16 - 1, G * 16, 2 * G * 16, 319]),
+            lambda G, F: [G * 16 - 1, G * 16, 2 * G * 16, 319]),
         # a request's first position, and dead rows: position 0 on the
         # all-zero table row (what the engine points dead rows at)
         "pos0_and_dead_rows": (small, two_pages,
                                {1: 0, 3: 0, 5: 0},
-                               lambda G: [0, 0, 9, 0, 1, 0]),
+                               lambda G, F: [0, 0, 9, 0, 1, 0]),
         # one prefill chunk: consecutive positions of ONE slot's pages
         "prefill_chunk_shares_pages": (small, two_pages,
                                        {1: "row0", 2: "row0", 3: "row0",
                                         4: "row0"},
-                                       lambda G: [6, 7, 8, 9, 10, 3]),
+                                       lambda G, F: [6, 7, 8, 9, 10, 3]),
         # ragged rows over tables whose tails point at the scratch page
         "ragged_scratch_tails": (small, two_pages,
                                  {0: "tail1", 2: "tail2", 4: "tail3"},
-                                 lambda G: [3, 17, 7, 12, 9, 19]),
+                                 lambda G, F: [3, 17, 7, 12, 9, 19]),
         # more rows than one grid step walks, the last block short
         "short_last_row_block": (dict(small, T=19), two_pages, None,
-                                 lambda G: list(range(0, 19))),
+                                 lambda G, F: list(range(0, 19))),
+        # the edges of the fold's own tile of scores, one turn of F
+        # pages wide (the whole group under the dense fold): a row
+        # that ends on the tile's last token and one that starts the
+        # next tile (a turn with ONE live token), first group and past
+        "turn_boundary": (dict(small, PP=6), two_pages, None,
+                          lambda G, F: [F * 4 - 1, F * 4, 2 * F * 4 - 1,
+                                        2 * F * 4, G * 4 + F * 4 - 1,
+                                        G * 4 + F * 4]),
+        # groups of four pages: rows whose last group holds one live
+        # token, one live page or all but one leave the rest of the
+        # folded tile dead (pages never copied, or an earlier row's)
+        "dead_group_tail": (dict(small, PP=8, NP=37), 4, None,
+                            lambda G, F: [0, 3, G * 4, G * 4 + 3,
+                                          2 * G * 4 - 5, 2 * G * 4 - 1]),
+        # the benchmark cell's page (16 heads of 64, 16 tokens, bf16)
+        # at the module's own group size, rows ending on every edge
+        "cell_page_bf16": (
+            dict(T=8, H=16, dh=64, ps=16, PP=20, NP=47,
+                 dtype="bfloat16"), None, None,
+            lambda G, F: [0, F * 16 - 1, F * 16, G * 16 - 1, G * 16,
+                          G * 16 + F * 16, 2 * G * 16 + 1, 319]),
         "f32_h6": (dict(small, H=6), two_pages, None,
-                   lambda G: [0, 5, 8, 9, 16, 19]),
+                   lambda G, F: [0, 5, 8, 9, 16, 19]),
         "f32_h3": (dict(small, H=3), two_pages, None,
-                   lambda G: [0, 5, 8, 9, 16, 19]),
+                   lambda G, F: [0, 5, 8, 9, 16, 19]),
         "bf16_h8": (dict(small, H=8, dh=16, dtype="bfloat16"),
                     two_pages, None,
-                    lambda G: [0, 5, 8, 9, 16, 19]),
+                    lambda G, F: [0, 5, 8, 9, 16, 19]),
         # what walk_geometry turns away runs the per-page grid
         "bf16_h6_per_page": (dict(small, H=6, dh=16, dtype="bfloat16"),
                              None, None,
-                             lambda G: [0, 5, 8, 9, 16, 19]),
+                             lambda G, F: [0, 5, 8, 9, 16, 19]),
         "bf16_h3_per_page": (dict(small, H=3, dh=16, dtype="bfloat16"),
                              None, None,
-                             lambda G: [0, 5, 8, 9, 16, 19]),
+                             lambda G, F: [0, 5, 8, 9, 16, 19]),
         "int8_per_page": (dict(small, int8=True), None, None,
-                          lambda G: [0, 5, 8, 9, 16, 19]),
+                          lambda G, F: [0, 5, 8, 9, 16, 19]),
         "int8_h6_per_page": (dict(small, H=6, int8=True), None, None,
-                             lambda G: [0, 5, 8, 9, 16, 19]),
+                             lambda G, F: [0, 5, 8, 9, 16, 19]),
     }[name]
 
 
 @pytest.mark.parametrize("name", [
     "group_boundary", "group_boundary_real_pages", "pos0_and_dead_rows",
     "prefill_chunk_shares_pages", "ragged_scratch_tails",
-    "short_last_row_block", "f32_h6", "f32_h3", "bf16_h8",
+    "short_last_row_block", "turn_boundary", "dead_group_tail",
+    "cell_page_bf16", "f32_h6", "f32_h3", "bf16_h8",
     "bf16_h6_per_page", "bf16_h3_per_page", "int8_per_page",
     "int8_h6_per_page"])
 def test_walk_cases(name, monkeypatch):
@@ -252,7 +274,7 @@ def test_walk_cases(name, monkeypatch):
     monkeypatch.setattr(PA, "_call_cache", {})
     geometry = PA.walk_geometry(mk["H"], mk["dh"], ps, PP, pool.dtype)
     assert (geometry is None) == name.endswith("_per_page")
-    G = geometry[0] if geometry else 1
+    G, F = geometry[:2] if geometry else (1, 1)
     if group_pages is not None:
         assert G == group_pages  # several groups inside the table
     elif geometry:
@@ -265,7 +287,7 @@ def test_walk_cases(name, monkeypatch):
             bt[r] = bt[0]
         else:
             bt[r, int(how[4:]):] = 0
-    pos = positions(G)
+    pos = positions(G, F)
     assert len(pos) == mk["T"] and max(pos) < PP * ps
     out, ref = _both(q, pool, scale, jnp.asarray(bt), pos, ps)
     tol = dict(rtol=2e-2, atol=2e-2) if mk.get("dtype") == "bfloat16" \
@@ -273,17 +295,17 @@ def test_walk_cases(name, monkeypatch):
     np.testing.assert_allclose(out, ref, **tol)
 
 
-@pytest.mark.parametrize("PP,F", [(5, 1), (6, 2)])
+@pytest.mark.parametrize("PP,F", [(5, 5), (6, 6), (9, 9)])
 def test_walk_reads_no_page_past_the_position(PP, F):
     """Pages past a row's position are never copied: with NaN in every
     page a row must not see, the walk's output is finite and unchanged
     (the gather reference, which reads the whole window, is the one
-    that would carry them through a 0 x NaN) — folding one page or two
-    a turn, where a row's last turn may fold a page it did not copy."""
+    that would carry them through a 0 x NaN) — though the dense fold
+    runs over the whole group, pages it did not copy among them."""
     import jax.numpy as jnp
     from mxnet_tpu.kernels import paged_attention as PA
     ps = 4
-    q, pool, scale, bt = _mk(T=4, ps=ps, PP=PP, NP=27)
+    q, pool, scale, bt = _mk(T=4, ps=ps, PP=PP, NP=1 + 4 * PP)
     assert PA.walk_geometry(2, 8, ps, PP, pool.dtype)[:2] == (PP, F)
     bt = np.arange(1, 1 + 4 * PP, dtype=np.int32).reshape(4, PP)
     pos = np.asarray([0, 3, 4, 13], np.int32)

@@ -250,26 +250,96 @@ def test_kv_pages_counters(kernel, kv_int8):
         assert eng.stats["kv_pages_read"] == window
 
 
+@pytest.mark.parametrize("kernel,page_size,kv_int8", [
+    ("pallas", 4, False), ("pallas", 64, False), ("xla", 4, False),
+    ("pallas", 4, True)])
+def test_kv_pages_folded_counter(kernel, page_size, kv_int8):
+    """``kv_pages_folded`` books, per dispatched step, each row's live
+    pages rounded up to the whole turns of F pages that the walk's two
+    contractions run over (F from ``walk_geometry``: the whole group
+    under the dense fold): at least ``kv_pages_read``, equal to it
+    where every row ends on a fold boundary (one page a slot: F = 1),
+    and nothing where no walk runs — the gather path, the per-page
+    grid on an int8 pool."""
+    import jax
+    from mxnet_tpu.kernels.paged_attention import walk_geometry
+    from mxnet_tpu.models import transformer as T
+    from mxnet_tpu.serving import ServingEngine
+
+    cfg = _cfg()
+    params = T.init_params(jax.random.PRNGKey(3), cfg)
+    eng = ServingEngine(params, cfg, num_slots=3, page_size=page_size,
+                        prefill_chunk=6, kernel=kernel, kv_int8=kv_int8)
+    walks = kernel == "pallas" and not kv_int8
+    F = walk_geometry(cfg.n_heads, cfg.d_model // cfg.n_heads, page_size,
+                      eng.pages_per_slot, "float32")[1]
+    assert F == eng.pages_per_slot == 64 // page_size
+    folded, seen = [], []
+    dispatch = eng._dispatch
+
+    def counting(plan):
+        live = plan.buf.row_pos // page_size + 1
+        folded.append(int(((live + F - 1) // F * F).sum()))
+        seen.append(eng.stats["kv_pages_folded"])
+        return dispatch(plan)
+    eng._dispatch = counting
+    rng = np.random.RandomState(0)
+    for P, N in [(5, 8), (3, 12), (9, 4), (2, 6)]:
+        eng.submit(rng.randint(1, 90, P).astype(np.int32), N)
+    eng.run()
+    assert len(folded) > 8
+    if not walks:
+        assert eng.stats["kv_pages_folded"] == 0
+        return
+    # booked step by step, with the plan that is dispatched
+    assert seen == list(np.cumsum(folded))
+    assert eng.stats["kv_pages_folded"] == sum(folded)
+    if F == 1:
+        assert eng.stats["kv_pages_folded"] == eng.stats["kv_pages_read"]
+    else:
+        assert eng.stats["kv_pages_folded"] > eng.stats["kv_pages_read"]
+
+
+def _counter_reader(metric):
+    """``read(counters)`` of the benchmark's reader file of a
+    ``program_counter`` metric, ``chipbench/layer_metrics/<metric>.py``."""
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "layer_metrics",
+        metric + ".py")
+    spec = importlib.util.spec_from_file_location("reader", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    return lambda counters: reader.read({}, {}, counters, None)
+
+
 def test_kv_page_read_share_reader():
     """The benchmark's reader of the two counters
     (``chipbench/layer_metrics/kv_page_read_share.serve.py``): their
     ratio in percent; nothing, without raising, from a program that
     books no such counters (the parent commit's ``stats``) or from a
     window in which no step was dispatched."""
-    import importlib.util
-    import os
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "chipbench", "layer_metrics",
-        "kv_page_read_share.serve.py")
-    spec = importlib.util.spec_from_file_location("kv_share", path)
-    reader = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(reader)
-    read = lambda counters: reader.read({}, {}, counters, None)  # noqa: E731
+    read = _counter_reader("kv_page_read_share.serve")
     assert read({"steps": 400, "dead_rows": 17000}) is None
     assert read({"kv_pages_window": 0, "kv_pages_read": 0}) is None
     assert read({"kv_pages_window": 5120, "kv_pages_read": 5120}) == 100.0
     assert read({"kv_pages_window": 5120, "kv_pages_read": 1385}) == \
         pytest.approx(27.05, abs=0.01)
+
+
+def test_kv_fold_live_share_reader():
+    """The reader of ``kv_fold_live_share.serve``: ``kv_pages_read``
+    over ``kv_pages_folded`` in percent; nothing, without raising,
+    from a program that books no such counter (the parent commit) or
+    from a window in which no step walked (the gather path books 0)."""
+    read = _counter_reader("kv_fold_live_share.serve")
+    assert read({"kv_pages_window": 5120, "kv_pages_read": 1385}) is None
+    assert read({"kv_pages_window": 5120, "kv_pages_read": 5120,
+                 "kv_pages_folded": 0}) is None
+    assert read({"kv_pages_read": 1380, "kv_pages_folded": 1380}) == 100.0
+    assert read({"kv_pages_read": 1380, "kv_pages_folded": 1460}) == \
+        pytest.approx(94.52, abs=0.01)
 
 
 @pytest.mark.slow
