@@ -79,6 +79,11 @@ CHIP = dict(
     # several page groups a row, the last row block short
     paged=[dict(T=32, H=12, dh=64, ps=16, PP=22, NP=353),
            dict(T=40, H=16, dh=64, ps=16, PP=32, NP=353)],
+    # a latent (MLA) pool as the gigachat3_702b_l5_ep16 cell has it: 64
+    # query heads against one 512 + 64 row a token (padded to 640
+    # lanes), rows several groups of 24 pages deep, a short last block
+    latent=dict(T=21, H=64, rank=512, rope=64, ps=16, PP=60, NP=353,
+                scale=0.14468),
     fsdp=dict(factory="bert_base", seq=512, batch=8),
     cluster_requests=16,
 )
@@ -93,6 +98,8 @@ REHEARSE = dict(
               width=dict(d_model=128, n_heads=2)),
     paged=[dict(T=6, H=4, dh=64, ps=8, PP=4, NP=17),
            dict(T=19, H=8, dh=64, ps=8, PP=5, NP=17)],
+    latent=dict(T=11, H=4, rank=64, rope=16, ps=16, PP=20, NP=53,
+                scale=0.3),
     fsdp=dict(factory="bert_tiny", seq=64, batch=8),
     cluster_requests=8,
 )
@@ -507,6 +514,48 @@ def _kernel_paged(ctx):
                      "%.0e)" % (what, err, _PAGED_TOL[kv_dtype]))
 
 
+def _kernel_paged_latent(ctx):
+    """(b') the walk's latent fold vs paged_attention_reference."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels.paged_attention import (
+        paged_attention, paged_attention_reference, walk_geometry)
+    from mxnet_tpu.serving.paged_kv import latent_width
+
+    g = ctx.sz["latent"]
+    T_, H, ps, PP, NP = (g[k] for k in ("T", "H", "ps", "PP", "NP"))
+    latent = g["rank"], g["rope"]
+    W = latent_width(*latent)
+    kw = dict(page_size=ps, latent=latent, scale=g["scale"])
+    rng = np.random.RandomState(0)
+    bt = jnp.asarray(rng.randint(1, NP, (T_, PP)), jnp.int32)
+    for dtype in ("float32", "bfloat16"):
+        G = walk_geometry(1, W // 2, ps, PP, dtype, flat=True,
+                          latent=True)[0]
+        # a dead row, a full table, and the two sides of a group's edge
+        pos = jnp.asarray(rng.randint(0, PP * ps, (T_,)), jnp.int32) \
+            .at[:4].set(jnp.asarray([0, PP * ps - 1, G * ps - 1, G * ps]))
+        rows = np.zeros((NP, ps, W), np.float32)
+        rows[..., :sum(latent)] = rng.randn(NP, ps, sum(latent))
+        kv = jnp.asarray(rows, dtype)
+        q = jnp.asarray(rng.randn(T_, H, sum(latent)), dtype)
+        compiled = jax.jit(lambda *a: paged_attention(*a, **kw)) \
+            .lower(q, kv, None, bt, pos).compile()
+        what = "paged_attention latent %d heads %s" % (H, dtype)
+        ctx.assert_compiled(what + " pool", compiled.as_text())
+        got = np.asarray(compiled(q, kv, None, bt, pos))
+        want = np.asarray(jax.jit(
+            lambda *a: paged_attention_reference(*a, **kw))(
+                q, kv, None, bt, pos))
+        err = float(np.abs(got - want).max() / np.abs(want).max())
+        if not np.isfinite(got).all() or err > _PAGED_TOL[dtype]:
+            raise AssertionError(
+                "%s: max|kernel-ref|/max|ref| = %.3g > %.0e"
+                % (what, err, _PAGED_TOL[dtype]))
+        ctx.note("%s: max|kernel-ref|/max|ref| = %.2e (tolerance %.0e)"
+                 % (what, err, _PAGED_TOL[dtype]))
+
+
 def _kernel_fused_sgd(ctx):
     """(c) nd.multi_sgd_mom_update vs the per-tensor loop, bit for bit."""
     import jax
@@ -565,6 +614,7 @@ def _kernel_fused_sgd(ctx):
 def leg_kernels(ctx):
     _kernel_flash_train(ctx)
     _kernel_paged(ctx)
+    _kernel_paged_latent(ctx)
     _kernel_fused_sgd(ctx)
 
 

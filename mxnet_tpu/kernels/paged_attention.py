@@ -53,6 +53,17 @@ recurrence with the roles of the axes turned: a head's scores are a
 column over the page's tokens, its k and v whole lane tiles read by
 each of the ``Hq / Hkv`` query heads that share them.
 
+**Latent pools** (PR 32).  Multi-head latent attention caches ONE row a
+token, ``[c_kv (rank) | rotated k_pe (rope)]``, shared by every query
+head (``latent=(rank, rope)``; pages ``(ps, W)``, the row padded with
+zero lanes to ``W`` whole tiles, 512 + 64 -> 640).  ``_fold_latent``
+is the shape the MXU wants: a row's absorbed queries (H, W) against a
+group's rows (n, W) are the scores (H, n) in one product, and ``p``
+against the same rows' first ``rank`` lanes is the read-back (H, rank):
+the page is read once, as the key and again as the value.  The softmax
+scale is the model's (``scale=``) and multiplies the float32 scores;
+there is no head size to take a root of.  Only the walk folds it.
+
 **Which pool takes which feeder** (``walk_geometry`` decides, from
 shapes alone).  The walk: every 32-bit pool; 16-bit ``(ps, H, 2*dh)``
 pools whose head count is a multiple of 8 (the benchmark's BERT
@@ -135,7 +146,13 @@ _GROUP_BYTES = 512 * 1024
 _ROWS = 16
 
 
-def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False):
+# rows a grid step of the LATENT walk takes: its q and output blocks
+# are 64 heads wide (R x H x (W + rank) values, double-buffered)
+_ROWS_LATENT = 8
+
+
+def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False,
+                  latent=False):
     """``(G, F, R)`` of the walk for one pool geometry — ``G`` pages
     are copied per DMA group (as many whole pages as ``_GROUP_BYTES``
     holds, at least one, at most a row's table), ``F`` of them are
@@ -162,19 +179,28 @@ def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False):
       (8 rows of 32 bits, 16 of 16) and the heads' lanes are a
       multiple of 128 — 4 heads of 128 in bf16 at 16-token pages walk.
 
+    ``latent``: a flat pool of one shared row a token (``H`` 1,
+    ``2*dh`` the padded row): the flat page's rule; the whole group is
+    one turn (``F = G``, a multiple of 8 pages where that many fit, so
+    that a turn's tokens fill the scores' lanes), ``_ROWS_LATENT`` rows a
+    grid step.
+
     Chosen from shapes alone; the tests read it to aim at the group
     boundaries."""
     import numpy as np
     kv_dtype = np.dtype(kv_dtype)
     if kv_dtype == np.int8:
         return None
-    if flat:
+    if flat or latent:
         if page_size % (32 // kv_dtype.itemsize) or (H * 2 * dh) % 128:
             return None
     elif kv_dtype.itemsize < 4 and H % 8:
         return None
     page_bytes = page_size * H * 2 * dh * kv_dtype.itemsize
     G = max(1, min(PP, _GROUP_BYTES // page_bytes))
+    if latent:
+        G -= G % 8 if G > 8 else 0
+        return G, G, _ROWS_LATENT
     return G, (2 - G % 2 if flat else G), _ROWS
 
 
@@ -326,14 +352,44 @@ def _fold_flat(kv, q, m, l, acc, k0, pos, dh, cdt):
     return tuple(m2), tuple(l2), tuple(acc2)
 
 
+def _fold_latent(kv, q, m, l, acc, k0, pos, dh, cdt, *, rank, scale):
+    """``_fold`` for a latent page: ``kv`` (n, W) holds n tokens' rows
+    ``[c_kv | k_pe | 0]``, ``q`` (H, W) the row's absorbed queries
+    ``[q_lat_h | q_pe_h | 0]`` in the pool's dtype; ``m`` / ``l`` (H, 1)
+    and ``acc`` (H, rank) float32.  Both contractions are plain MXU
+    products, every head against the one shared row: ``q @ kv^T`` the
+    scores (H, n), times ``scale`` in float32; ``p @ kv[:, :rank]`` the
+    read-back.  ``dh`` is not used (the scale is the model's)."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    prec = jax.lax.Precision.HIGHEST if kv.dtype.itemsize == 4 else None
+    s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+                            preferred_element_type=f32,
+                            precision=prec) * scale
+    tok = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(k0 + tok <= pos, s, -1e30)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))   # (H, 1)
+    p = jnp.exp(s - m_new)                                      # (H, n)
+    alpha = jnp.exp(m - m_new)
+    l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+    pv = jax.lax.dot_general(p.astype(cdt), kv[:, :rank],
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=f32, precision=prec)
+    return m_new, l, acc * alpha + pv
+
+
 def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
-                 page_size, dh, T, PP, G, F, R, flat):
+                 page_size, dh, T, PP, G, F, R, flat, latent=None,
+                 scale=None):
     """Grid over blocks of R rows; the pool stays in HBM.  Per row a
     loop over groups of G pages, bounded by the row's own position:
     group g+1 (or the next row's first group) is copied into one VMEM
     slot while group g is folded, F pages a turn, out of the other:
     ``_fold_dense`` over the whole group at once (F = G), ``flat``
-    pools (grouped-query) two pages a turn with ``_fold_flat``."""
+    pools (grouped-query) two pages a turn with ``_fold_flat``,
+    ``latent`` pools (``latent`` the rank, ``scale`` the softmax's) the
+    whole group with ``_fold_latent``."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -373,10 +429,13 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
         pos = pos_ref[t]
         last = last_page(t)
         n_groups = last // G + 1
-        q = _scaled(q_ref[r], dh)                  # (H, 2*dh)
-        if not flat:
-            # the MXU's operand: a power of two keeps q exact
-            q = q.astype(buf.dtype)
+        if latent:
+            q = q_ref[r]                 # (H, W); the scale is the scores'
+        else:
+            q = _scaled(q_ref[r], dh)                  # (H, 2*dh)
+            if not flat:
+                # the MXU's operand: a power of two keeps q exact
+                q = q.astype(buf.dtype)
 
         def group(g, carry):
             m, l, acc, slot = carry
@@ -399,7 +458,9 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
                         # by its source
                         copy(0, slot, c * F + f).wait()
                 kv = buf[slot, pl.ds(c * F, F)]
-                fold = _fold_flat if flat else _fold_dense
+                fold = functools.partial(_fold_latent, rank=latent,
+                                         scale=scale) if latent \
+                    else _fold_flat if flat else _fold_dense
                 return fold(kv.reshape((F * ps,) + kv.shape[2:]), q,
                             *carry, (g * G + c * F) * ps, pos, dh,
                             q_ref.dtype)
@@ -412,7 +473,11 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
                                               turn, (m, l, acc))
             return m, l, acc, 1 - slot
 
-        if flat:
+        if latent:
+            init = (jnp.full((H, 1), -jnp.inf, f32),
+                    jnp.zeros((H, 1), f32),
+                    jnp.zeros((H, latent), f32), slot)
+        elif flat:
             init = ((jnp.full((1, 1), -jnp.inf, f32),) * H,
                     (jnp.zeros((1, 1), f32),) * H,
                     (jnp.zeros((1, dh), f32),) * H, slot)
@@ -421,7 +486,9 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
                     jnp.zeros((H, 1), f32),
                     jnp.zeros((H, 2 * dh), f32), slot)
         _, l, acc, slot = jax.lax.fori_loop(0, n_groups, group, init)
-        if flat:
+        if latent:
+            o_ref[r] = (acc / l).astype(o_ref.dtype)
+        elif flat:
             for i in range(H):
                 o_ref[r, pl.ds(i, 1), :] = (acc[i] / l[i]).astype(
                     o_ref.dtype)
@@ -501,17 +568,19 @@ _CALL_CACHE_MAX = 32
 
 
 def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
-           int8, interpret, Hkv=None):
+           int8, interpret, Hkv=None, latent=None, scale=None):
     """The ``pallas_call`` for one geometry.  ``Hkv`` given: a flat
     grouped-query pool ``(pages, page_size, Hkv*2*dh)`` under ``H``
-    query heads; None: ``(pages, page_size, H, 2*dh)``."""
+    query heads; None: ``(pages, page_size, H, 2*dh)``.  ``latent``
+    (the rank; ``Hkv`` 1, ``2*dh`` the padded row): queries ``(T, H,
+    2*dh)`` against the shared row, out ``(T, H, rank)``."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     key = (T, H, dh, PP, page_size, num_pages, str(kv_dtype),
-           str(q_dtype), int8, interpret, Hkv)
+           str(q_dtype), int8, interpret, Hkv, latent, scale)
     fn = _call_cache.get(key)
     if fn is not None:
         return fn
@@ -520,10 +589,18 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
     # a page as the pool holds it, and a row's queries: zero-extended
     # over the v lanes for the (H, 2*dh) fold, bare for the flat one
     page = (page_size, Hkv * 2 * dh) if flat else (page_size, H, 2 * dh)
-    qw = dh if flat else 2 * dh
+    qw = 2 * dh if latent or not flat else dh
+    ow = latent or dh
     zeros = (0,) * len(page)
     geometry = walk_geometry(Hkv if flat else H, dh, page_size, PP,
-                             kv_dtype, flat=flat)
+                             kv_dtype, flat=flat, latent=bool(latent))
+    if latent and geometry is None:
+        raise ValueError(
+            "paged_attention: a latent pool is folded by the page walk "
+            "alone, which cannot cut %d-token %s pages of %d lanes out of "
+            "the pool (whole tiles: 16 tokens of 16 bits or 8 of 32, "
+            "lanes a multiple of 128)"
+            % (page_size, kv_dtype, 2 * dh))
     if geometry is not None:
         G, F, R = geometry
         R = min(R, T)
@@ -532,13 +609,13 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
             pl.BlockSpec((R, H, qw), lambda b, bt, pos: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
         ]
-        out_specs = pl.BlockSpec((R, H, dh),
+        out_specs = pl.BlockSpec((R, H, ow),
                                  lambda b, bt, pos: (b, 0, 0))
         scratch = [pltpu.VMEM((2, G) + page, kv_dtype),
                    pltpu.SemaphoreType.DMA((2, G))]
         body = functools.partial(_walk_kernel, page_size=page_size,
                                  dh=dh, T=T, PP=PP, G=G, F=F, R=R,
-                                 flat=flat)
+                                 flat=flat, latent=latent, scale=scale)
     else:
         grid = (T, PP)
         in_specs = [
@@ -561,7 +638,7 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
                                  dh=dh, int8=int8, flat=flat)
     fn = pl.pallas_call(
         body,
-        out_shape=jax.ShapeDtypeStruct((T, H, dh), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((T, H, ow), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch),
@@ -574,7 +651,8 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
 
 
 def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
-                    page_size, interpret=None, mesh=None):
+                    page_size, interpret=None, mesh=None, latent=None,
+                    scale=None):
     """Single-token attention over paged K/V via block-table walk.
 
     Parameters
@@ -595,6 +673,12 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
         should point at the scratch page 0.
     row_pos : (T,) int32 per-row absolute positions — each row attends
         to positions <= its own (the continuous-batching mask).
+    latent : ``(rank, rope)`` of a LATENT pool ``(num_pages, page_size,
+        W)``, ``W = latent_width(rank, rope)``: ``q`` is then ``(T, H,
+        rank + rope)``, each head's absorbed query against the one row
+        a token that all heads share, ``scale`` the softmax scale (the
+        model's; required), and the result ``(T, H, rank)``: ``p``
+        against the rows' first ``rank`` lanes.
     mesh : optional serving mesh with a live ``tp`` axis (round 22).
         The call is then lowered through ``shard_map``: each device
         walks only its H/tp heads slice of the heads-sharded pools
@@ -622,7 +706,21 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
                          % (pool_kv.shape[1], page_size))
     int8 = pool_s is not None
     Hkv = None
-    if pool_kv.ndim == 3:
+    if latent:
+        rank, rope = latent
+        W = pool_kv.shape[-1]
+        if pool_kv.ndim != 3 or W % 2 or W < rank + rope \
+                or dh != rank + rope or scale is None or int8 \
+                or mesh is not None:
+            raise ValueError(
+                "paged_attention: a latent pool is (pages, page_size, W "
+                ">= rank + rope) under (T, H, rank + rope) queries with "
+                "the model's scale=, no int8 and no mesh; got %r, q %r, "
+                "latent %r" % (tuple(pool_kv.shape), tuple(q.shape),
+                               latent))
+        Hkv, dh = 1, W // 2
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, W - rank - rope)))
+    elif pool_kv.ndim == 3:
         Hkv = pool_kv.shape[2] // (2 * dh)
         if Hkv * 2 * dh != pool_kv.shape[2] or H % max(Hkv, 1):
             raise ValueError(
@@ -659,7 +757,8 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
 
     def call(interp):
         fn = _build(T, H // tp, dh, PP, page_size, num_pages,
-                    pool_kv.dtype, q.dtype, int8, interp, Hkv)
+                    pool_kv.dtype, q.dtype, int8, interp, Hkv,
+                    latent and latent[0], scale and float(scale))
         if tp_axis is None:
             return fn
         from jax.sharding import PartitionSpec as P
@@ -679,7 +778,8 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
 
 
 def paged_attention_reference(q, pool_kv, pool_s, block_tables,
-                              row_pos, *, page_size):
+                              row_pos, *, page_size, latent=None,
+                              scale=None):
     """The jnp path: block-table gather + ``_attend_rows``.  This IS
     the serving engine's ``kernel="xla"`` attention (the step program
     calls it directly — one copy, so the engine path and the tests'
@@ -694,6 +794,27 @@ def paged_attention_reference(q, pool_kv, pool_s, block_tables,
     T, H, dh = q.shape
     PP = block_tables.shape[1]
     L = PP * page_size
+    if latent:
+        # every head against the one gathered row a token; the same
+        # roundings as ``_fold_latent`` (operands in the pool's dtype,
+        # float32 scores times the scale, p rounded before its product)
+        rank, rope = latent
+        prec = jax.lax.Precision.HIGHEST \
+            if pool_kv.dtype.itemsize == 4 else None
+        with jax.named_scope("paged_attn"):
+            with jax.named_scope("gather"):
+                rows = pool_kv[block_tables].reshape(T, L, -1)
+            with jax.named_scope("attend"):
+                s = jnp.einsum("thw,tlw->thl", q, rows[..., :rank + rope],
+                               preferred_element_type=jnp.float32,
+                               precision=prec) * scale
+                live = jnp.arange(L)[None, None, :] \
+                    <= row_pos[:, None, None]
+                p = jax.nn.softmax(jnp.where(live, s, -1e30), axis=-1)
+                return jnp.einsum("thl,tlr->thr", p.astype(q.dtype),
+                                  rows[..., :rank],
+                                  preferred_element_type=jnp.float32,
+                                  precision=prec)
     with jax.named_scope("paged_attn"):
         with jax.named_scope("gather"):
             if pool_kv.ndim == 3:

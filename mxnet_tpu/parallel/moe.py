@@ -12,6 +12,20 @@ all-to-alls over ICI.
 Gradients flow through the gate probabilities in the combine tensor
 (standard straight-through routing); an auxiliary load-balancing loss
 (Switch eq. 4) keeps the router from collapsing onto few experts.
+
+**The serving form** (``route_group_limited`` + ``held_experts_ffn``,
+PR 32) is the other recipe, DeepSeek-V3's: sigmoid scores, a bias that
+takes part in the choice alone, group-limited top-k, and DROPLESS
+dispatch with no capacity buffer.  The expert layer is told which
+experts it holds (``held_first``, ``held_count``: one rank's share of an
+expert-parallel deployment), routes over ALL of them and computes its
+own experts' part of the result: the row-expert pairs are sorted by
+expert, the held ones first, and three grouped matmuls run over a
+static ``rows x top_k`` pairs (``_grouped_dot``: on a TPU the megablox
+kernel, which visits only the row tiles that hold a group and reads
+each hit expert's weights about once; ``jax.lax.ragged_dot`` elsewhere).  What
+the absent experts would add is left out; on one chip the layer runs
+without its exchange.
 """
 from __future__ import annotations
 
@@ -21,7 +35,8 @@ from typing import Optional
 from ..base import MXNetError
 
 __all__ = ["init_moe_ffn", "moe_ffn", "moe_param_specs",
-           "moe_param_shardings"]
+           "moe_param_shardings", "route_group_limited",
+           "held_experts_ffn"]
 
 
 def init_moe_ffn(key, d_model, d_ff, n_experts, param_dtype="float32"):
@@ -161,3 +176,112 @@ def moe_ffn(x, params, *, n_experts, top_k=2, capacity_factor=1.25,
 
     out = jnp.einsum("gsec,egcd->gsd", combine.astype(cdt), y)
     return out.astype(x.dtype), aux_loss
+
+
+# ------------------------------------------------- the serving form ---
+
+def route_group_limited(scores, bias, *, n_group, topk_group, top_k,
+                        norm_topk_prob=True, scale=1.0):
+    """DeepSeek-V3's ``noaux_tc`` choice over ``scores`` (T, E) float32
+    (the sigmoid of the router's logits): for choosing only,
+    ``s' = scores + bias``; a group's score is the sum of its two best
+    ``s'`` (``n_group`` groups of ``E / n_group`` consecutive experts),
+    the best ``topk_group`` groups stay, and the ``top_k`` best ``s'``
+    among them are chosen.  The weights are the chosen SCORES (not
+    ``s'``), divided by their sum where ``norm_topk_prob``, times
+    ``scale``.  Returns ``(idx (T, top_k) int32, w (T, top_k) float32)``
+    over all E experts, whoever holds them."""
+    import jax
+    import jax.numpy as jnp
+    T, E = scores.shape
+    choice = scores + bias.astype(scores.dtype)
+    if n_group > 1:
+        grouped = choice.reshape(T, n_group, E // n_group)
+        best2, _ = jax.lax.top_k(grouped, 2)
+        _, keep = jax.lax.top_k(jnp.sum(best2, axis=-1), topk_group)
+        kept = jnp.any(keep[:, :, None] == jnp.arange(n_group), axis=1)
+        choice = jnp.where(jnp.repeat(kept, E // n_group, axis=1), choice,
+                           -jnp.inf)
+    _, idx = jax.lax.top_k(choice, top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scale
+
+
+def _tile(n, prefer):
+    """The largest of ``prefer`` that divides ``n``, else ``n`` whole."""
+    return next((t for t in prefer if n % t == 0), n)
+
+
+def _grouped_dot(x, w, sizes):
+    """``x[rows of group g] @ w[g]`` for every group: x (M, K) sorted by
+    group, w (G, K, N), sizes (G,) int32 whose sum may fall short of M
+    (the rows after the last group are whatever the kernel leaves
+    there).  Float32 out.  On a TPU ``megablox.gmm``: row tiles of 128,
+    weight tiles of up to 1024 x 1024 (2 MiB in bfloat16, the copy that
+    binds a step with a handful of rows an expert), a grid over the
+    ACTIVE row tiles only; the XLA ``ragged_dot`` on the CPU."""
+    import jax
+    import jax.numpy as jnp
+    from ..kernels.platform import run_kernel
+    M, K = x.shape
+    N = w.shape[2]
+
+    def build(on_cpu):
+        if on_cpu:
+            return lambda x, w, sizes: jax.lax.ragged_dot(
+                x, w, sizes, preferred_element_type=jnp.float32)
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        tiling = (_tile(M, (128, 64, 32, 16, 8)),
+                  _tile(K, (1024, 512, 256, 128)),
+                  _tile(N, (1024, 512, 256, 128)))
+        return lambda x, w, sizes: gmm(x, w, sizes, jnp.float32, tiling)
+
+    return run_kernel(build, x, w, sizes)
+
+
+def held_experts_ffn(x, w_gate, w_up, w_down, idx, w, *, held_first,
+                     live=None):
+    """The held experts' part of a dropless expert layer.
+
+    ``x`` (T, D) rows in the compute dtype; ``w_gate`` / ``w_up``
+    (E_held, D, F) and ``w_down`` (E_held, F, D): the SwiGLU experts
+    ``held_first .. held_first + E_held - 1`` of the layer; ``idx`` /
+    ``w`` (T, k) each row's chosen experts and weights over ALL experts
+    (``route_group_limited``); ``live`` (T,) bool: rows whose pairs are
+    dispatched (a dead row of a fixed-shape step is none of the
+    traffic).  Returns ``(y (T, D) float32, pairs, hit)``:
+    ``y = sum over a row's HELD choices of w_k E_k(x)``, the number of
+    row-expert pairs dispatched and of held experts with at least one.
+
+    The ``T x k`` pairs are a static bound, so nothing is dropped and
+    no capacity is set: the pairs are sorted by held expert (those of
+    absent experts and dead rows last, outside every group), each
+    matmul is one grouped product over the sorted rows, and the result
+    goes back by the inverse permutation."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    T, D = x.shape
+    K = idx.shape[1]
+    E = w_gate.shape[0]
+    local = idx - held_first
+    held = (local >= 0) & (local < E)
+    if live is not None:
+        held = held & live[:, None]
+    key = jnp.where(held, local, E).reshape(-1)              # (T*K,)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(E)[None, :], axis=0,
+                    dtype=jnp.int32)                          # (E,)
+    xs = x[order // K]                                        # (T*K, D)
+    h = (jax.nn.silu(_grouped_dot(xs, w_gate, sizes))
+         * _grouped_dot(xs, w_up, sizes)).astype(x.dtype)
+    out = _grouped_dot(h, w_down, sizes)                      # (T*K, D)
+    # rows past the groups are whatever the grouped product leaves
+    # there: selected away, not multiplied away
+    back = jnp.argsort(order)
+    out = jnp.where(held.reshape(-1, 1), out[back], 0.0)
+    y = jnp.sum(out.reshape(T, K, D)
+                * jnp.where(held, w, 0.0).astype(f32)[..., None], axis=1)
+    return y, jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32)

@@ -124,6 +124,19 @@ by name, what would need snapshots or rollback of that state:
 ``prefix_cache``, ``spec_K``, the KV tier, ``admit_prefilled``,
 ``kv_int8``, ``tp > 1`` (ROADMAP B-m6).
 
+A family whose cache is LATENT (``cfg.latent_row``: multi-head latent
+attention, ``models/deepseek_v3.py``) hands ``attend`` each row's
+absorbed queries and its ONE cache row instead of per-head keys and
+values: the pool holds that row a token a layer (``paged_kv.py``), the
+walk folds every head against it with the model's own softmax scale.
+Such a family's module may count on the device over the step's live
+rows (``STEP_COUNTERS``: its expert layers' dispatched pairs and experts
+hit): the counts ride behind the sampled tokens in the step's one
+read-back and ``counter_stats`` books them in ``stats`` at the commit.
+Refused for it by name: ``prefix_cache``, ``spec_K``, the KV tier,
+``admit_prefilled``, ``kv_int8``, ``tp > 1`` (no test shows them on
+latent pages yet).
+
 Exactness: under f32 greedy, engine outputs are token-identical to
 ``models/gpt.py generate`` per request, whatever the batch mix,
 admission order, page reuse, preemptions, swap-outs, kernel choice,
@@ -157,8 +170,8 @@ import numpy as np
 from .. import profiler
 from ..models import gpt as G
 from . import drafters
-from .paged_kv import (PagedKVCache, kv_geometry, slot_state_shapes,
-                       write_rows)
+from .paged_kv import (PagedKVCache, kv_geometry, latent_row,
+                       slot_state_shapes, write_latent, write_rows)
 from .prefix_cache import PrefixCache
 from .tier_store import HostTierStore
 
@@ -406,6 +419,8 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
     # for a family with ``slot_state_shapes``, the per-slot state
     model = getattr(cfg, "serving", G)
     state_names = tuple(slot_state_shapes(cfg))
+    latent = latent_row(cfg)
+    counter_names = tuple(getattr(model, "STEP_COUNTERS", ()))
 
     def _body(params, pools, tokens, row_slot, row_pos, row_live, bt,
               slot_rows, slot_fresh=None):
@@ -428,7 +443,24 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
         row_pages = bt[row_slot]                       # (T, PP)
 
         new_pools = []
+        # what the model counts over the step's live rows, all layers
+        # together (``STEP_COUNTERS``)
+        counts = model.StepCounts(row_live) if counter_names else None
         for layer, pool in zip(params["layers"], pools):
+            def attend_latent(q, row, pool=pool):
+                """Write the rows' one latent row each into their
+                pages, then every head of each row against its own
+                block table's rows: (T, H, rank) float32."""
+                from ..kernels import paged_attention as PA
+                with jax.named_scope("kv_write"):
+                    pool_kv = write_latent(pool["kv"], page, off, row)
+                    new_pools.append({"kv": pool_kv})
+                fn = PA.paged_attention if kernel == "pallas" \
+                    else PA.paged_attention_reference
+                return fn(q, pool_kv, None, row_pages, row_pos,
+                          page_size=page_size, latent=latent,
+                          scale=cfg.softmax_scale)
+
             def attend(q, k, v, pool=pool):
                 """Write the rows' k/v into their pages, then each
                 row's attention over its own block table: (T, H, dh)
@@ -481,8 +513,10 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
             state = model.SlotState(
                 {name: pool[name] for name in state_names}, row_slot,
                 slot_fresh, n_rows - num_slots * n_sample) \
-                if state_names else None
-            x = model.serve_block(layer, cfg, x, row_pos, attend, state)
+                if state_names else counts
+            x = model.serve_block(layer, cfg, x, row_pos,
+                                  attend_latent if latent else attend,
+                                  state)
             if state_names:
                 new_pools[-1].update(state.pools)
 
@@ -495,6 +529,12 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
         with jax.named_scope("sample"):
             next_tok = jnp.argmax(slot_logits,
                                   axis=-1).astype(jnp.int32)
+        if counter_names:
+            # the step's counts ride behind the tokens, one row each:
+            # the host's one read-back brings them
+            next_tok = jnp.concatenate([next_tok, jnp.broadcast_to(
+                jnp.stack(counts.counts)[:, None],
+                (len(counter_names), n_sample))])
         return next_tok, new_pools
 
     if overlap:
@@ -993,12 +1033,13 @@ class ServingEngine:
             except ValueError:
                 raise ValueError(
                     "MXNET_SERVE_TIER_BYTES=%r: expected int" % env)
-        if self._stateful:
-            # each of these moves, shares or rolls back a sequence's
-            # cache as PAGES; a slot's recurrent state is none, and
-            # has no snapshot, rollback or sharded layout yet
-            # (ROADMAP B-m)
-            for on, what in (
+        # what a family is refused, by name.  Per-slot recurrent state:
+        # each of these moves, shares or rolls back a sequence's cache
+        # as PAGES; a slot's state is none, and has no snapshot,
+        # rollback or sharded layout yet.  A latent (MLA) row per
+        # token: no test shows these on latent pages yet (ROADMAP B-m6)
+        for keeps, refused in (
+                (self._stateful and "per-slot recurrent state", (
                     (prefix_cache, "prefix_cache=True: a shared prefix "
                      "is K/V pages; the state after it has no snapshot "
                      "to restore"),
@@ -1010,12 +1051,20 @@ class ServingEngine:
                     (kv_int8, "kv_int8=True: no int8 layout for a "
                      "grouped-query pool"),
                     (tp > 1, "tp > 1: no sharded layout for the "
-                     "key/value heads and the per-slot state")):
+                     "key/value heads and the per-slot state"))),
+                (latent_row(cfg) and "a latent (MLA) row per token", (
+                    (prefix_cache, "prefix_cache=True"),
+                    (spec_K > 0, "spec_K > 0"),
+                    (tier_bytes, "tier_bytes > 0 (KV tier / swap)"),
+                    (kv_int8, "kv_int8=True: no int8 layout for a "
+                     "latent row"),
+                    (tp > 1, "tp > 1: a latent row has no heads to "
+                     "shard")))):
+            for on, what in refused if keeps else ():
                 if on:
                     raise ValueError(
-                        "ServingEngine: %s keeps per-slot recurrent "
-                        "state; refused with %s"
-                        % (type(cfg).__name__, what))
+                        "ServingEngine: %s keeps %s; refused with %s"
+                        % (type(cfg).__name__, keeps, what))
         if device is not None and tp > 1:
             raise ValueError("ServingEngine: device= places a tp=1 "
                              "engine; a tp>1 engine is placed by its "
@@ -1120,7 +1169,8 @@ class ServingEngine:
         kv_heads, head_dim, flat_kv = kv_geometry(cfg)
         geometry = kernel == "pallas" and walk_geometry(
             kv_heads // tp, head_dim, page_size, pages_per_slot,
-            self.cache.pools[0]["kv"].dtype, flat=flat_kv)
+            self.cache.pools[0]["kv"].dtype, flat=flat_kv,
+            latent=bool(latent_row(cfg)))
         self._walk_turn = geometry[1] if geometry else 0
         # host-DRAM KV tier (round 18): explicit argument >
         # MXNET_SERVE_TIER_BYTES env > off.  0/None disables — every
@@ -1184,6 +1234,15 @@ class ServingEngine:
             # only while every slot is live with one row
             self.stats.update(ssm_state_updates=0, ssm_state_resets=0,
                               ssm_state_bytes=0)
+        # what the model's module counts on the device a step
+        # (``STEP_COUNTERS``, read back behind the tokens) and what
+        # ``counter_stats`` makes of it: absent for a family that
+        # counts nothing
+        self._model = getattr(cfg, "serving", G)
+        self._n_counters = len(getattr(self._model, "STEP_COUNTERS", ()))
+        if self._n_counters:
+            self.stats.update(self._model.counter_stats(
+                cfg, self.params, (0,) * self._n_counters))
         # One lock (_mu) guards what the caller of step() shares with
         # the threads that submit, cancel, preempt and admit_prefilled:
         # queue/slots/pages/prefix/stats and the request fields they
@@ -1285,6 +1344,11 @@ class ServingEngine:
                 "the disaggregated hand-off moves K/V pages and has no "
                 "snapshot of that state to install"
                 % type(self.cfg).__name__)
+        if latent_row(self.cfg):
+            raise ValueError(
+                "admit_prefilled: %s keeps a latent (MLA) row per token; "
+                "the disaggregated hand-off is not shown on latent pages "
+                "yet" % type(self.cfg).__name__)
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         generated = [int(t) for t in generated]
         if not generated:
@@ -2044,7 +2108,8 @@ class ServingEngine:
                 else:
                     if self._tok0 is None:
                         self._tok0 = jnp.zeros(
-                            (self.num_slots, 1 + self.spec_K), jnp.int32)
+                            (self.num_slots + self._n_counters,
+                             1 + self.spec_K), jnp.int32)
                     prev = self._tok0
                 staged += [prev, jnp.asarray(buf.tok_src)]
         with profiler.span("engine.launch"):
@@ -2151,6 +2216,11 @@ class ServingEngine:
             req.n_cached = max(req.n_cached, p1)
             if self.prefix is not None:
                 self._insert_prefix(req)
+        if self._n_counters:
+            for name, n in self._model.counter_stats(
+                    self.cfg, self.params,
+                    next_tok[self.num_slots:, 0]).items():
+                self.stats[name] += n
 
         if obs is not None:
             dead = self.n_rows - plan.n_rows_used

@@ -14,6 +14,12 @@ k then v side by side on the last axis: with few heads the 4-d page's
 two minor dims are no whole tiles and the page walk could not cut
 pages out of it (``kernels/paged_attention.py walk_geometry``).
 
+A LATENT pool (multi-head latent attention, ``latent_row``) is flat
+too, ``(num_pages, page_size, W)``: one row ``[c_kv | rotated k_pe]`` a
+token shared by every query head, read once as the key and its first
+``rank`` lanes again as the value, padded with zero lanes to whole
+tiles (``latent_width``; ``write_latent`` writes it).
+
 A model whose sequences keep state that is no page (``slot_state_shapes``
 of its serving module: a recurrent state, a convolution window) gets a
 second, NON-paged pool per layer and name, ``(num_slots + 1, ...)``:
@@ -92,7 +98,23 @@ from collections import deque
 from typing import Any, Dict
 
 __all__ = ["PagedKVCache", "contiguous_kv_bytes", "kv_geometry",
-           "slot_state_shapes", "write_rows"]
+           "latent_row", "latent_width", "slot_state_shapes",
+           "write_latent", "write_rows"]
+
+
+def latent_row(cfg):
+    """``(rank, rope)`` of a config whose cache is LATENT (multi-head
+    latent attention: ``cfg.latent_row``), else None.  A latent pool
+    holds one row ``[c_kv (rank) | rotated k_pe (rope)]`` a token,
+    shared by every query head, and nothing per head."""
+    return getattr(cfg, "latent_row", None)
+
+
+def latent_width(rank, rope):
+    """Lanes of a latent page's row: ``rank + rope`` padded with zeros
+    to whole 128-lane tiles (512 + 64 -> 640), so that the page walk
+    can cut whole pages out of the pool."""
+    return -(-(rank + rope) // 128) * 128
 
 
 def kv_geometry(cfg):
@@ -100,7 +122,11 @@ def kv_geometry(cfg):
     key/value heads and explicit head size where it names them, else
     one per query head of ``d_model // n_heads``; ``flat`` where the
     key/value heads are shared by groups of query heads (the page is
-    then ``(page_size, heads*2*head_size)``)."""
+    then ``(page_size, heads*2*head_size)``).  A latent pool
+    (``latent_row``) is one flat "head" whose k | v are the two halves
+    of its padded row: the page is ``(page_size, latent_width)``."""
+    if latent_row(cfg):
+        return 1, latent_width(*latent_row(cfg)) // 2, True
     H = getattr(cfg, "n_kv_heads", None) or cfg.n_heads
     dh = getattr(cfg, "head_dim", None) or cfg.d_model // cfg.n_heads
     return H, dh, H != cfg.n_heads
@@ -116,6 +142,16 @@ def write_rows(pool_kv, page, off, k, v):
     new = jnp.concatenate([k, v], axis=-1).astype(pool_kv.dtype)
     if pool_kv.ndim == 3:
         new = new.reshape(new.shape[0], -1)
+    return pool_kv.at[page, off].set(new)
+
+
+def write_latent(pool_kv, page, off, row):
+    """The latent pool with row r's ``row[r]`` ((T, rank + rope)) written
+    at position ``off[r]`` of page ``page[r]``, zero lanes after it up
+    to the page's width."""
+    import jax.numpy as jnp
+    pad = pool_kv.shape[-1] - row.shape[-1]
+    new = jnp.pad(row.astype(pool_kv.dtype), ((0, 0), (0, pad)))
     return pool_kv.at[page, off].set(new)
 
 
@@ -250,9 +286,10 @@ class PagedKVCache:
         H, dh, flat = kv_geometry(cfg)
         if flat and (kv_int8 or mesh is not None):
             raise ValueError(
-                "PagedKVCache: a grouped-query pool (%d key/value heads "
-                "under %d query heads) has no int8 scale planes and no "
-                "heads-sharded placement" % (H, cfg.n_heads))
+                "PagedKVCache: a flat pool (grouped-query or latent: %d "
+                "key/value heads under %d query heads) has no int8 scale "
+                "planes and no heads-sharded placement"
+                % (H, cfg.n_heads))
         page = (page_size, H * 2 * dh) if flat \
             else (page_size, H, 2 * dh)
         cdt = jnp.dtype(cfg.dtype)

@@ -134,6 +134,50 @@ def test_grouped_paged_attention_compiles(v5e, geom, dtype, walks):
         _sds((g["T"], g["PP"]), "int32"), _sds((g["T"],), "int32"))
 
 
+# a latent (MLA) pool is one 512 + 64 row a token padded to 640 lanes:
+# the gigachat3_702b_l5_ep16 cell is 256 step rows, 64 query heads, 128
+# 16-token pages a row, a (16385, 16, 640) bf16 pool
+_MLA = dict(T=256, H=64, rank=512, rope=64, ps=16, PP=128, NP=16385)
+
+
+@pytest.mark.parametrize("geom,dtype", [
+    (_MLA, "bfloat16"),
+    (dict(_MLA, T=37, PP=7, NP=353), "float32"),
+], ids=["cell-bf16", "odd-f32"])
+def test_latent_paged_attention_compiles(v5e, geom, dtype):
+    from mxnet_tpu.kernels.paged_attention import (paged_attention,
+                                                   walk_geometry)
+    from mxnet_tpu.serving.paged_kv import latent_width
+    g = geom
+    W = latent_width(g["rank"], g["rope"])
+    assert W == 640
+    G, F, R = walk_geometry(1, W // 2, g["ps"], g["PP"], dtype, flat=True,
+                            latent=True)
+    # the whole group a turn, its tokens whole lane tiles of scores
+    assert F == G == (24 if dtype == "bfloat16" else g["PP"])
+    _compile(lambda q, kv, bt, pos: paged_attention(
+        q, kv, None, bt, pos, page_size=g["ps"],
+        latent=(g["rank"], g["rope"]), scale=0.14468), v5e[0],
+        _sds((g["T"], g["H"], g["rank"] + g["rope"]), dtype),
+        _sds((g["NP"], g["ps"], W), dtype),
+        _sds((g["T"], g["PP"]), "int32"), _sds((g["T"],), "int32"))
+
+
+def test_held_experts_ffn_compiles(v5e):
+    """The cell's expert layer: 256 rows x top-8 = 2,048 static pairs
+    over the 16 held experts of width 2,048: three grouped products,
+    each one Mosaic call (megablox)."""
+    from mxnet_tpu.parallel.moe import held_experts_ffn
+    T, K, D, F, E = 256, 8, 7168, 2048, 16
+    text = _compile(
+        lambda x, wg, wu, wd, idx, w, live: held_experts_ffn(
+            x, wg, wu, wd, idx, w, held_first=0, live=live), v5e[0],
+        _sds((T, D), "bfloat16"), _sds((E, D, F), "bfloat16"),
+        _sds((E, D, F), "bfloat16"), _sds((E, F, D), "bfloat16"),
+        _sds((T, K), "int32"), _sds((T, K), "float32"), _sds((T,), "bool"))
+    assert text.count("tpu_custom_call") >= 3
+
+
 def test_flash_fwd_bwd_compiles(v5e):
     from mxnet_tpu.kernels import flash_attention as fa
     qkv = _sds((1, 4096, 12, 64), "bfloat16")

@@ -564,6 +564,40 @@ def build_gpt_train_step():
     return step, (state, _train_batch(False), key)
 
 
+def build_serving_step_latent():
+    """The step of a latent-attention, routed-experts family
+    (``models/deepseek_v3.py``, PR 32) as a TPU engine runs it: the
+    SAME live ``_make_step`` builder, pipelined, latent pages donated,
+    the expert layers' counts behind the tokens.  One dense and one
+    expert layer at toy widths, 8 routed experts of which 4 are held."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.models import deepseek_v3 as M
+    from mxnet_tpu.serving.engine import _make_step
+    from mxnet_tpu.serving.paged_kv import latent_width
+    cfg = M.DeepseekV3Config(
+        vocab_size=256, d_model=64, n_layers=2, n_heads=4, q_lora_rank=32,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, d_ff=128, moe_d_ff=32, n_routed_experts=8,
+        n_shared_experts=1, top_k=2, n_group=2, topk_group=1,
+        first_k_dense=1, held_count=4, rope_factor=4.0,
+        rope_mscale_all_dim=1.0, dtype="bfloat16")
+    pps, n_rows = 4, _SLOTS + _CHUNK
+    fn = _make_step(cfg, _SLOTS, n_rows, pps, _PAGE, False, kernel="xla",
+                    overlap=True)
+    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+    params = jax.eval_shape(
+        lambda: M.init_params(jax.random.PRNGKey(0), cfg))
+    pools = [{"kv": sds((_SLOTS * pps + 1, _PAGE,
+                         latent_width(*cfg.latent_row)), jnp.bfloat16)}
+             for _ in range(cfg.n_layers)]
+    n_counts = len(M.STEP_COUNTERS)
+    return fn, (params, pools, sds((n_rows,), i32), sds((n_rows,), i32),
+                sds((n_rows,), i32), sds((n_rows,), jnp.bool_),
+                sds((_SLOTS + 1, pps), i32), sds((_SLOTS, 1), i32),
+                sds((_SLOTS + n_counts, 1), i32), sds((n_rows,), i32))
+
+
 def build_paged_attention_kernel():
     import jax
     import jax.numpy as jnp
@@ -609,6 +643,11 @@ def live_programs() -> List[ProgramSpec]:
         # budget are gated exactly like the serial program's
         spec("serving_step_overlap", build_serving_step_overlap,
              donate=(1,), dtype_region="int8", f32_allow=acc),
+        # PR 32: a latent-attention family's step (no dtype region:
+        # its norms, router and softmax are float32 by design, at
+        # widths of its own)
+        spec("serving_step_latent", build_serving_step_latent,
+             donate=(1,)),
         spec("serving_step_tp", build_serving_step_tp, donate=(1,),
              dtype_region="int8", f32_allow=acc),
         # round 22: the mesh-lowered PALLAS step — the chip-ready
