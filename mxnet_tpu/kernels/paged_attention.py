@@ -13,21 +13,36 @@ straight from the pool, once, and folds them into an
 weighted-V sum, the FlashAttention recurrence over pages instead of
 k-blocks): it is what the engine runs on a TPU.
 
-**The walk** (``_walk_kernel``, PR 27).  Grid over blocks of R rows
-— 10 grid steps a layer for the benchmark's 160-row step, not one per
-page.  The block table and the positions are scalar-prefetched
+**The walk** (``_walk_kernel``, PR 27, 34).  Grid over blocks of R
+rows — 5 grid steps a layer for the benchmark's 160-row step, not one
+per page.  The block table and the positions are scalar-prefetched
 (``pltpu.PrefetchScalarGridSpec``); the pool stays in HBM
-(``memory_space=pl.ANY``).  Per row, a ``fori_loop`` over groups of G
-pages bounded by ``row_pos // page_size + 1`` and by nothing else:
-pages past a row's position, and every page of a dead row but its
-scratch page, are neither copied nor folded.  ``make_async_copy``
-brings group g+1 — or the NEXT row's first group, so a row's first
-copy hides behind the row before it — into one of two VMEM slots
-while group g is folded out of the other, F pages a turn; a whole
-page ``(ps, H, 2*dh)`` is one contiguous copy.  G, F and R follow
-from the shapes (``walk_geometry``): for the cell's bf16 pool of 16
-heads of 64, pages are 64 KiB, G = 8 (two 512 KiB slots), F = G,
-R = 16.
+(``memory_space=pl.ANY``).  A grid step's work is the flat sequence
+of its rows' live groups of G pages, the (row, group) items, bounded
+by ``row_pos // page_size + 1`` and by nothing else: pages past a
+row's position, and every page of a dead row but its scratch page,
+are neither copied nor folded.  A cursor steps through the items
+twice, K - 1 items apart: ``make_async_copy`` brings item i + K - 1
+into slot (i - 1) mod K of a ring of K VMEM buffers while item i is
+folded out of slot i mod K — a whole page ``(ps, H, 2*dh)`` is one
+contiguous copy, and a row's first copy hides behind the rows before
+it.  The loop takes K items a trip, so every slot index is static and
+the compiler, which sees each slot as a buffer of its own, runs the
+scalar code that issues the copies under the chain of the fold beside
+it; each item is folded FROM A FRESH (m, l, acc), a whole group at
+once, and merged into its row's running state in VMEM (the two-way
+softmax merge), so a chain waits for nothing an earlier one leaves;
+the rows are normalised and written when the grid step's items are
+in.  The slots that a block's last trip has no item for fold what
+they hold, masked, into a spare row of the state (a guard a slot
+would cost every item a basic block's boundary, a guarded extra trip
+a second copy of the loop's body to trace and lower at every
+start-up).  G, F, R and K follow from the shapes
+(``walk_geometry``): for the cell's bf16 pool of 16 heads of 64,
+pages are 64 KiB, G = 8 (four 512 KiB slots), F = G, R = 32, K = 4.
+The flat fold keeps the older loop: per row a ``fori_loop`` over its
+groups, one group copied ahead into the other of two slots, the state
+carried from group to group.
 
 **The dense fold** (``_fold_dense``, PR 31) is what the walk folds a
 ``(ps, H, 2*dh)`` pool with, a whole group a turn.  A page's rows,
@@ -119,16 +134,22 @@ actually guarantees.
 
 On the chip: ``tests/test_kernels_mosaic.py`` compiles every pool
 kind for v5e without one; ``chip_smoke.py`` pins both feeders against
-``paged_attention_reference`` there.  Measured (PERF.md, PR 27 and
-31): in ``bert_large_decoder.decode_heavy`` the gather path was 68 ms
-of device time a step, the per-page grid 43, the walk under the column
-fold 7.3 (bound by the XLU: 64 lane reductions a turn of two pages),
-under the dense fold 5.3: rows 31 pages deep run at 72% of the HBM
-peak, and what binds the cell's ragged rows is the latency of the one
-group of copies in flight and the length of one group's chain through
-the MXU, neither the bytes nor the arithmetic.  A tree of rolls and
-selects in place of the lane reductions was built first and lost
-(x0.42): every roll is an XLU operation too.
+``paged_attention_reference`` there.  Measured (PERF.md, PR 27, 31
+and 34): in ``bert_large_decoder.decode_heavy`` the gather path was 68
+ms of device time a step, the per-page grid 43, the walk under the
+column fold 7.3 (bound by the XLU: 64 lane reductions a turn of two
+pages), under the dense fold 5.3, with the ring 3.9: 554 GB/s, 68% of
+the HBM peak on the cell's ragged rows, 90% on rows 31 pages deep.
+The core runs at 0.94 GHz and what binds the walk is its instruction
+stream, which the compiled loop's bundles show without a chip: a
+group was 657 bundles under two slots, 240 of them the serial scalar
+code that issues eight copies, and is about 500 in the ring — 384 the MXU's
+weight pushes (a transposed push is 8 cycles an MXU, a plain one 4;
+128 of each a group over four MXUs), the rest waits and the tail of
+the issue.  Built and lost on the way: a tree of rolls and selects in
+place of the lane reductions (x0.42: every roll is an XLU operation
+too); two chains in one basic block (no shorter than two blocks: they
+contend for the push slots); a ring of three or of six.
 """
 from __future__ import annotations
 
@@ -137,8 +158,8 @@ import functools
 __all__ = ["paged_attention", "paged_attention_reference"]
 
 # VMEM one DMA slot of the walk may hold: a group is as many whole
-# pages as fit (two slots are live, so the walk's buffers are twice
-# this, beside the f32 temporaries of one fold)
+# pages as fit (the ring's _RING slots are live, so the walk's buffers
+# are that many times this, beside the f32 temporaries of one fold)
 _GROUP_BYTES = 512 * 1024
 # rows of the step a grid step walks: the first copy of a grid step
 # has nothing to hide behind, so its latency is paid once per _ROWS
@@ -149,22 +170,34 @@ _ROWS = 16
 # rows a grid step of the LATENT walk takes: its q and output blocks
 # are 64 heads wide (R x H x (W + rank) values, double-buffered)
 _ROWS_LATENT = 8
+# rows a grid step of the ring takes on a ``(ps, H, 2*dh)`` pool: what
+# a grid step costs beside its items (its first copies exposed, the
+# rows' state normalised and written) is paid once per _ROWS_RING rows
+_ROWS_RING = 32
+# items a trip of the ring's loop takes, each from a slot of its own:
+# while one is folded, _RING - 1 groups of copies are on their way
+_RING = 4
 
 
 def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False,
                   latent=False):
-    """``(G, F, R)`` of the walk for one pool geometry — ``G`` pages
+    """``(G, F, R, K)`` of the walk for one pool geometry — ``G`` pages
     are copied per DMA group (as many whole pages as ``_GROUP_BYTES``
     holds, at least one, at most a row's table), ``F`` of them are
     folded per turn of the inner loop (the whole group under the dense
     fold, whose fixed cost, the MXU's latency twice over, is then paid
     once a group; two a turn under the flat fold where G is even: a
     row's last turn folds at most one page it did not need), ``R``
-    rows are walked per grid
-    step — or ``None`` where Mosaic cannot cut whole pages out of the
-    pool and the per-page grid serves instead: a ``memref_slice`` of
-    an HBM ref must be whole tiles in its two minor dims even where it
-    takes them whole.
+    rows are walked per grid step, ``K`` groups are taken per trip of
+    the walk's loop, each folded by a chain of its own out of its own
+    slot of a ring of K copies (``_RING`` where the fold is one MXU
+    turn a group, F == G: while one group is folded, K - 1 are on
+    their way; 1 under the flat fold, whose turns are bound by the
+    XLU's lane reductions and keep their loop of one group a trip
+    over two slots) — or ``None`` where Mosaic cannot cut whole pages
+    out of the pool and the per-page grid serves instead: a
+    ``memref_slice`` of an HBM ref must be whole tiles in its two
+    minor dims even where it takes them whole.
 
     ``H`` is the pool's head count (the key/value heads), ``flat``
     which of the two page layouts it has:
@@ -183,7 +216,7 @@ def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False,
     ``2*dh`` the padded row): the flat page's rule; the whole group is
     one turn (``F = G``, a multiple of 8 pages where that many fit, so
     that a turn's tokens fill the scores' lanes), ``_ROWS_LATENT`` rows a
-    grid step.
+    grid step, the ring.
 
     Chosen from shapes alone; the tests read it to aim at the group
     boundaries."""
@@ -200,8 +233,8 @@ def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False,
     G = max(1, min(PP, _GROUP_BYTES // page_bytes))
     if latent:
         G -= G % 8 if G > 8 else 0
-        return G, G, _ROWS_LATENT
-    return G, (2 - G % 2 if flat else G), _ROWS
+        return G, G, _ROWS_LATENT, _RING
+    return (G, 2 - G % 2, _ROWS, 1) if flat else (G, G, _ROWS_RING, _RING)
 
 
 def _scale_folds(dh):
@@ -379,17 +412,28 @@ def _fold_latent(kv, q, m, l, acc, k0, pos, dh, cdt, *, rank, scale):
     return m_new, l, acc * alpha + pv
 
 
-def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
-                 page_size, dh, T, PP, G, F, R, flat, latent=None,
+def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, *scratch,
+                 page_size, dh, T, PP, G, F, R, K, flat, latent=None,
                  scale=None):
-    """Grid over blocks of R rows; the pool stays in HBM.  Per row a
-    loop over groups of G pages, bounded by the row's own position:
-    group g+1 (or the next row's first group) is copied into one VMEM
-    slot while group g is folded, F pages a turn, out of the other:
-    ``_fold_dense`` over the whole group at once (F = G), ``flat``
-    pools (grouped-query) two pages a turn with ``_fold_flat``,
-    ``latent`` pools (``latent`` the rank, ``scale`` the softmax's) the
-    whole group with ``_fold_latent``."""
+    """Grid over blocks of R rows; the pool stays in HBM.  A grid step's
+    work is the flat sequence of its rows' live groups of G pages, the
+    (row, group) ITEMS, bounded by each row's own position.
+
+    The ring (``K`` > 1: ``_fold_dense``; ``latent`` pools, ``latent``
+    the rank and ``scale`` the softmax's, ``_fold_latent``): item i
+    lives in VMEM slot i mod K, a buffer of its own.  A cursor copies
+    K - 1 items ahead of the fold; a trip of the loop takes K items,
+    slot by static slot: the item's copies waited for, the item K - 1 on
+    copied into the slot the item before left, the whole group folded at
+    once (F = G) FROM A FRESH (m, l, acc), and that partial merged into
+    its row's running ``state`` (m, l, acc: VMEM, R rows and a spare
+    one for the slots a block's last trip has no item for); the rows
+    are normalised and written when the grid step's items are in.
+
+    One item a trip (``flat`` pools): per row a loop over its groups,
+    group g+1 (or the next row's first) copied into one of two slots
+    while group g is folded out of the other, two pages a turn with
+    ``_fold_flat``, (m, l, acc) carried from group to group."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -401,6 +445,14 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
     row0 = pl.program_id(0) * R
     # rows of this grid step that exist (the last block may be short)
     n_rows = jnp.minimum(R, T - row0)
+    if K == 1:
+        buf, sem = scratch
+        slots = [buf]
+    else:
+        # a buffer a slot: the compiler then sees that a copy into one
+        # slot and a fold out of another touch nothing in common, and
+        # runs the scalar code of the one under the other
+        slots, sem, state = scratch[:K], scratch[K], scratch[K + 1:]
 
     def last_page(t):
         # the page that holds the row's own position: the walk's one
@@ -409,8 +461,8 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
         return jnp.minimum(pos_ref[t] // ps, PP - 1)
 
     def copy(page, slot, i):
-        return pltpu.make_async_copy(kv_hbm.at[page], buf.at[slot, i],
-                                     sem.at[slot, i])
+        dst = buf.at[slot, i] if K == 1 else slots[slot].at[i]
+        return pltpu.make_async_copy(kv_hbm.at[page], dst, sem.at[slot, i])
 
     def start(t, g, slot):
         # row t's group g: whole pages, HBM to VMEM slot ``slot``;
@@ -429,13 +481,7 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
         pos = pos_ref[t]
         last = last_page(t)
         n_groups = last // G + 1
-        if latent:
-            q = q_ref[r]                 # (H, W); the scale is the scores'
-        else:
-            q = _scaled(q_ref[r], dh)                  # (H, 2*dh)
-            if not flat:
-                # the MXU's operand: a power of two keeps q exact
-                q = q.astype(buf.dtype)
+        q = _scaled(q_ref[r], dh)                      # (H, dh)
 
         def group(g, carry):
             m, l, acc, slot = carry
@@ -458,53 +504,144 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, buf, sem, *,
                         # by its source
                         copy(0, slot, c * F + f).wait()
                 kv = buf[slot, pl.ds(c * F, F)]
-                fold = functools.partial(_fold_latent, rank=latent,
-                                         scale=scale) if latent \
-                    else _fold_flat if flat else _fold_dense
-                return fold(kv.reshape((F * ps,) + kv.shape[2:]), q,
-                            *carry, (g * G + c * F) * ps, pos, dh,
-                            q_ref.dtype)
+                return _fold_flat(kv.reshape((F * ps,) + kv.shape[2:]), q,
+                                  *carry, (g * G + c * F) * ps, pos, dh,
+                                  q_ref.dtype)
 
-            if F == G:
-                m, l, acc = turn(0, (m, l, acc))
-            else:
-                n_pages = jnp.minimum(G, last + 1 - g * G)
-                m, l, acc = jax.lax.fori_loop(0, (n_pages + F - 1) // F,
-                                              turn, (m, l, acc))
+            n_pages = jnp.minimum(G, last + 1 - g * G)
+            m, l, acc = jax.lax.fori_loop(0, (n_pages + F - 1) // F,
+                                          turn, (m, l, acc))
             return m, l, acc, 1 - slot
 
-        if latent:
-            init = (jnp.full((H, 1), -jnp.inf, f32),
-                    jnp.zeros((H, 1), f32),
-                    jnp.zeros((H, latent), f32), slot)
-        elif flat:
-            init = ((jnp.full((1, 1), -jnp.inf, f32),) * H,
-                    (jnp.zeros((1, 1), f32),) * H,
-                    (jnp.zeros((1, dh), f32),) * H, slot)
-        else:
-            init = (jnp.full((H, 1), -jnp.inf, f32),
-                    jnp.zeros((H, 1), f32),
-                    jnp.zeros((H, 2 * dh), f32), slot)
+        init = ((jnp.full((1, 1), -jnp.inf, f32),) * H,
+                (jnp.zeros((1, 1), f32),) * H,
+                (jnp.zeros((1, dh), f32),) * H, slot)
         _, l, acc, slot = jax.lax.fori_loop(0, n_groups, group, init)
-        if latent:
-            o_ref[r] = (acc / l).astype(o_ref.dtype)
-        elif flat:
-            for i in range(H):
-                o_ref[r, pl.ds(i, 1), :] = (acc[i] / l[i]).astype(
-                    o_ref.dtype)
-        else:
-            o_ref[r] = (acc[:, dh:] / l).astype(o_ref.dtype)
+        for i in range(H):
+            o_ref[r, pl.ds(i, 1), :] = (acc[i] / l[i]).astype(o_ref.dtype)
         return slot
+
+    # -- the ring's cursor: an item is (r, g, last): row r of the grid
+    # step, its group g, and the row's last page, -1 (no page) for the
+    # rows from n_rows on, which are past the last item.  Scalars by
+    # ``lax``: a trip holds the cursor's step 2 K times over, and what
+    # ``jnp`` wraps around a floor division is most of what lowering
+    # the kernel would cost --
+    lax = jax.lax
+    i32 = jnp.int32
+
+    def row_last(r):
+        # (the step's last row stands in for the read past the rows)
+        pos = pos_ref[lax.min(row0 + r, i32(T - 1))]
+        return lax.select(r < n_rows,
+                          lax.min(lax.div(pos, i32(ps)), i32(PP - 1)),
+                          i32(-1))
+
+    def after(item):
+        # a row's next group, or the next row's first
+        r, g, last = item
+        more = (g + 1) * G <= last
+        return (lax.select(more, r, r + 1), lax.select(more, g + 1, i32(0)),
+                lax.select(more, last, row_last(r + 1)))
+
+    def pages(item, each, under_chain=False):
+        # ``each(i, j)`` for the pages of the item's group that its row's
+        # position reaches: i in the group, j in the row's table.  A
+        # loop; ``under_chain`` straight-line code instead, a guard a
+        # page, which the compiler can run under the chain of the fold
+        # beside it (G times the code to lower at every start-up, so
+        # only where that pays: the copies a trip issues)
+        _, g, last = item
+        if under_chain:
+            def page(i, carry):
+                pl.when(g * G + i <= last)(lambda: each(i, g * G + i))
+                return carry
+            lax.fori_loop(0, G, page, 0, unroll=True)
+            return
+
+        def page(i, carry):
+            each(i, g * G + i)
+            return carry
+        lax.fori_loop(
+            i32(0), lax.max(lax.min(last + 1 - g * G, i32(G)), i32(0)),
+            page, 0)
+
+    def issue(item, slot, under_chain=False):
+        t = lax.min(row0 + item[0], i32(T - 1))
+        pages(item, lambda i, j: copy(bt_ref[t * PP + j], slot, i).start(),
+              under_chain)
+
+    def fold(item, slot):
+        r, g, _ = item
+        # (a wait goes by the copy's slot and size, not by its source)
+        pages(item, lambda i, j: copy(0, slot, i).wait())
+        # pages past the row's last were not copied and hold older
+        # pages (or the zeros below): finite, and masked by position;
+        # the items a block's last trip lacks fold what their slots hold
+        # (past the last item: any row's query, the state's spare row)
+        q = q_ref[lax.min(r, i32(R - 1))]
+        dst = lax.select(r < n_rows, r, i32(R))
+        if latent:
+            chain = functools.partial(_fold_latent, rank=latent,
+                                      scale=scale)
+        else:
+            # the MXU's operand: a power of two keeps q exact
+            chain, q = _fold_dense, _scaled(q, dh).astype(kv_hbm.dtype)
+        m_ref, l_ref, acc_ref = state
+        kv = slots[slot][...]
+        # from a fresh state: the chain waits for no other's result
+        m_p, l_p, acc_p = chain(
+            kv.reshape((G * ps,) + kv.shape[2:]), q,
+            jnp.full((H, 1), -jnp.inf, f32), jnp.zeros((H, 1), f32),
+            jnp.zeros(acc_ref.shape[1:], f32), g * G * ps,
+            pos_ref[lax.min(row0 + r, i32(T - 1))], dh, q_ref.dtype)
+        # the two-way softmax merge into the row's running state; a
+        # row's first group finds none
+        m_o = jnp.where(g == 0, -jnp.inf, m_ref[dst])
+        l_o = jnp.where(g == 0, 0.0, l_ref[dst])
+        acc_o = jnp.where(g == 0, 0.0, acc_ref[dst])
+        m = jnp.maximum(m_o, m_p)
+        a_o, a_p = jnp.exp(m_o - m), jnp.exp(m_p - m)
+        m_ref[dst] = m
+        l_ref[dst] = l_o * a_o + l_p * a_p
+        acc_ref[dst] = acc_o * a_o + acc_p * a_p
+
+    def trip(_, cursors):
+        # K items, slot by slot.  ``head`` is the item to fold,
+        # ``tail`` the one K - 1 on, whose copies go where the item
+        # before ``head`` was
+        head, tail = cursors
+        for slot in range(K):
+            issue(tail, (slot - 1) % K, under_chain=True)
+            fold(head, slot)
+            head, tail = after(head), after(tail)
+        return head, tail
 
     if F > 1:
         @pl.when(pl.program_id(0) == 0)
         def _():
             # what a turn may fold without having copied it must not
             # be whatever VMEM held before this call
-            buf[...] = jnp.zeros_like(buf)
+            for b in slots:
+                b[...] = jnp.zeros_like(b)
 
     start(row0, 0, 0)
-    jax.lax.fori_loop(0, n_rows, row, 0)
+    if K == 1:
+        jax.lax.fori_loop(0, n_rows, row, 0)
+        return
+    n_items = lax.fori_loop(
+        0, n_rows, lambda r, n: n + lax.div(row_last(r), i32(G)) + 1, i32(0))
+    head = (i32(0), i32(0), row_last(i32(0)))
+    tail = after(head)
+    for slot in range(1, K - 1):
+        issue(tail, slot)
+        tail = after(tail)
+    lax.fori_loop(0, lax.div(n_items + i32(K - 1), i32(K)), trip,
+                  (head, tail))
+    m_ref, l_ref, acc_ref = state
+    acc = acc_ref[pl.ds(0, R)]
+    o_ref[...] = ((acc if latent else acc[:, :, dh:])
+                  / l_ref[pl.ds(0, R)]).astype(o_ref.dtype)
 
 
 def _page_kernel(bt_ref, pos_ref, q_ref, kv_ref, *rest, page_size, dh,
@@ -602,7 +739,7 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
             "lanes a multiple of 128)"
             % (page_size, kv_dtype, 2 * dh))
     if geometry is not None:
-        G, F, R = geometry
+        G, F, R, K = geometry
         R = min(R, T)
         grid = (-(-T // R),)
         in_specs = [
@@ -611,10 +748,19 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
         ]
         out_specs = pl.BlockSpec((R, H, ow),
                                  lambda b, bt, pos: (b, 0, 0))
-        scratch = [pltpu.VMEM((2, G) + page, kv_dtype),
-                   pltpu.SemaphoreType.DMA((2, G))]
+        if K == 1:
+            scratch = [pltpu.VMEM((2, G) + page, kv_dtype),
+                       pltpu.SemaphoreType.DMA((2, G))]
+        else:
+            # the ring's slots, a buffer each; the rows' running
+            # (m, l, acc) beside them
+            scratch = [pltpu.VMEM((G,) + page, kv_dtype)] * K + [
+                pltpu.SemaphoreType.DMA((K, G)),
+                pltpu.VMEM((R + 1, H, 1), jnp.float32),
+                pltpu.VMEM((R + 1, H, 1), jnp.float32),
+                pltpu.VMEM((R + 1, H, latent or 2 * dh), jnp.float32)]
         body = functools.partial(_walk_kernel, page_size=page_size,
-                                 dh=dh, T=T, PP=PP, G=G, F=F, R=R,
+                                 dh=dh, T=T, PP=PP, G=G, F=F, R=R, K=K,
                                  flat=flat, latent=latent, scale=scale)
     else:
         grid = (T, PP)

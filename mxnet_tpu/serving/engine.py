@@ -932,7 +932,9 @@ class ServingEngine:
         ``stats["kv_pages_read"]`` over ``stats["kv_pages_window"]``
         says how much of the attention window a step read, and over
         ``stats["kv_pages_folded"]`` how much of what the walk folded
-        (whole turns of pages) was live.
+        (whole turns of pages) was live; ``stats["kv_groups_live"]``
+        over ``stats["kv_chain_slots"]`` how full the trips of the
+        walk's loop ran (K groups each).
     spec_K : in-engine speculative decode — each running decode slot
         drafts K tokens per step, the step program verifies all rows'
         drafts in ONE batched forward over the paged cache, accepted
@@ -1164,14 +1166,17 @@ class ServingEngine:
         # walk, on a pool it can cut pages out of) or reads the whole
         # (rows x pages_per_slot) window: what kv_pages_read books.
         # The walk folds whole turns of F pages: what kv_pages_folded
-        # books (0: no walk)
+        # books (0: no walk); it copies groups of G pages and takes K
+        # of them a trip of its loop within each block of R rows: what
+        # kv_groups_live and kv_chain_slots book
         from ..kernels.paged_attention import walk_geometry
         kv_heads, head_dim, flat_kv = kv_geometry(cfg)
         geometry = kernel == "pallas" and walk_geometry(
             kv_heads // tp, head_dim, page_size, pages_per_slot,
             self.cache.pools[0]["kv"].dtype, flat=flat_kv,
             latent=bool(latent_row(cfg)))
-        self._walk_turn = geometry[1] if geometry else 0
+        self._walk_group, self._walk_turn, self._walk_rows, \
+            self._walk_chains = geometry or (0, 0, 0, 0)
         # host-DRAM KV tier (round 18): explicit argument >
         # MXNET_SERVE_TIER_BYTES env > off.  0/None disables — every
         # pre-tier behavior (drop on pressure, recompute on resume)
@@ -1222,7 +1227,8 @@ class ServingEngine:
                       "swap_outs": 0, "swap_ins": 0,
                       "slot_occupancy_sum": 0.0, "overlap_steps": 0,
                       "kv_pages_window": 0, "kv_pages_read": 0,
-                      "kv_pages_folded": 0}
+                      "kv_pages_folded": 0, "kv_groups_live": 0,
+                      "kv_chain_slots": 0}
         if self._stateful:
             # slot-states read and written (the live slots of each
             # dispatched step), those started from zero, and the bytes
@@ -2075,6 +2081,15 @@ class ServingEngine:
                 plan.kv_pages = int(live.sum())
                 self.stats["kv_pages_folded"] += int(
                     ((live + F - 1) // F * F).sum())
+                # the groups that hold a live page, and the chain
+                # slots of the trips that fold them: each block of R
+                # rows rounds its groups up to whole trips of K
+                G, K = self._walk_group, self._walk_chains
+                groups = (live + G - 1) // G
+                trips = -(-np.add.reduceat(
+                    groups, np.arange(0, T, self._walk_rows)) // K)
+                self.stats["kv_groups_live"] += int(groups.sum())
+                self.stats["kv_chain_slots"] += int(trips.sum()) * K
             self.stats["kv_pages_window"] += window
             self.stats["kv_pages_read"] += plan.kv_pages
             if self._stateful:
@@ -2107,9 +2122,20 @@ class ServingEngine:
                     prev = self._inflight[1]
                 else:
                     if self._tok0 is None:
-                        self._tok0 = jnp.zeros(
+                        # committed where the pools are (``device=``),
+                        # as the steps' own output will then be: a
+                        # first step fed an uncommitted array is
+                        # lowered, and its executable fetched, a second
+                        # time when the second step brings the
+                        # committed one
+                        import jax
+                        tok0 = jnp.zeros(
                             (self.num_slots + self._n_counters,
                              1 + self.spec_K), jnp.int32)
+                        kv = self.cache.pools[0]["kv"]
+                        if self.mesh is None and kv.committed:
+                            tok0 = jax.device_put(tok0, kv.sharding)
+                        self._tok0 = tok0
                     prev = self._tok0
                 staged += [prev, jnp.asarray(buf.tok_src)]
         with profiler.span("engine.launch"):

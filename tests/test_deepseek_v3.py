@@ -398,7 +398,7 @@ def test_latent_fold_matches_reference(ps, PP, dtype, tol):
     query heads against one 64 + 16 row a token padded to 128 lanes."""
     from mxnet_tpu.kernels.paged_attention import (
         paged_attention, paged_attention_reference, walk_geometry)
-    G, F, R = walk_geometry(1, 64, ps, PP, dtype, flat=True, latent=True)
+    G, F, R, _ = walk_geometry(1, 64, ps, PP, dtype, flat=True, latent=True)
     assert F == G and (G % 8 == 0 or G == PP)
     q, pool, bt, pos = _latent_case(R + 3, 4, 64, 16, ps, PP, dtype, ps)
     edges = [0, PP * ps - 1, ps - 1, ps, G * ps - 1, min(G, PP - 1) * ps]
@@ -529,15 +529,16 @@ def test_benchmark_json_names_the_cell_and_its_metrics():
     cell = {"name": CELL, "bench": bench}
     per_layer = [m["name"] for m in chipbench_run.metrics_for(
         cell, "per_layer")]
-    assert per_layer[-3:] == ["moe_expert_bw_share.serve",
+    assert per_layer[-4:] == ["moe_expert_bw_share.serve",
                               "latent_read_bw_share.serve",
-                              "moe_rows_per_expert.serve"]
+                              "moe_rows_per_expert.serve",
+                              "kv_chain_fill_share.serve"]
     assert "ssm_state_bw_share.serve" not in per_layer
-    assert "step_mfu.serve" in per_layer and len(per_layer) == 20
+    assert "step_mfu.serve" in per_layer and len(per_layer) == 21
     assert [m["name"] for m in chipbench_run.metrics_for(
         cell, "end_to_end")] == ["setup_s", "serve_tok_s", "itl_p95_ms"]
     # each new reader is silent where the program books no such counter
-    for name in per_layer[-3:]:
+    for name in per_layer[-4:]:
         reader = chipbench_run.load_module("layer_metrics", name)
         assert reader.read({"config": {}, "device": {"kind": "TPU v5 lite"}},
                            {}, {"steps": 5, "kv_pages_read": 7},

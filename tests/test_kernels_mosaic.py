@@ -88,11 +88,13 @@ def test_paged_attention_compiles(v5e, geom, kv_dtype, q_dtype):
                      (kv_dtype == "bfloat16" and geom["H"] % 8 == 0))
     if walks:
         # the dense fold: a whole group of pages is one turn, its
-        # scores one tile (the per-page grid keeps the column fold)
-        G, F, _ = geometry
+        # scores one tile (the per-page grid keeps the column fold);
+        # four chains a trip of the loop, out of a ring of four slots
+        G, F, _, K = geometry
         assert F == G == min(geom["PP"], _GROUP_BYTES // (
             ps * geom["H"] * 2 * geom["dh"]
             * jnp.dtype(kv_dtype).itemsize))
+        assert K == 4
     if sc is None:
         _compile(lambda q, kv, bt, pos: paged_attention(
             q, kv, None, bt, pos, page_size=ps),
@@ -125,8 +127,8 @@ def test_grouped_paged_attention_compiles(v5e, geom, dtype, walks):
     assert (geometry is not None) == walks
     if walks:
         # the flat fold keeps its turns of two pages (one where the
-        # group is an odd count)
-        assert geometry[1] == 2 - geometry[0] % 2
+        # group is an odd count) and its loop: one group a trip
+        assert geometry[1] == 2 - geometry[0] % 2 and geometry[3] == 1
     _compile(lambda q, kv, bt, pos: paged_attention(
         q, kv, None, bt, pos, page_size=g["ps"]), v5e[0],
         _sds((g["T"], g["Hq"], g["dh"]), dtype),
@@ -151,10 +153,11 @@ def test_latent_paged_attention_compiles(v5e, geom, dtype):
     g = geom
     W = latent_width(g["rank"], g["rope"])
     assert W == 640
-    G, F, R = walk_geometry(1, W // 2, g["ps"], g["PP"], dtype, flat=True,
-                            latent=True)
-    # the whole group a turn, its tokens whole lane tiles of scores
-    assert F == G == (24 if dtype == "bfloat16" else g["PP"])
+    G, F, R, K = walk_geometry(1, W // 2, g["ps"], g["PP"], dtype, flat=True,
+                               latent=True)
+    # the whole group a turn, its tokens whole lane tiles of scores;
+    # four chains a trip, out of a ring of four slots
+    assert F == G == (24 if dtype == "bfloat16" else g["PP"]) and K == 4
     _compile(lambda q, kv, bt, pos: paged_attention(
         q, kv, None, bt, pos, page_size=g["ps"],
         latent=(g["rank"], g["rope"]), scale=0.14468), v5e[0],

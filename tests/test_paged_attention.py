@@ -27,11 +27,19 @@ _RTOL, _ATOL = 3e-6, 3e-6
 
 
 def _mk(T=6, H=2, dh=8, ps=4, PP=3, NP=11, int8=False, seed=0,
-        dtype="float32"):
+        dtype="float32", latent=None):
     import jax.numpy as jnp
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(T, H, dh), jnp.dtype(dtype))
-    if int8:
+    if latent:
+        # one shared row a token, [c_kv | k_pe] padded with zero lanes
+        # to whole tiles (serving/paged_kv.py latent_width); dh is
+        # rank + rope
+        from mxnet_tpu.serving.paged_kv import latent_width
+        rows = np.zeros((NP, ps, latent_width(*latent)), np.float32)
+        rows[..., :dh] = rng.randn(NP, ps, dh)
+        pool, scale = jnp.asarray(rows, jnp.dtype(dtype)), None
+    elif int8:
         pool = jnp.asarray(rng.randint(-127, 128, (NP, ps, H, 2 * dh)),
                            jnp.int8)
         # round-22 tile-shaped scale layout: (NP, 2, ps, H) planes
@@ -46,14 +54,15 @@ def _mk(T=6, H=2, dh=8, ps=4, PP=3, NP=11, int8=False, seed=0,
     return q, pool, scale, bt
 
 
-def _both(q, pool, scale, bt, pos, ps):
+def _both(q, pool, scale, bt, pos, ps, latent=None):
     import jax.numpy as jnp
     from mxnet_tpu.kernels import paged_attention as PA
     pos = jnp.asarray(pos, jnp.int32)
+    kw = dict(latent=latent, scale=0.17) if latent else {}
     out = PA.paged_attention(q, pool, scale, bt, pos, page_size=ps,
-                             interpret=True)
+                             interpret=True, **kw)
     ref = PA.paged_attention_reference(q, pool, scale, bt, pos,
-                                       page_size=ps)
+                                       page_size=ps, **kw)
     return np.asarray(out), np.asarray(ref)
 
 
@@ -205,8 +214,8 @@ def _walk_case(name):
                                  {0: "tail1", 2: "tail2", 4: "tail3"},
                                  lambda G, F: [3, 17, 7, 12, 9, 19]),
         # more rows than one grid step walks, the last block short
-        "short_last_row_block": (dict(small, T=19), two_pages, None,
-                                 lambda G, F: list(range(0, 19))),
+        "short_last_row_block": (dict(small, T=35), two_pages, None,
+                                 lambda G, F: [i % 20 for i in range(35)]),
         # the edges of the fold's own tile of scores, one turn of F
         # pages wide (the whole group under the dense fold): a row
         # that ends on the tile's last token and one that starts the
@@ -228,6 +237,52 @@ def _walk_case(name):
                  dtype="bfloat16"), None, None,
             lambda G, F: [0, F * 16 - 1, F * 16, G * 16 - 1, G * 16,
                           G * 16 + F * 16, 2 * G * 16 + 1, 319]),
+        # -- the trips of the ring's loop: a grid step's (row, group)
+        # items taken four a trip, the slots the block's last trip has
+        # no item for folded into a spare row of the state (groups a
+        # row in the comments) --
+        # seven items: one whole trip, three items and an empty slot
+        "odd_item_count": (small, two_pages, None,          # 1 2 1 1 1 1
+                           lambda G, F: [0, 9, 3, 5, 7, 2]),
+        # ten: trips that take two groups of ONE row beside groups of
+        # two others, and one that ends a row and starts the next
+        "turns_within_and_across_rows": (
+            small, two_pages, None,                         # 2 1 1 3 1 2
+            lambda G, F: [12, 3, 5, 19, 2, 9]),
+        # twelve, three whole trips: a row of four groups, begun in
+        # a trip's second slot, between rows of one
+        "four_groups_between_ones": (
+            dict(small, PP=8, NP=37), two_pages, None,      # 1 4 1 1 1 4
+            lambda G, F: [3, 31, 2, 0, 7, 30]),
+        # dead rows in a trip's second and fourth slot and as the
+        # block's last item
+        "dead_row_second_item": (small, two_pages,          # 1 1 2 1 1 1
+                                 {1: 0, 3: 0, 5: 0},
+                                 lambda G, F: [5, 0, 9, 0, 6, 0]),
+        # one row: three groups, fewer than a trip; and one group,
+        # fewer than the cursor runs ahead
+        "one_row": (dict(small, T=1), two_pages, None,
+                    lambda G, F: [19]),
+        "one_item": (dict(small, T=1), two_pages, None,
+                     lambda G, F: [2]),
+        # the last row block short, with an odd count of items (58 in
+        # the first block of 32 rows, 9 in the last six)
+        "short_last_block_odd_items": (
+            dict(small, T=38), two_pages, None,
+            lambda G, F: [(7 * i + 3) % 20 for i in range(38)]),
+        # the cell's page at the module's own G: 15 items, dead rows
+        # in neighbouring slots                     # 3 1 1 2 1 3 2 1 1
+        "cell_page_bf16_odd_items": (
+            dict(T=9, H=16, dh=64, ps=16, PP=20, NP=47,
+                 dtype="bfloat16"), None, {1: 0, 2: 0, 8: 0},
+            lambda G, F: [319, 0, 0, G * 16 + 2, 5, 2 * G * 16 + 1,
+                          G * 16, 100, 0]),
+        # a latent pool at the module's own G (128 pages of 4 KiB):
+        # seven items                                       # 1 1 2 2 1
+        "latent_bf16_own_group": (
+            dict(T=5, H=4, dh=80, ps=16, PP=150, NP=311,
+                 dtype="bfloat16", latent=(64, 16)), None, None,
+            lambda G, F: [0, G * 16 - 1, G * 16, 150 * 16 - 1, 2000]),
         "f32_h6": (dict(small, H=6), two_pages, None,
                    lambda G, F: [0, 5, 8, 9, 16, 19]),
         "f32_h3": (dict(small, H=3), two_pages, None,
@@ -253,7 +308,11 @@ def _walk_case(name):
     "group_boundary", "group_boundary_real_pages", "pos0_and_dead_rows",
     "prefill_chunk_shares_pages", "ragged_scratch_tails",
     "short_last_row_block", "turn_boundary", "dead_group_tail",
-    "cell_page_bf16", "f32_h6", "f32_h3", "bf16_h8",
+    "cell_page_bf16", "odd_item_count", "turns_within_and_across_rows",
+    "four_groups_between_ones", "dead_row_second_item", "one_row",
+    "one_item",
+    "short_last_block_odd_items", "cell_page_bf16_odd_items",
+    "latent_bf16_own_group", "f32_h6", "f32_h3", "bf16_h8",
     "bf16_h6_per_page", "bf16_h3_per_page", "int8_per_page",
     "int8_h6_per_page"])
 def test_walk_cases(name, monkeypatch):
@@ -272,9 +331,18 @@ def test_walk_cases(name, monkeypatch):
                             group_pages * pool[0].nbytes)
     # built calls are cached by shape, not by the group size
     monkeypatch.setattr(PA, "_call_cache", {})
-    geometry = PA.walk_geometry(mk["H"], mk["dh"], ps, PP, pool.dtype)
+    latent = mk.get("latent")
+    if latent:
+        geometry = PA.walk_geometry(1, pool.shape[-1] // 2, ps, PP,
+                                    pool.dtype, flat=True, latent=True)
+    else:
+        geometry = PA.walk_geometry(mk["H"], mk["dh"], ps, PP, pool.dtype)
     assert (geometry is None) == name.endswith("_per_page")
     G, F = geometry[:2] if geometry else (1, 1)
+    if geometry:
+        # every pool here is folded a whole group at once, out of a
+        # ring of four slots
+        assert F == G and geometry[3] == 4
     if group_pages is not None:
         assert G == group_pages  # several groups inside the table
     elif geometry:
@@ -289,31 +357,45 @@ def test_walk_cases(name, monkeypatch):
             bt[r, int(how[4:]):] = 0
     pos = positions(G, F)
     assert len(pos) == mk["T"] and max(pos) < PP * ps
-    out, ref = _both(q, pool, scale, jnp.asarray(bt), pos, ps)
+    out, ref = _both(q, pool, scale, jnp.asarray(bt), pos, ps, latent)
     tol = dict(rtol=2e-2, atol=2e-2) if mk.get("dtype") == "bfloat16" \
         else dict(rtol=_RTOL, atol=_ATOL)
     np.testing.assert_allclose(out, ref, **tol)
 
 
-@pytest.mark.parametrize("PP,F", [(5, 5), (6, 6), (9, 9)])
-def test_walk_reads_no_page_past_the_position(PP, F):
+@pytest.mark.parametrize("PP,G,pos", [
+    (5, 5, [0, 3, 4, 13]), (6, 6, [0, 3, 4, 13]), (9, 9, [0, 3, 4, 13]),
+    # groups of two pages: 11 items go round the ring of four slots
+    # nearly three times, every slot refilled under a row that ends
+    # inside its group                                    # 1 3 1 4 1 1
+    (9, 2, [0, 17, 4, 26, 7, 1]),
+    # groups of three: 9 items, an odd count            # 2 1 3 1 1 1
+    (9, 3, [13, 3, 35, 0, 9, 8]),
+])
+def test_walk_reads_no_page_past_the_position(PP, G, pos, monkeypatch):
     """Pages past a row's position are never copied: with NaN in every
     page a row must not see, the walk's output is finite and unchanged
     (the gather reference, which reads the whole window, is the one
     that would carry them through a 0 x NaN) — though the dense fold
-    runs over the whole group, pages it did not copy among them."""
+    runs over the whole group, pages it did not copy among them, and
+    the ring's four slots hold what earlier rows left there."""
     import jax.numpy as jnp
     from mxnet_tpu.kernels import paged_attention as PA
-    ps = 4
-    q, pool, scale, bt = _mk(T=4, ps=ps, PP=PP, NP=1 + 4 * PP)
-    assert PA.walk_geometry(2, 8, ps, PP, pool.dtype)[:2] == (PP, F)
-    bt = np.arange(1, 1 + 4 * PP, dtype=np.int32).reshape(4, PP)
-    pos = np.asarray([0, 3, 4, 13], np.int32)
+    ps, T = 4, len(pos)
+    q, pool, scale, bt = _mk(T=T, ps=ps, PP=PP, NP=1 + T * PP)
+    monkeypatch.setattr(PA, "_GROUP_BYTES",
+                        min(G * pool[0].nbytes, PA._GROUP_BYTES))
+    monkeypatch.setattr(PA, "_call_cache", {})
+    # the whole group at once, out of a ring of four slots
+    assert PA.walk_geometry(2, 8, ps, PP, pool.dtype) \
+        == (G, G, PA._ROWS_RING, 4)
+    bt = np.arange(1, 1 + T * PP, dtype=np.int32).reshape(T, PP)
+    pos = np.asarray(pos, np.int32)
     clean = PA.paged_attention(q, pool, None, jnp.asarray(bt),
                                jnp.asarray(pos), page_size=ps,
                                interpret=True)
     dirty = np.asarray(pool).copy()
-    for r in range(4):
+    for r in range(T):
         dirty[bt[r, pos[r] // ps + 1:]] = np.nan
     out = PA.paged_attention(q, jnp.asarray(dirty), None,
                              jnp.asarray(bt), jnp.asarray(pos),

@@ -250,6 +250,21 @@ def test_kv_pages_counters(kernel, kv_int8):
         assert eng.stats["kv_pages_read"] == window
 
 
+def _run_counting(eng, book):
+    """Serve four requests with ``book(plan)`` called on every plan
+    that is dispatched, before its dispatch."""
+    dispatch = eng._dispatch
+
+    def counting(plan):
+        book(plan)
+        return dispatch(plan)
+    eng._dispatch = counting
+    rng = np.random.RandomState(0)
+    for P, N in [(5, 8), (3, 12), (9, 4), (2, 6)]:
+        eng.submit(rng.randint(1, 90, P).astype(np.int32), N)
+    eng.run()
+
+
 @pytest.mark.parametrize("kernel,page_size,kv_int8", [
     ("pallas", 4, False), ("pallas", 64, False), ("xla", 4, False),
     ("pallas", 4, True)])
@@ -275,18 +290,12 @@ def test_kv_pages_folded_counter(kernel, page_size, kv_int8):
                       eng.pages_per_slot, "float32")[1]
     assert F == eng.pages_per_slot == 64 // page_size
     folded, seen = [], []
-    dispatch = eng._dispatch
 
-    def counting(plan):
+    def book(plan):
         live = plan.buf.row_pos // page_size + 1
         folded.append(int(((live + F - 1) // F * F).sum()))
         seen.append(eng.stats["kv_pages_folded"])
-        return dispatch(plan)
-    eng._dispatch = counting
-    rng = np.random.RandomState(0)
-    for P, N in [(5, 8), (3, 12), (9, 4), (2, 6)]:
-        eng.submit(rng.randint(1, 90, P).astype(np.int32), N)
-    eng.run()
+    _run_counting(eng, book)
     assert len(folded) > 8
     if not walks:
         assert eng.stats["kv_pages_folded"] == 0
@@ -298,6 +307,67 @@ def test_kv_pages_folded_counter(kernel, page_size, kv_int8):
         assert eng.stats["kv_pages_folded"] == eng.stats["kv_pages_read"]
     else:
         assert eng.stats["kv_pages_folded"] > eng.stats["kv_pages_read"]
+
+
+@pytest.mark.parametrize("kernel,page_size,kv_int8,group_pages", [
+    ("pallas", 4, False, None), ("pallas", 64, False, None),
+    ("pallas", 4, False, 2), ("xla", 4, False, None),
+    ("pallas", 4, True, None)])
+def test_kv_chain_counters(kernel, page_size, kv_int8, group_pages,
+                           monkeypatch):
+    """``kv_groups_live`` books, per dispatched step, the groups of G
+    pages that hold a live page; ``kv_chain_slots`` those rounded up,
+    block of R rows by block, to the whole trips of K chains that the
+    walk's loop makes (G, R and K from ``walk_geometry``): at least the
+    live groups, the same share wherever a row is one group (16 pages
+    of 4 tokens or one of 64), and nothing where no walk runs — the
+    gather path, the per-page grid on an int8 pool."""
+    import jax
+    from mxnet_tpu.kernels import paged_attention as PA
+    from mxnet_tpu.models import transformer as T
+    from mxnet_tpu.serving import ServingEngine
+
+    cfg = _cfg()
+    params = T.init_params(jax.random.PRNGKey(3), cfg)
+    if group_pages:
+        # groups of two pages: rows of up to eight groups
+        monkeypatch.setattr(PA, "_GROUP_BYTES", group_pages * page_size
+                            * 2 * cfg.d_model * 4)
+        monkeypatch.setattr(PA, "_call_cache", {})
+    eng = ServingEngine(params, cfg, num_slots=3, page_size=page_size,
+                        prefill_chunk=6, kernel=kernel, kv_int8=kv_int8)
+    walks = kernel == "pallas" and not kv_int8
+    G, _, R, K = PA.walk_geometry(
+        cfg.n_heads, cfg.d_model // cfg.n_heads, page_size,
+        eng.pages_per_slot, "float32")
+    assert G == (group_pages or eng.pages_per_slot) and K == 4
+    live, slots, seen = [], [], []
+
+    def book(plan):
+        groups = -(-(plan.buf.row_pos // page_size + 1) // G)
+        live.append(int(groups.sum()))
+        slots.append(sum(-(-int(groups[b:b + R].sum()) // K) * K
+                         for b in range(0, len(groups), R)))
+        seen.append((eng.stats["kv_groups_live"],
+                     eng.stats["kv_chain_slots"]))
+    _run_counting(eng, book)
+    assert len(live) > 8
+    if not walks:
+        assert eng.stats["kv_groups_live"] == 0
+        assert eng.stats["kv_chain_slots"] == 0
+        return
+    # booked step by step, with the plan that is dispatched
+    assert seen == list(zip(np.cumsum(live), np.cumsum(slots)))
+    assert (eng.stats["kv_groups_live"], eng.stats["kv_chain_slots"]) \
+        == (sum(live), sum(slots))
+    assert sum(live) <= sum(slots)
+    rows = 3 + 6                        # the step's rows: slots + chunk
+    if group_pages:
+        assert sum(live) > rows * len(live)         # rows of several
+    else:
+        # one group a row, whatever the page: the same share
+        assert sum(live) == rows * len(live)
+        assert sum(slots) == -(-rows // K) * K * len(live)
 
 
 def _counter_reader(metric):
@@ -340,6 +410,19 @@ def test_kv_fold_live_share_reader():
     assert read({"kv_pages_read": 1380, "kv_pages_folded": 1380}) == 100.0
     assert read({"kv_pages_read": 1380, "kv_pages_folded": 1460}) == \
         pytest.approx(94.52, abs=0.01)
+
+
+def test_kv_chain_fill_share_reader():
+    """The reader of ``kv_chain_fill_share.serve``: ``kv_groups_live``
+    over ``kv_chain_slots`` in percent; nothing, without raising, from
+    a program that books no such counters (the parent commit) or from
+    a window in which no step walked (the gather path books 0)."""
+    read = _counter_reader("kv_chain_fill_share.serve")
+    assert read({"kv_pages_read": 1385, "kv_pages_folded": 1460}) is None
+    assert read({"kv_groups_live": 0, "kv_chain_slots": 0}) is None
+    assert read({"kv_groups_live": 260, "kv_chain_slots": 260}) == 100.0
+    assert read({"kv_groups_live": 257, "kv_chain_slots": 272}) == \
+        pytest.approx(94.49, abs=0.01)
 
 
 @pytest.mark.slow
