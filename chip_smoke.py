@@ -16,8 +16,9 @@ own means:
   platform (pipelined on a TPU) against the other one, which it is given
   by substituting that observation while it is built, the attention
   lowering held to the engine's own: identical tokens, for the ``full``
-  preset as deployed and for Falcon-H1 (a recurrent state per slot) at the
-  benchmark configuration's rehearsal size, more requests than slots.
+  preset as deployed and, at the benchmark configurations' rehearsal
+  sizes, for Falcon-H1 (a recurrent state per slot) and LFM2-MoE (windows
+  beside pages, routed experts), more requests than slots.
 * ``kernels``        — the three Pallas kernels, each proven COMPILED (a
   ``tpu_custom_call`` in the compiled program) and compared on the chip
   with its plain-jnp reference.
@@ -362,22 +363,27 @@ def leg_serve_full(ctx):
 
 # ----------------------------------------------------- serve_schedules ---
 
-def _falcon_toy():
-    """Falcon-H1 at the toy size of the benchmark's configuration file
-    (its ``rehearse`` group: the published multipliers and flags, two
-    layers), seeded weights, the configuration's own dtype; one chunk
-    holds a prompt of every slot, so that a request's prompt is one
-    chunk whichever step admits it (the chunked scan sums in another
-    order than the step-by-step one)."""
+def _family_toy(family):
+    """A family the engine serves beside ``TransformerConfig`` — Falcon-H1
+    (a recurrent state per slot in every layer) or LFM2-MoE (layers that
+    keep a window beside layers that keep pages, routed experts) — at
+    the toy size of the benchmark's configuration file (its ``rehearse``
+    group: the published multipliers and flags, a few layers), seeded
+    weights, the configuration's own dtype; one chunk holds a prompt of
+    every slot, so that a request's prompt is one chunk whichever step
+    admits it (the chunked scan sums in another order than the
+    step-by-step one)."""
+    import importlib
     import jax
-    from mxnet_tpu.models import falcon_h1
+    config, file = {"falcon_h1": ("FalconH1Config", "falcon_h1_34b_l6"),
+                    "lfm2_moe": ("Lfm2MoeConfig", "lfm2_8b_a1b_l12")}[family]
+    model = importlib.import_module("mxnet_tpu.models." + family)
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                           "chipbench", "configs",
-                           "falcon_h1_34b_l6.json")) as f:
+                           "chipbench", "configs", file + ".json")) as f:
         c = json.load(f)
     toy = dict(c, **c["rehearse"])
-    cfg = falcon_h1.FalconH1Config.from_hf(toy, dtype=c["dtype"])
-    params = falcon_h1.init_params(jax.random.PRNGKey(0), cfg)
+    cfg = getattr(model, config).from_hf(toy, dtype=c["dtype"])
+    params = model.init_params(jax.random.PRNGKey(0), cfg)
     engine = dict(toy["engine"])
     longest = 24
     engine["prefill_chunk"] = engine["num_slots"] * longest
@@ -425,15 +431,16 @@ def leg_serve_schedules(ctx):
                 sum(n for _, n in prompts)))
     del params
 
-    params, cfg, engine, prompts = _falcon_toy()
-    gen = {ov: _serve(ctx, params, cfg, prompts, pools_seen_on=plat,
-                      kernel=kernel, **engine)
-           for ov, plat in both.items()}
-    _identical("falcon_h1 serial vs pipelined", gen[False], gen[True])
-    ctx.note("falcon_h1 (toy, %s): serial and pipelined token-identical "
-             "on %d requests over %d slots, %d tokens"
-             % (cfg.dtype, len(prompts), engine["num_slots"],
-                sum(n for _, n in prompts)))
+    for family in ("falcon_h1", "lfm2_moe"):
+        params, cfg, engine, prompts = _family_toy(family)
+        gen = {ov: _serve(ctx, params, cfg, prompts, pools_seen_on=plat,
+                          kernel=kernel, **engine)
+               for ov, plat in both.items()}
+        _identical(family + " serial vs pipelined", gen[False], gen[True])
+        ctx.note("%s (toy, %s): serial and pipelined token-identical "
+                 "on %d requests over %d slots, %d tokens"
+                 % (family, cfg.dtype, len(prompts), engine["num_slots"],
+                    sum(n for _, n in prompts)))
 
 
 # ------------------------------------------------------------- kernels ---

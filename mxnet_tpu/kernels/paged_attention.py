@@ -40,9 +40,9 @@ a second copy of the loop's body to trace and lower at every
 start-up).  G, F, R and K follow from the shapes
 (``walk_geometry``): for the cell's bf16 pool of 16 heads of 64,
 pages are 64 KiB, G = 8 (four 512 KiB slots), F = G, R = 32, K = 4.
-The flat fold keeps the older loop: per row a ``fori_loop`` over its
-groups, one group copied ahead into the other of two slots, the state
-carried from group to group.
+The flat column fold keeps the older loop: per row a ``fori_loop`` over
+its groups, one group copied ahead into the other of two slots, the
+state carried from group to group.
 
 **The dense fold** (``_fold_dense``, PR 31) is what the walk folds a
 ``(ps, H, 2*dh)`` pool with, a whole group a turn.  A page's rows,
@@ -66,7 +66,13 @@ dims would be no whole tiles; the flat page is, so the same walk cuts
 it out of HBM with the same copies.  ``_fold_flat`` is the same
 recurrence with the roles of the axes turned: a head's scores are a
 column over the page's tokens, its k and v whole lane tiles read by
-each of the ``Hq / Hkv`` query heads that share them.
+each of the ``Hq / Hkv`` query heads that share them.  Where a head's
+``[k | v]`` pair is ONE lane tile (heads of 64, PR 36: there the
+column fold read 80 GB/s, a lane reduction a query head a turn of two
+pages under 32 query heads) the flat pool goes through the ring with
+the dense form, ``_fold_flat_dense``: a pair the MXU's weights as it
+lies in VMEM, every query head against it, the rows of the heads that
+share it kept; the scores one tile, heads by tokens.
 
 **Latent pools** (PR 32).  Multi-head latent attention caches ONE row a
 token, ``[c_kv (rank) | rotated k_pe (rope)]``, shared by every query
@@ -211,6 +217,11 @@ def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False,
       key/value heads): whole tiles when the tokens fill the sublanes
       (8 rows of 32 bits, 16 of 16) and the heads' lanes are a
       multiple of 128 — 4 heads of 128 in bf16 at 16-token pages walk.
+      Where a head's ``[k | v]`` pair is ONE lane tile (heads of 64:
+      PR 36) the flat pool takes the ring and the dense form of its
+      fold (``_fold_flat_dense``); at heads of 128 the column fold and
+      its loop stay until a PR measures the switch in the cell that
+      runs it (ROADMAP A12 (b)).
 
     ``latent``: a flat pool of one shared row a token (``H`` 1,
     ``2*dh`` the padded row): the flat page's rule; the whole group is
@@ -234,7 +245,15 @@ def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False,
     if latent:
         G -= G % 8 if G > 8 else 0
         return G, G, _ROWS_LATENT, _RING
-    return (G, 2 - G % 2, _ROWS, 1) if flat else (G, G, _ROWS_RING, _RING)
+    if flat and 2 * dh != 128:
+        return G, 2 - G % 2, _ROWS, 1
+    return G, G, _ROWS_RING, _RING
+
+
+def _ring(geometry):
+    """Whether the walk of this geometry folds whole groups out of the
+    ring (its folds take the query zero-extended over the v lanes)."""
+    return geometry is not None and geometry[3] > 1
 
 
 def _scale_folds(dh):
@@ -383,6 +402,54 @@ def _fold_flat(kv, q, m, l, acc, k0, pos, dh, cdt):
                     + jnp.sum(p * v, axis=0, keepdims=True))     # (1, dh)
         m2.append(m_new)
     return tuple(m2), tuple(l2), tuple(acc2)
+
+
+def _fold_flat_dense(kv, q, m, l, acc, k0, pos, dh, cdt):
+    """``_fold_dense`` for a grouped-query page in the flat layout whose
+    heads' ``[k | v]`` pairs are whole lane tiles: ``kv`` (n, Hkv*2*dh)
+    a group's pages as the pool holds them, ``q`` (Hq, 2*dh) the row's
+    ``_scaled`` queries in the pool's dtype, zero-extended over the v
+    half; ``m`` / ``l`` (Hq, 1) and ``acc`` (Hq, 2*dh) float32.
+
+    A key/value head's pair ``kv[:, j]`` (n, 2*dh) is the MXU's weights
+    as it lies in VMEM, cut out on tile boundaries: EVERY query head
+    against it is one product (Hq, n), of which the rows of the heads
+    that share pair j are kept; the scores are one tile, query heads on
+    the sublanes, tokens along the lanes, and mask, max, exp, sums and
+    the round of p run once on it; p, zeroed outside group j, times the
+    pair is that group's v sum (and p.k on the k half, finite and never
+    read).  Hkv times the products the scores need, on a unit that a
+    handful of query rows leaves idle otherwise; no lane reduction a
+    token and head, no slice inside a tile, no page cast to f32."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    n, W = kv.shape
+    Hq = q.shape[0]
+    Hkv = W // (2 * dh)
+    prec = jax.lax.Precision.HIGHEST if kv.dtype.itemsize == 4 else None
+    group = jax.lax.broadcasted_iota(jnp.int32, (Hq, 1), 0) // (Hq // Hkv)
+    pairs = [kv[:, j * 2 * dh:(j + 1) * 2 * dh] for j in range(Hkv)]
+    s = jnp.zeros((Hq, n), f32)
+    for j, pair in enumerate(pairs):
+        s = jnp.where(group == j, jax.lax.dot_general(
+            q, pair, (((1,), (1,)), ((), ())),
+            preferred_element_type=f32, precision=prec), s)
+    if not _scale_folds(dh):
+        s = s / jnp.sqrt(f32(dh))
+    tok = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(k0 + tok <= pos, s, -1e30)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))   # (Hq, 1)
+    p = jnp.exp(s - m_new)                                      # (Hq, n)
+    alpha = jnp.exp(m - m_new)
+    l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+    p = p.astype(cdt)
+    pv = jnp.zeros(acc.shape, f32)
+    for j, pair in enumerate(pairs):
+        pv = pv + jax.lax.dot_general(
+            jnp.where(group == j, p, 0), pair, (((1,), (0,)), ((), ())),
+            preferred_element_type=f32, precision=prec)
+    return m_new, l, acc * alpha + pv
 
 
 def _fold_latent(kv, q, m, l, acc, k0, pos, dh, cdt, *, rank, scale):
@@ -586,7 +653,8 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, *scratch,
                                       scale=scale)
         else:
             # the MXU's operand: a power of two keeps q exact
-            chain, q = _fold_dense, _scaled(q, dh).astype(kv_hbm.dtype)
+            chain = _fold_flat_dense if flat else _fold_dense
+            q = _scaled(q, dh).astype(kv_hbm.dtype)
         m_ref, l_ref, acc_ref = state
         kv = slots[slot][...]
         # from a fresh state: the chain waits for no other's result
@@ -726,11 +794,11 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
     # a page as the pool holds it, and a row's queries: zero-extended
     # over the v lanes for the (H, 2*dh) fold, bare for the flat one
     page = (page_size, Hkv * 2 * dh) if flat else (page_size, H, 2 * dh)
-    qw = 2 * dh if latent or not flat else dh
     ow = latent or dh
     zeros = (0,) * len(page)
     geometry = walk_geometry(Hkv if flat else H, dh, page_size, PP,
                              kv_dtype, flat=flat, latent=bool(latent))
+    qw = 2 * dh if latent or not flat or _ring(geometry) else dh
     if latent and geometry is None:
         raise ValueError(
             "paged_attention: a latent pool is folded by the page walk "
@@ -884,11 +952,14 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
                          % (pool_kv.shape[2], H))
     # q zero-extended over the v half of a page's lanes (the kernel's
     # one full-width product then contracts q with k alone); the flat
-    # fold cuts k out of the page and takes q as it is
+    # column fold cuts k out of the page and takes q as it is (the
+    # latent query was padded to the row above)
+    bare = latent or (Hkv and not _ring(walk_geometry(
+        Hkv, dh, page_size, PP, pool_kv.dtype, flat=True)))
     args = [block_tables.reshape(-1).astype(jnp.int32),
             row_pos.astype(jnp.int32),
-            q if Hkv else jnp.concatenate([q, jnp.zeros_like(q)],
-                                          axis=-1), pool_kv]
+            q if bare else jnp.concatenate([q, jnp.zeros_like(q)],
+                                           axis=-1), pool_kv]
     if int8:
         args.append(pool_s)
 

@@ -302,13 +302,14 @@ STEP_COUNTERS = ("moe_pairs", "moe_experts_hit")
 
 class StepCounts:
     """What the expert layers of one call count over its live rows:
-    ``live`` (T,) bool in, ``counts`` (the ``STEP_COUNTERS``, int32
-    scalars summed over the layers) out."""
+    ``live`` (T,) bool in, ``counts`` (one int32 scalar per entry of
+    ``names``, summed over the layers) out."""
+    names = STEP_COUNTERS
 
     def __init__(self, live):
         import jax.numpy as jnp
         self.live = live
-        self.counts = [jnp.zeros((), jnp.int32) for _ in STEP_COUNTERS]
+        self.counts = [jnp.zeros((), jnp.int32) for _ in self.names]
 
     def add(self, *counts):
         self.counts = [a + b for a, b in zip(self.counts, counts)]
@@ -343,7 +344,7 @@ def _experts(layer, cfg, m, counts):
             top_k=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
             scale=cfg.routed_scaling_factor)
     with jax.named_scope("moe_experts"):
-        y, pairs, hit = held_experts_ffn(
+        y, pairs, hit, _ = held_experts_ffn(
             m.astype(cdt), layer["ew_gate"].astype(cdt),
             layer["ew_up"].astype(cdt), layer["ew_down"].astype(cdt),
             idx, w, held_first=cfg.held_first, live=counts.live)
@@ -445,11 +446,13 @@ def _block(layer, cfg, x, attention, counts):
     return (h.astype(jnp.float32) + y).astype(cdt)
 
 
-def serve_block(layer, cfg, x, row_pos, attend, counts):
+def serve_block(layer, cfg, x, row_pos, attend, state, counts):
     """One block on (T, D) rows at positions ``row_pos``.
     ``attend(q (T, H, rank + rope), row (T, rank + rope))`` writes each
     row's cache row and returns its heads' read-back in latent space,
-    (T, H, rank) float32; ``counts`` is the call's ``StepCounts``."""
+    (T, H, rank) float32; ``counts`` is the call's ``StepCounts``.
+    ``state`` is the slot state of families that keep one; this one
+    keeps pages alone."""
     return _block(layer, cfg, x, lambda u: _attn_absorbed(
         layer, cfg, u, row_pos, attend), counts)
 
@@ -503,7 +506,7 @@ def forward(params, cfg, tokens, absorbed=True):
     x = serve_embed(params, cfg, tokens.reshape(-1), row_pos)
     for layer in params["layers"]:
         if absorbed:
-            x = serve_block(layer, cfg, x, row_pos, attend, counts)
+            x = serve_block(layer, cfg, x, row_pos, attend, None, counts)
         else:
             x = _block(layer, cfg, x, lambda u, layer=layer:
                        _attn_expanded(layer, cfg, u, row_pos, B), counts)

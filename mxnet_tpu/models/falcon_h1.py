@@ -54,7 +54,7 @@ from typing import Tuple
 
 __all__ = ["FalconH1Config", "init_params", "forward", "serve_embed",
            "serve_block", "serve_logits", "slot_state_shapes",
-           "SlotState", "slot_scan", "slot_conv"]
+           "layer_cache", "SlotState", "slot_scan", "slot_conv"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -359,8 +359,14 @@ def slot_state_shapes(cfg):
                     "float32")}
 
 
+def layer_cache(cfg):
+    """What each layer keeps between calls, ``(pages, slot state)`` a
+    layer: K/V pages and both states in every one."""
+    return [(True, slot_state_shapes(cfg))] * cfg.n_layers
+
+
 class SlotState:
-    """The slot-state backend of one layer in one call: the layer's two
+    """The slot-state backend of one layer in one call: the layer's
     state pools, which slot each row belongs to and which slots start
     from zero.  ``conv`` and ``scan`` compute over the rows and replace
     ``pools`` by the updated ones."""
@@ -424,11 +430,12 @@ def _mixer(layer, cfg, u, state):
         return _mm(y, layer["out_proj"], cdt)
 
 
-def serve_block(layer, cfg, x, row_pos, attend, state):
+def serve_block(layer, cfg, x, row_pos, attend, state, counts=None):
     """One parallel block on (T, D) rows at positions ``row_pos``.
     ``attend(q (T, Hq, dh), k, v (T, Hkv, dh))`` returns each row's
     attention over its own sequence, (T, Hq, dh) float32; ``state`` is
-    the slot-state backend (``SlotState``)."""
+    the slot-state backend (``SlotState``).  ``counts`` is the step
+    counters of families that count; this one counts nothing."""
     import jax
     import jax.numpy as jnp
     cdt = jnp.dtype(cfg.dtype)

@@ -274,10 +274,12 @@ def serve_embed(params, cfg, tokens, row_pos):
                          params["emb_ln"]["b"].astype(cdt))
 
 
-def serve_block(layer, cfg, x, row_pos, attend, state=None):
+def serve_block(layer, cfg, x, row_pos, attend, state=None, counts=None):
     """One post-LN block on (T, D) rows; ``attend(q, k, v)`` over
     (T, H, dh) each returns (T, H, dh) float32.  ``state`` is the slot
-    state of families that keep one; this one keeps pages alone."""
+    state of families that keep one and ``counts`` the step counters of
+    families that count; this one keeps pages alone and counts
+    nothing."""
     import jax
     import jax.numpy as jnp
     cdt = jnp.dtype(cfg.dtype)

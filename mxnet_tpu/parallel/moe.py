@@ -181,14 +181,15 @@ def moe_ffn(x, params, *, n_experts, top_k=2, capacity_factor=1.25,
 # ------------------------------------------------- the serving form ---
 
 def route_group_limited(scores, bias, *, n_group, topk_group, top_k,
-                        norm_topk_prob=True, scale=1.0):
+                        norm_topk_prob=True, scale=1.0, eps=1e-20):
     """DeepSeek-V3's ``noaux_tc`` choice over ``scores`` (T, E) float32
     (the sigmoid of the router's logits): for choosing only,
     ``s' = scores + bias``; a group's score is the sum of its two best
     ``s'`` (``n_group`` groups of ``E / n_group`` consecutive experts),
     the best ``topk_group`` groups stay, and the ``top_k`` best ``s'``
-    among them are chosen.  The weights are the chosen SCORES (not
-    ``s'``), divided by their sum where ``norm_topk_prob``, times
+    among them are chosen (``n_group`` 1: the ``top_k`` best of all).
+    The weights are the chosen SCORES (not ``s'``), divided by their sum
+    plus ``eps`` (the family's constant) where ``norm_topk_prob``, times
     ``scale``.  Returns ``(idx (T, top_k) int32, w (T, top_k) float32)``
     over all E experts, whoever holds them."""
     import jax
@@ -205,7 +206,7 @@ def route_group_limited(scores, bias, *, n_group, topk_group, top_k,
     _, idx = jax.lax.top_k(choice, top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return idx.astype(jnp.int32), w * scale
 
 
@@ -220,8 +221,12 @@ def _grouped_dot(x, w, sizes):
     (the rows after the last group are whatever the kernel leaves
     there).  Float32 out.  On a TPU ``megablox.gmm``: row tiles of 128,
     weight tiles of up to 1024 x 1024 (2 MiB in bfloat16, the copy that
-    binds a step with a handful of rows an expert), a grid over the
-    ACTIVE row tiles only; the XLA ``ragged_dot`` on the CPU."""
+    binds a step with a handful of rows an expert), each side the
+    largest multiple of 128 lanes that divides it (1,024 of 2,048 or
+    7,168; 896 of 1,792 = 7 x 256, where powers of two alone gave
+    256-wide tiles at 365 GB/s against 484: my chip runs, PR 36), a
+    grid over the ACTIVE row tiles only; the XLA ``ragged_dot`` on the
+    CPU."""
     import jax
     import jax.numpy as jnp
     from ..kernels.platform import run_kernel
@@ -233,9 +238,9 @@ def _grouped_dot(x, w, sizes):
             return lambda x, w, sizes: jax.lax.ragged_dot(
                 x, w, sizes, preferred_element_type=jnp.float32)
         from jax.experimental.pallas.ops.tpu.megablox import gmm
-        tiling = (_tile(M, (128, 64, 32, 16, 8)),
-                  _tile(K, (1024, 512, 256, 128)),
-                  _tile(N, (1024, 512, 256, 128)))
+        lanes = range(1024, 0, -128)
+        tiling = (_tile(M, (128, 64, 32, 16, 8)), _tile(K, lanes),
+                  _tile(N, lanes))
         return lambda x, w, sizes: gmm(x, w, sizes, jnp.float32, tiling)
 
     return run_kernel(build, x, w, sizes)
@@ -251,9 +256,10 @@ def held_experts_ffn(x, w_gate, w_up, w_down, idx, w, *, held_first,
     ``w`` (T, k) each row's chosen experts and weights over ALL experts
     (``route_group_limited``); ``live`` (T,) bool: rows whose pairs are
     dispatched (a dead row of a fixed-shape step is none of the
-    traffic).  Returns ``(y (T, D) float32, pairs, hit)``:
+    traffic).  Returns ``(y (T, D) float32, pairs, hit, sizes)``:
     ``y = sum over a row's HELD choices of w_k E_k(x)``, the number of
-    row-expert pairs dispatched and of held experts with at least one.
+    row-expert pairs dispatched, of held experts with at least one, and
+    each held expert's pairs, (E_held,) int32.
 
     The ``T x k`` pairs are a static bound, so nothing is dropped and
     no capacity is set: the pairs are sorted by held expert (those of
@@ -284,4 +290,4 @@ def held_experts_ffn(x, w_gate, w_up, w_down, idx, w, *, held_first,
     out = jnp.where(held.reshape(-1, 1), out[back], 0.0)
     y = jnp.sum(out.reshape(T, K, D)
                 * jnp.where(held, w, 0.0).astype(f32)[..., None], axis=1)
-    return y, jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32)
+    return y, jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32), sizes

@@ -109,9 +109,15 @@ from the config it is given — no option selects it — and supplies what a
 block keeps between steps: ``attend(q, k, v)``, which writes the rows'
 keys and values into their pages and reads each row's sequence back
 through the block table, and, for a family whose sequences keep state
-that is no page (``slot_state_shapes``: Falcon-H1's convolution window
-and SSM state), a SLOT-STATE backend over one ``(num_slots + 1, ...)``
-pool per layer beside the pages (``serving/paged_kv.py``).  A slot's
+that is no page (a convolution window, an SSM state), a SLOT-STATE
+backend over one ``(num_slots + 1, ...)`` pool per layer and name
+(``serving/paged_kv.py``).  What a layer keeps is the module's to say,
+layer by layer (``layer_cache``): pages, slot state, both (a parallel
+block) or one of them (an operator that attends beside one that
+convolves).  Every block is handed the attention backend, its layer's
+slot-state backend (None where the layer keeps none) and the step's
+counters together and uses what it needs; the layer's new pools are
+collected from whichever ran.  A slot's
 state starts from zero at the slot's first prefill chunk — the planner
 fills a per-slot ``fresh`` mask, staged with the rows, and the program
 masks the pool's old content as it reads it: no dispatch of its own —
@@ -170,8 +176,8 @@ import numpy as np
 from .. import profiler
 from ..models import gpt as G
 from . import drafters
-from .paged_kv import (PagedKVCache, kv_geometry, latent_row,
-                       slot_state_shapes, write_latent, write_rows)
+from .paged_kv import (PAGE_LEAVES, PagedKVCache, kv_geometry, latent_row,
+                       layer_cache, write_latent, write_rows)
 from .prefix_cache import PrefixCache
 from .tier_store import HostTierStore
 
@@ -207,18 +213,10 @@ def step_input_specs(params, cfg, kv_int8, tp="tp", overlap=False):
     from jax.sharding import PartitionSpec as P
 
     from ..models import gpt as G
-    from .paged_kv import PagedKVCache
 
-    pool_spec = P(*[tp if a == "tp" else a
-                    for a in PagedKVCache.POOL_SPEC])
-    pool = {"kv": pool_spec}
-    if kv_int8:
-        pool["s"] = P(*[tp if a == "tp" else a
-                        for a in PagedKVCache.S_POOL_SPEC])
     rep = P()
     out = (G.decode_param_specs(params, cfg, tp=tp),
-           [dict(pool) for _ in range(cfg.n_layers)],
-           rep, rep, rep, rep, rep, rep)
+           _pool_specs(cfg, kv_int8, tp), rep, rep, rep, rep, rep, rep)
     if overlap:
         out = out + (rep, rep)
     return out
@@ -232,15 +230,20 @@ def step_output_specs(cfg, kv_int8, tp="tp"):
     the buffers in place — the ``graph-donation`` gate)."""
     from jax.sharding import PartitionSpec as P
 
-    from .paged_kv import PagedKVCache
+    return (P(), _pool_specs(cfg, kv_int8, tp))
 
-    pool_spec = P(*[tp if a == "tp" else a
-                    for a in PagedKVCache.POOL_SPEC])
-    pool = {"kv": pool_spec}
+
+def _pool_specs(cfg, kv_int8, tp):
+    """The pools' spec tree: the page leaves of every layer that keeps
+    pages (slot state has no sharded placement)."""
+    from jax.sharding import PartitionSpec as P
+
+    pool = {"kv": P(*[tp if a == "tp" else a
+                      for a in PagedKVCache.POOL_SPEC])}
     if kv_int8:
         pool["s"] = P(*[tp if a == "tp" else a
                         for a in PagedKVCache.S_POOL_SPEC])
-    return (P(), [dict(pool) for _ in range(cfg.n_layers)])
+    return [dict(pool) if pages else {} for pages, _ in layer_cache(cfg)]
 
 
 def _bind(mesh, tree):
@@ -329,13 +332,9 @@ def _make_copy(cfg, kv_int8, mesh=None):
         return fn
 
     def copy(pools, s, d):
-        out = []
-        for pool in pools:
-            new = {"kv": pool["kv"].at[d].set(pool["kv"][s])}
-            if "s" in pool:
-                new["s"] = pool["s"].at[d].set(pool["s"][s])
-            out.append(new)
-        return out
+        return [dict(pool, **{k: pool[k].at[d].set(pool[k][s])
+                              for k in PAGE_LEAVES if k in pool})
+                for pool in pools]
 
     kw = {}
     if mesh is not None:
@@ -379,10 +378,11 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
     needed for the spec tree's structure only — float vs weight-only
     int8).
 
-    For a family with per-slot state the program takes one more input
-    after ``slot_rows``: ``slot_fresh``, (num_slots + 1,) bool, the
-    slots whose state starts from zero in this step; the state pools
-    ride in ``pools`` and are donated with the pages.
+    For a family with per-slot state (in any layer: ``layer_cache``)
+    the program takes one more input after ``slot_rows``:
+    ``slot_fresh``, (num_slots + 1,) bool, the slots whose state starts
+    from zero in this step; the state pools ride in ``pools`` and are
+    donated with the pages.
 
     With ``overlap`` (round 21, latency-hiding scheduling) the
     program takes two extra inputs: ``prev_tok``, the PREVIOUS step's
@@ -416,9 +416,8 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
     # its sampling rows' logits (``serve_*`` of models/gpt.py, or of
     # the module a config names itself); the engine supplies what a
     # block keeps between steps — the paged K/V behind ``attend`` and,
-    # for a family with ``slot_state_shapes``, the per-slot state
+    # in a layer that keeps some (``layer_cache``), the per-slot state
     model = getattr(cfg, "serving", G)
-    state_names = tuple(slot_state_shapes(cfg))
     latent = latent_row(cfg)
     counter_names = tuple(getattr(model, "STEP_COUNTERS", ()))
 
@@ -446,25 +445,30 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
         # what the model counts over the step's live rows, all layers
         # together (``STEP_COUNTERS``)
         counts = model.StepCounts(row_live) if counter_names else None
-        for layer, pool in zip(params["layers"], pools):
-            def attend_latent(q, row, pool=pool):
+        for layer, pool, (_, keeps) in zip(params["layers"], pools,
+                                           layer_cache(cfg)):
+            # the layer's updated pools, from whichever backend ran
+            new = {}
+
+            def attend_latent(q, row, pool=pool, new=new):
                 """Write the rows' one latent row each into their
                 pages, then every head of each row against its own
                 block table's rows: (T, H, rank) float32."""
                 from ..kernels import paged_attention as PA
                 with jax.named_scope("kv_write"):
                     pool_kv = write_latent(pool["kv"], page, off, row)
-                    new_pools.append({"kv": pool_kv})
+                    new["kv"] = pool_kv
                 fn = PA.paged_attention if kernel == "pallas" \
                     else PA.paged_attention_reference
                 return fn(q, pool_kv, None, row_pages, row_pos,
                           page_size=page_size, latent=latent,
                           scale=cfg.softmax_scale)
 
-            def attend(q, k, v, pool=pool):
+            def attend(q, k, v, pool=pool, new=new):
                 """Write the rows' k/v into their pages, then each
                 row's attention over its own block table: (T, H, dh)
-                float32.  Appends the layer's updated pools."""
+                float32.  Leaves the layer's updated pages in
+                ``new``."""
                 with jax.named_scope("kv_write"):
                     if kv_int8:
                         kvq, skv = G._kv_quantize(k, v)  # (T, H, 2dh/2)
@@ -475,11 +479,11 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
                         # _kv_quantize's (T, H, 2) transposes once here
                         pool_s = pool["s"].at[page, :, off].set(
                             skv.transpose(0, 2, 1))
-                        new_pools.append({"kv": pool_kv, "s": pool_s})
+                        new.update(kv=pool_kv, s=pool_s)
                     else:
                         pool_kv = write_rows(pool["kv"], page, off, k, v)
                         pool_s = None
-                        new_pools.append({"kv": pool_kv})
+                        new["kv"] = pool_kv
                 if kernel == "pallas":
                     # fused block-table walk
                     # (kernels/paged_attention.py): each row's live
@@ -507,18 +511,20 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
                     q, pool_kv, pool_s, row_pages, row_pos,
                     page_size=page_size)
 
-            # a slot's state that is no page: the layer's (num_slots
-            # + 1, ...) pools, the rows' slots (dead rows the scratch
-            # slot) and which slots start from zero this step
+            # a slot's state that is no page, where the layer keeps
+            # some: its (num_slots + 1, ...) pools, the rows' slots
+            # (dead rows the scratch slot) and which slots start from
+            # zero this step
             state = model.SlotState(
-                {name: pool[name] for name in state_names}, row_slot,
+                {name: pool[name] for name in keeps}, row_slot,
                 slot_fresh, n_rows - num_slots * n_sample) \
-                if state_names else counts
+                if keeps else None
             x = model.serve_block(layer, cfg, x, row_pos,
                                   attend_latent if latent else attend,
-                                  state)
-            if state_names:
-                new_pools[-1].update(state.pools)
+                                  state, counts)
+            if keeps:
+                new.update(state.pools)
+            new_pools.append(new)
 
         with jax.named_scope("head"):
             # (S, n_sample, V) f32: column 0 is the slot's sampling
@@ -999,10 +1005,11 @@ class ServingEngine:
                  tier_bytes=None, device=None):
         if not cfg.causal:
             cfg = dataclasses.replace(cfg, causal=True)
-        # a family whose sequences keep state that is no page (its
-        # serving module's ``slot_state_shapes``): one state per slot
-        # and layer beside the paged K/V, see ``_build_plan``
-        self._stateful = bool(slot_state_shapes(cfg))
+        # a family whose sequences keep state that is no page (in any
+        # layer, by its serving module's ``layer_cache``): one state
+        # per slot beside or instead of that layer's pages, see
+        # ``_build_plan``
+        self._stateful = any(state for _, state in layer_cache(cfg))
         if num_slots < 1:
             raise ValueError("ServingEngine: num_slots must be >= 1")
         if prefill_chunk < 1:
@@ -1173,7 +1180,7 @@ class ServingEngine:
         kv_heads, head_dim, flat_kv = kv_geometry(cfg)
         geometry = kernel == "pallas" and walk_geometry(
             kv_heads // tp, head_dim, page_size, pages_per_slot,
-            self.cache.pools[0]["kv"].dtype, flat=flat_kv,
+            self.cache.page_dtype, flat=flat_kv,
             latent=bool(latent_row(cfg)))
         self._walk_group, self._walk_turn, self._walk_rows, \
             self._walk_chains = geometry or (0, 0, 0, 0)
@@ -1233,7 +1240,8 @@ class ServingEngine:
             # slot-states read and written (the live slots of each
             # dispatched step), those started from zero, and the bytes
             # the recurrence REQUIRES: one read and one write of a live
-            # slot's state per layer.  What the program moves is no
+            # slot's state per layer that keeps one.  What the program
+            # moves is no
             # less: ``slot_scan``'s single-row pass reads and rewrites
             # the whole (num_slots + 1) pool every step and a chunk's
             # slot is touched once more in its loop, so the two agree
@@ -2096,7 +2104,7 @@ class ServingEngine:
                 self.stats["ssm_state_updates"] += plan.state_slots
                 self.stats["ssm_state_resets"] += plan.resets
                 self.stats["ssm_state_bytes"] += 2 * plan.state_slots \
-                    * self.cfg.n_layers * self.cache.bytes_per_slot_state
+                    * self.cache.bytes_per_slot_state
             self.stats["peak_pages"] = max(self.stats["peak_pages"],
                                            self.cache.pages_in_use)
             self.stats["slot_occupancy_sum"] += \
@@ -2132,9 +2140,10 @@ class ServingEngine:
                         tok0 = jnp.zeros(
                             (self.num_slots + self._n_counters,
                              1 + self.spec_K), jnp.int32)
-                        kv = self.cache.pools[0]["kv"]
-                        if self.mesh is None and kv.committed:
-                            tok0 = jax.device_put(tok0, kv.sharding)
+                        pool = jax.tree_util.tree_leaves(
+                            self.cache.pools)[0]
+                        if self.mesh is None and pool.committed:
+                            tok0 = jax.device_put(tok0, pool.sharding)
                         self._tok0 = tok0
                     prev = self._tok0
                 staged += [prev, jnp.asarray(buf.tok_src)]

@@ -20,13 +20,21 @@ token shared by every query head, read once as the key and its first
 ``rank`` lanes again as the value, padded with zero lanes to whole
 tiles (``latent_width``; ``write_latent`` writes it).
 
-A model whose sequences keep state that is no page (``slot_state_shapes``
-of its serving module: a recurrent state, a convolution window) gets a
-second, NON-paged pool per layer and name, ``(num_slots + 1, ...)``:
-row ``s`` is slot ``s``'s, the last row the scratch slot that dead rows
-point at, as they point at the scratch page.  These pools ride in the
-layer's dict beside ``kv`` and are donated and updated in place by the
-step program with it; no allocator: a slot's row is its own.
+A model whose sequences keep state that is no page (a recurrent state,
+a convolution window) gets a second, NON-paged pool per layer and name,
+``(num_slots + 1, ...)``: row ``s`` is slot ``s``'s, the last row the
+scratch slot that dead rows point at, as they point at the scratch page.
+These pools ride in the layer's dict beside ``kv`` and are donated and
+updated in place by the step program with it; no allocator: a slot's
+row is its own.
+
+What a layer keeps is the model's to say, layer by layer
+(``layer_cache``: the ``layer_cache(cfg)`` of the serving module a config
+names): pages or none, and which slot-state pools.  A layer that keeps
+no pages has no ``kv`` leaf and costs no page bytes; page ids stay
+model-wide (one block table: a page id means "this page of every layer
+that has pages").  A module that says nothing keeps pages in every
+layer and no state.
 
 Each page holds ``page_size`` consecutive token positions of ONE
 sequence, all heads, k and v halves fused in the last axis — the same
@@ -94,12 +102,17 @@ made it.
 """
 from __future__ import annotations
 
+import math
 from collections import deque
 from typing import Any, Dict
 
 __all__ = ["PagedKVCache", "contiguous_kv_bytes", "kv_geometry",
-           "latent_row", "latent_width", "slot_state_shapes",
-           "write_latent", "write_rows"]
+           "latent_row", "latent_width", "layer_cache", "write_latent",
+           "write_rows"]
+
+# the leaves of a layer's pool dict that are PAGES (indexed by page id:
+# what page transfer moves); every other leaf is per-slot state
+PAGE_LEAVES = ("kv", "s")
 
 
 def latent_row(cfg):
@@ -190,13 +203,9 @@ def _make_install(cfg, kv_int8, bucket, mesh=None):
         return fn
 
     def install(pools, ids, content):
-        out = []
-        for pool, new in zip(pools, content):
-            o = {"kv": pool["kv"].at[ids].set(new["kv"])}
-            if "s" in pool:
-                o["s"] = pool["s"].at[ids].set(new["s"])
-            out.append(o)
-        return out
+        return [dict(pool, **{k: pool[k].at[ids].set(new[k])
+                              for k in PAGE_LEAVES if k in pool})
+                for pool, new in zip(pools, content)]
 
     fn = jax.jit(install, donate_argnums=(0,))
     if len(_xfer_cache) >= _XFER_CACHE_MAX:
@@ -217,13 +226,8 @@ def _make_export(cfg, kv_int8, bucket, mesh=None):
         return fn
 
     def export(pools, ids):
-        out = []
-        for pool in pools:
-            o = {"kv": pool["kv"][ids]}
-            if "s" in pool:
-                o["s"] = pool["s"][ids]
-            out.append(o)
-        return out
+        return [{k: pool[k][ids] for k in PAGE_LEAVES if k in pool}
+                for pool in pools]
 
     fn = jax.jit(export)
     if len(_xfer_cache) >= _XFER_CACHE_MAX:
@@ -232,26 +236,28 @@ def _make_export(cfg, kv_int8, bucket, mesh=None):
     return fn
 
 
-def slot_state_shapes(cfg):
-    """What one slot keeps per layer that is no page, ``{name: (shape,
-    dtype)}``: the ``slot_state_shapes`` of the model module a config
-    names (``cfg.serving``), or nothing."""
-    shapes = getattr(getattr(cfg, "serving", None), "slot_state_shapes",
-                     None)
-    return shapes(cfg) if shapes else {}
+def layer_cache(cfg):
+    """What each layer keeps between steps, one ``(pages, state)`` a
+    layer: whether it keeps K/V pages, and what one slot keeps there
+    that is no page, ``{name: (shape, dtype)}``.  The ``layer_cache`` of
+    the model module a config names (``cfg.serving``); a module that
+    says nothing keeps pages in every layer and no state."""
+    per_layer = getattr(getattr(cfg, "serving", None), "layer_cache", None)
+    return list(per_layer(cfg)) if per_layer \
+        else [(True, {})] * cfg.n_layers
 
 
 def contiguous_kv_bytes(cfg, batch, total, kv_int8=False):
     """HBM the contiguous allocator holds for a (batch, total)-shaped
-    decode: B*H*total*2*dh elements per layer (+ the f32 scale pair
-    per (row, token) when int8) — the baseline for the paged-vs-
-    contiguous comparison in benchmark/serve_bench.py."""
+    decode: B*H*total*2*dh elements per layer that keeps K/V (+ the f32
+    scale pair per (row, token) when int8) — the baseline for the
+    paged-vs-contiguous comparison in benchmark/serve_bench.py."""
     H, dh, _ = kv_geometry(cfg)
     rows = batch * H * total
     per_row = 2 * dh * (1 if kv_int8 else _dtype_size(cfg.dtype))
     if kv_int8:
         per_row += 2 * 4                      # f32 scale pair
-    return rows * per_row * cfg.n_layers
+    return rows * per_row * sum(pages for pages, _ in layer_cache(cfg))
 
 
 class PagedKVCache:
@@ -312,33 +318,34 @@ class PagedKVCache:
             def place(x, spec=self.POOL_SPEC):
                 return jax.device_put(
                     x, NamedSharding(mesh, P(*spec)))
+        # what each layer keeps (module docstring): pages or none, and
+        # one (num_slots + 1, ...) pool per name of what a slot keeps
+        # beside them, zeros
+        self.layers = layer_cache(cfg)
+        self.page_dtype = jnp.dtype(jnp.int8) if kv_int8 else cdt
+        names = sorted({n for _, state in self.layers for n in state})
+        if names and (num_slots is None or mesh is not None):
+            raise ValueError(
+                "PagedKVCache: per-slot state %s needs num_slots and has "
+                "no sharded placement" % names)
+        # (the planner books it every step: summed once)
+        self._slot_state_bytes = sum(
+            math.prod(shape) * _dtype_size(dtype)
+            for _, state in self.layers for shape, dtype in state.values())
         self.pools = []
-        for _ in range(cfg.n_layers):
-            if kv_int8:
-                self.pools.append({
-                    "kv": place(jnp.zeros((num_pages,) + page,
-                                          jnp.int8)),
-                    "s": place(jnp.zeros(
+        for pages, state in self.layers:
+            pool = {}
+            if pages:
+                pool["kv"] = place(jnp.zeros((num_pages,) + page,
+                                             self.page_dtype))
+                if kv_int8:
+                    pool["s"] = place(jnp.zeros(
                         (num_pages, 2, page_size, H), jnp.float32),
-                        self.S_POOL_SPEC),
-                })
-            else:
-                self.pools.append({
-                    "kv": place(jnp.zeros((num_pages,) + page, cdt)),
-                })
-        # what a slot keeps beside its pages (module docstring): one
-        # (num_slots + 1, ...) pool per layer and name, zeros
-        self.slot_state = slot_state_shapes(cfg)
-        if self.slot_state:
-            if num_slots is None or mesh is not None:
-                raise ValueError(
-                    "PagedKVCache: per-slot state %s needs num_slots "
-                    "and has no sharded placement"
-                    % sorted(self.slot_state))
-            for pool in self.pools:
-                for name, (shape, dtype) in self.slot_state.items():
-                    pool[name] = place(jnp.zeros(
-                        (num_slots + 1,) + tuple(shape), dtype))
+                        self.S_POOL_SPEC)
+            for name, (shape, dtype) in state.items():
+                pool[name] = place(jnp.zeros(
+                    (num_slots + 1,) + tuple(shape), dtype))
+            self.pools.append(pool)
         # page 0 is scratch — never allocated
         self._free = deque(range(1, num_pages))
         self._in_use = 0
@@ -443,8 +450,10 @@ class PagedKVCache:
         padded = []
         for layer, pool in zip(content, self.pools):
             lay = {}
-            for k, ref in pool.items():
-                a = np.asarray(layer[k])
+            for k in PAGE_LEAVES:
+                if k not in pool:
+                    continue
+                ref, a = pool[k], np.asarray(layer[k])
                 want = (n,) + tuple(ref.shape[1:])
                 if a.shape != want or a.dtype != ref.dtype:
                     raise ValueError(
@@ -462,21 +471,22 @@ class PagedKVCache:
     # -------------------------------------------------- accounting ---
     @property
     def bytes_per_page(self):
-        """Device bytes one page costs across all layers."""
+        """Device bytes one page costs across the layers that keep
+        pages."""
         H, dh, _ = kv_geometry(self.cfg)
         per_tok = H * 2 * dh * (1 if self.kv_int8
                                 else _dtype_size(self.cfg.dtype))
         if self.kv_int8:
             per_tok += H * 2 * 4
-        return per_tok * self.page_size * self.cfg.n_layers
+        return per_tok * self.page_size \
+            * sum(pages for pages, _ in self.layers)
 
     @property
     def bytes_per_slot_state(self):
-        """Device bytes of ONE layer's state of ONE slot, all names
-        together (0 for a model that keeps pages alone)."""
-        import numpy as np
-        return sum(int(np.prod(shape)) * _dtype_size(dtype)
-                   for shape, dtype in self.slot_state.values())
+        """Device bytes of ONE slot's state that is no page, every name
+        of every layer that keeps some together (0 for a model that
+        keeps pages alone)."""
+        return self._slot_state_bytes
 
     @property
     def bytes_held(self):

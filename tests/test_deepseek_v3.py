@@ -198,7 +198,7 @@ def test_shares_add_up_to_the_uncut_expert_layer(ref, model):
     total, pairs = 0.0, 0
     for rank in range(8):
         cut = slice(4 * rank, 4 * rank + 4)
-        y, n, hit = moe.held_experts_ffn(
+        y, n, hit, _ = moe.held_experts_ffn(
             m, layer["ew_gate"][cut], layer["ew_up"][cut],
             layer["ew_down"][cut], idx, w, held_first=4 * rank)
         # the reference, given the same share, agrees rank by rank
@@ -227,11 +227,12 @@ def test_dead_rows_dispatch_no_pair():
                       jnp.int32)
     w = jnp.ones((6, 2), jnp.float32)
     live = jnp.asarray([True, True, True, False, True, False])
-    y, pairs, hit = moe.held_experts_ffn(x, wg, wu, wd, idx, w,
+    y, pairs, hit, sizes = moe.held_experts_ffn(x, wg, wu, wd, idx, w,
                                          held_first=0, live=live)
     assert (int(pairs), int(hit)) == (5, 2)
+    assert sizes.tolist() == [3, 2]
     assert float(jnp.max(jnp.abs(y[jnp.asarray([2, 3, 5])]))) == 0.0
-    y2, pairs2, _ = moe.held_experts_ffn(x, wg, wu, wd, idx, w,
+    y2, pairs2, _, _ = moe.held_experts_ffn(x, wg, wu, wd, idx, w,
                                          held_first=0)
     assert int(pairs2) == 7
     np.testing.assert_allclose(np.asarray(y2)[[0, 1, 4]],

@@ -112,12 +112,19 @@ def test_paged_attention_compiles(v5e, geom, kv_dtype, q_dtype):
 _GQA = dict(T=128, Hq=20, Hkv=4, dh=128, ps=16, PP=48, NP=3073)
 
 
+# ... and the lfm2_8b_a1b_l12 cell 256 step rows, 32 query heads over 8
+# key/value heads of 64 (a head's keys are HALF a lane tile of the flat
+# 1,024-lane page), 128 pages a row, a (16385, 16, 1024) bf16 pool
+_GQA64 = dict(T=256, Hq=32, Hkv=8, dh=64, ps=16, PP=128, NP=16385)
+
+
 @pytest.mark.parametrize("geom,dtype,walks", [
     (_GQA, "bfloat16", True),
     (dict(_GQA, T=37, PP=7, NP=353), "float32", True),
     # 8-token bf16 pages are half a tile: the per-page grid
     (dict(_GQA, T=32, ps=8, NP=353), "bfloat16", False),
-], ids=["cell-bf16", "odd-f32", "half-tile-bf16"])
+    (_GQA64, "bfloat16", True),
+], ids=["cell-bf16", "odd-f32", "half-tile-bf16", "heads-of-64-bf16"])
 def test_grouped_paged_attention_compiles(v5e, geom, dtype, walks):
     from mxnet_tpu.kernels.paged_attention import (paged_attention,
                                                    walk_geometry)
@@ -125,10 +132,14 @@ def test_grouped_paged_attention_compiles(v5e, geom, dtype, walks):
     geometry = walk_geometry(g["Hkv"], g["dh"], g["ps"], g["PP"], dtype,
                              flat=True)
     assert (geometry is not None) == walks
-    if walks:
-        # the flat fold keeps its turns of two pages (one where the
+    if walks and g["dh"] == 128:
+        # the column fold keeps its turns of two pages (one where the
         # group is an odd count) and its loop: one group a trip
         assert geometry[1] == 2 - geometry[0] % 2 and geometry[3] == 1
+    elif walks:
+        # a head's [k | v] pair one lane tile: the ring, a whole group
+        # of 16 pages a turn under the dense form of the flat fold
+        assert geometry == (16, 16, 32, 4)
     _compile(lambda q, kv, bt, pos: paged_attention(
         q, kv, None, bt, pos, page_size=g["ps"]), v5e[0],
         _sds((g["T"], g["Hq"], g["dh"]), dtype),
@@ -166,12 +177,19 @@ def test_latent_paged_attention_compiles(v5e, geom, dtype):
         _sds((g["T"], g["PP"]), "int32"), _sds((g["T"],), "int32"))
 
 
-def test_held_experts_ffn_compiles(v5e):
-    """The cell's expert layer: 256 rows x top-8 = 2,048 static pairs
-    over the 16 held experts of width 2,048: three grouped products,
-    each one Mosaic call (megablox)."""
-    from mxnet_tpu.parallel.moe import held_experts_ffn
-    T, K, D, F, E = 256, 8, 7168, 2048, 16
+@pytest.mark.parametrize("T,K,D,F,E", [
+    (256, 8, 7168, 2048, 16),
+    # lfm2_8b_a1b_l12: top-4 over all 32 experts of width 1,792 = 7 x
+    # 256: the first width that is no multiple of 512 (896-wide tiles)
+    (256, 4, 2048, 1792, 32),
+], ids=["gigachat", "lfm2"])
+def test_held_experts_ffn_compiles(v5e, T, K, D, F, E):
+    """A cell's expert layer: 256 rows x top-k static pairs over the
+    held experts: three grouped products, each one Mosaic call
+    (megablox)."""
+    from mxnet_tpu.parallel.moe import _tile, held_experts_ffn
+    assert _tile(F, range(1024, 0, -128)) == (1024 if F == 2048 else 896)
+    assert _tile(D, range(1024, 0, -128)) == 1024
     text = _compile(
         lambda x, wg, wu, wd, idx, w, live: held_experts_ffn(
             x, wg, wu, wd, idx, w, held_first=0, live=live), v5e[0],

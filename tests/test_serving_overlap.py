@@ -476,7 +476,8 @@ sys.exit(rc)
 
 
 @pytest.mark.parametrize("cell", ["bert_large_decoder.decode_heavy",
-                                  "falcon_h1_34b_l6.chat_decode"])
+                                  "falcon_h1_34b_l6.chat_decode",
+                                  "lfm2_8b_a1b_l12.long_decode"])
 def test_serving_cells_rehearse_pipelined(cell):
     """The benchmark's serving cells follow the engine's choice with no
     benchmark file edited, so their harness has to be schedule-agnostic:

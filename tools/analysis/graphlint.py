@@ -598,6 +598,41 @@ def build_serving_step_latent():
                 sds((_SLOTS + n_counts, 1), i32), sds((n_rows,), i32))
 
 
+def build_serving_step_layer_kinds():
+    """The step of a family whose layers keep different things
+    (``models/lfm2_moe.py``, PR 36) as a TPU engine runs it: the SAME
+    live ``_make_step`` builder, pipelined; the convolution layers'
+    windows and the attention layer's pages donated, each in the layer
+    that keeps it; the expert layers' three counts behind the tokens.
+    conv, conv, attention, conv at toy widths, 1 dense and 3 expert
+    layers of 8 experts."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.models import lfm2_moe as M
+    from mxnet_tpu.serving.engine import _make_step
+    from mxnet_tpu.serving.paged_kv import PagedKVCache
+    cfg = M.Lfm2MoeConfig(
+        vocab_size=256, d_model=64, n_layers=4, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=128, moe_d_ff=32, n_experts=8, top_k=2,
+        n_dense_layers=1,
+        layer_types=("conv", "conv", "full_attention", "conv"),
+        dtype="bfloat16")
+    pps, n_rows = 4, _SLOTS + _CHUNK
+    fn = _make_step(cfg, _SLOTS, n_rows, pps, _PAGE, False, kernel="xla",
+                    overlap=True)
+    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+    params = jax.eval_shape(
+        lambda: M.init_params(jax.random.PRNGKey(0), cfg))
+    pools = jax.eval_shape(lambda: PagedKVCache(
+        cfg, _SLOTS * pps + 1, _PAGE, num_slots=_SLOTS).pools)
+    n_counts = len(M.STEP_COUNTERS)
+    return fn, (params, pools, sds((n_rows,), i32), sds((n_rows,), i32),
+                sds((n_rows,), i32), sds((n_rows,), jnp.bool_),
+                sds((_SLOTS + 1, pps), i32), sds((_SLOTS, 1), i32),
+                sds((_SLOTS + 1,), jnp.bool_),
+                sds((_SLOTS + n_counts, 1), i32), sds((n_rows,), i32))
+
+
 def build_paged_attention_kernel():
     import jax
     import jax.numpy as jnp
@@ -647,6 +682,10 @@ def live_programs() -> List[ProgramSpec]:
         # its norms, router and softmax are float32 by design, at
         # widths of its own)
         spec("serving_step_latent", build_serving_step_latent,
+             donate=(1,)),
+        # PR 36: a family whose layers keep different things (windows
+        # beside pages): every leaf of the mixed pools donated
+        spec("serving_step_layer_kinds", build_serving_step_layer_kinds,
              donate=(1,)),
         spec("serving_step_tp", build_serving_step_tp, donate=(1,),
              dtype_region="int8", f32_allow=acc),
