@@ -32,8 +32,9 @@ take the same path (a row is a token in the engine's step).
 deployment (``held_first``, ``held_count``): it routes over all
 ``n_routed_experts`` as published and sums the terms of the experts held
 here (``parallel/moe.py``); the shared expert is computed whole.  It
-counts, over the live rows, the row-expert pairs it dispatched and the
-held experts hit (``StepCounts``): the engine reads them back with the
+counts, over the live rows, the row-expert pairs it dispatched, the
+held experts hit and the copies of an expert's weights its grouped
+products ask for (``StepCounts``): the engine reads them back with the
 step's tokens.
 
 Precision: the residual stream and the matmuls' operands are
@@ -297,7 +298,7 @@ def _swiglu(m, w_gate, w_up, w_down, cdt):
     return _mm(h, w_down, cdt)
 
 
-STEP_COUNTERS = ("moe_pairs", "moe_experts_hit")
+STEP_COUNTERS = ("moe_pairs", "moe_experts_hit", "moe_weight_fetches")
 
 
 class StepCounts:
@@ -318,11 +319,14 @@ class StepCounts:
 def counter_stats(cfg, params, counts):
     """What one step's ``STEP_COUNTERS`` add to the engine's ``stats``:
     themselves, and the bytes of the expert weights the step had to
-    read (``moe_experts_hit`` x one expert's three matrices)."""
-    pairs, hit = (int(c) for c in counts)
+    read (``moe_experts_hit`` x one expert's three matrices; what the
+    grouped products copied is ``moe_weight_fetches`` matrices, three
+    a hit where none is copied twice)."""
+    pairs, hit, fetches = (int(c) for c in counts)
     w = next(layer["ew_gate"] for layer in params["layers"]
              if "ew_gate" in layer)
     return {"moe_pairs": pairs, "moe_experts_hit": hit,
+            "moe_weight_fetches": fetches,
             "moe_expert_bytes": hit * 3 * w.shape[1] * w.shape[2]
             * w.dtype.itemsize}
 
@@ -344,11 +348,11 @@ def _experts(layer, cfg, m, counts):
             top_k=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
             scale=cfg.routed_scaling_factor)
     with jax.named_scope("moe_experts"):
-        y, pairs, hit, _ = held_experts_ffn(
+        y, pairs, hit, _, fetches = held_experts_ffn(
             m.astype(cdt), layer["ew_gate"].astype(cdt),
             layer["ew_up"].astype(cdt), layer["ew_down"].astype(cdt),
             idx, w, held_first=cfg.held_first, live=counts.live)
-        counts.add(pairs, hit)
+        counts.add(pairs, hit, fetches)
     with jax.named_scope("moe_shared"):
         return y + _swiglu(m, layer["sw_gate"], layer["sw_up"],
                            layer["sw_down"], cdt)

@@ -33,8 +33,9 @@ call's flat rows and moves every slot's window past them).
 
 **The expert layer** holds every expert: ``parallel/moe.py``'s serving
 form with one group and all experts held.  It counts, over the live
-rows, the row-expert pairs it dispatched, the experts hit and the
-heaviest expert's pairs (``STEP_COUNTERS``, summed over the layers):
+rows, the row-expert pairs it dispatched, the experts hit, the copies
+of an expert's weights its grouped products ask for and the heaviest
+expert's pairs (``STEP_COUNTERS``, summed over the layers):
 the engine reads them back with the step's tokens.
 
 Precision: the residual stream, the matmuls' operands and the window
@@ -49,6 +50,7 @@ import math
 import sys
 from typing import Tuple
 
+from .deepseek_v3 import STEP_COUNTERS as _STEP_COUNTERS
 from .deepseek_v3 import StepCounts as _StepCounts
 from .deepseek_v3 import _swiglu
 from .deepseek_v3 import counter_stats as _counter_stats
@@ -187,21 +189,21 @@ def init_params(key, cfg, dtype=None):
 
 # ------------------------------------------------------------ pieces ---
 
-STEP_COUNTERS = ("moe_pairs", "moe_experts_hit", "moe_pairs_max")
+STEP_COUNTERS = _STEP_COUNTERS + ("moe_pairs_max",)
 
 
 class StepCounts(_StepCounts):
-    """``deepseek_v3.StepCounts`` with this family's third count: per
-    expert layer the heaviest expert's pairs."""
+    """``deepseek_v3.StepCounts`` with this family's own count after
+    them: per expert layer the heaviest expert's pairs."""
     names = STEP_COUNTERS
 
 
 def counter_stats(cfg, params, counts):
     """What one step's ``STEP_COUNTERS`` add to the engine's ``stats``:
-    ``deepseek_v3.counter_stats`` of the first two (themselves and the
-    bytes of the expert weights the step had to read) and the third."""
-    return dict(_counter_stats(cfg, params, counts[:2]),
-                moe_pairs_max=int(counts[2]))
+    ``deepseek_v3.counter_stats`` of that family's (themselves and the
+    bytes of the expert weights the step had to read) and this one's."""
+    return dict(_counter_stats(cfg, params, counts[:-1]),
+                moe_pairs_max=int(counts[-1]))
 
 
 def _short_conv(layer, cfg, u, state):
@@ -262,11 +264,11 @@ def _experts(layer, cfg, m, counts):
             top_k=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
             scale=cfg.routed_scaling_factor, eps=1e-6)
     with jax.named_scope("moe_experts"):
-        y, pairs, hit, sizes = held_experts_ffn(
+        y, pairs, hit, sizes, fetches = held_experts_ffn(
             m.astype(cdt), layer["ew_gate"].astype(cdt),
             layer["ew_up"].astype(cdt), layer["ew_down"].astype(cdt),
             idx, w, held_first=0, live=counts.live)
-        counts.add(pairs, hit, jnp.max(sizes))
+        counts.add(pairs, hit, fetches, jnp.max(sizes))
     return y
 
 

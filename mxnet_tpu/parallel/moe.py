@@ -215,32 +215,76 @@ def _tile(n, prefer):
     return next((t for t in prefer if n % t == 0), n)
 
 
+# the largest weight tile ``_gmm_tiling`` asks for whole-K: two buffers
+# of it, the (128, K) rows' two, the float32 output tile's two and the
+# accumulator stay under 10.5 MiB of the 16 MiB of VMEM a call gets
+_WEIGHT_TILE_BYTES = 4 << 20
+
+
+def _gmm_tiling(M, K, N, itemsize):
+    """``(tm, tk, tn)`` of the grouped product (M, K) x (G, K, N), from
+    the shapes and the operands' item size alone.  Row tiles of 128.
+    The weight tile spans ALL of K where some ``tn`` of 512 to 1,024
+    lanes (the largest multiple of 128 that divides N) keeps ``(K, tn)``
+    within ``_WEIGHT_TILE_BYTES``: the grid then asks for the same
+    weight block from an expert's consecutive row tiles, and the copy is
+    made once (``_grouped_dot``).  Elsewhere each side is the largest
+    multiple of 128 lanes up to 1,024 that divides it (1,024 of 2,048
+    or 7,168; 896 of 1,792 = 7 x 256, where powers of two alone gave
+    256-wide tiles at 365 GB/s against 484: my chip runs, PR 36), else
+    the side whole."""
+    tm = _tile(M, (128, 64, 32, 16, 8))
+    tn = next((t for t in range(1024, 511, -128)
+               if N % t == 0 and K * t * itemsize <= _WEIGHT_TILE_BYTES),
+              None)
+    if tn is not None:
+        return tm, K, tn
+    lanes = range(1024, 0, -128)
+    return tm, _tile(K, lanes), _tile(N, lanes)
+
+
+def _weight_fetches(sizes, tm, tiles_k):
+    """The copies of a group's weights that megablox's grid makes in
+    one grouped product over sorted groups of ``sizes`` (G,) int32, in
+    units of one group's whole matrix.  A grid step's weight block is
+    ``(group of the visit, k_i, n_i)``, a visit being one row tile of
+    ``tm`` that holds some of a group's rows, and a block is copied
+    unless the step before asked for the same one: with one K tile a
+    group is copied once however many row tiles its rows straddle (the
+    groups hit); with more, ``k_i`` has moved on between two visits and
+    every visit copies the matrix again."""
+    import jax.numpy as jnp
+    if tiles_k == 1:
+        return jnp.sum(sizes > 0, dtype=jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    return jnp.sum(jnp.where(sizes > 0, -(-ends // tm) - starts // tm, 0),
+                   dtype=jnp.int32)
+
+
 def _grouped_dot(x, w, sizes):
     """``x[rows of group g] @ w[g]`` for every group: x (M, K) sorted by
     group, w (G, K, N), sizes (G,) int32 whose sum may fall short of M
     (the rows after the last group are whatever the kernel leaves
-    there).  Float32 out.  On a TPU ``megablox.gmm``: row tiles of 128,
-    weight tiles of up to 1024 x 1024 (2 MiB in bfloat16, the copy that
-    binds a step with a handful of rows an expert), each side the
-    largest multiple of 128 lanes that divides it (1,024 of 2,048 or
-    7,168; 896 of 1,792 = 7 x 256, where powers of two alone gave
-    256-wide tiles at 365 GB/s against 484: my chip runs, PR 36), a
-    grid over the ACTIVE row tiles only; the XLA ``ragged_dot`` on the
-    CPU."""
+    there).  Float32 out.  On a TPU ``megablox.gmm`` under
+    ``_gmm_tiling``: a grid (N tiles, ACTIVE row tiles, K tiles) whose
+    weight block follows the row tile's group.  The weights' copy binds
+    a step with a handful of rows an expert, and a group whose rows
+    straddle a row tile's edge is visited from both tiles: where the
+    weight tiles split K the second visit copies the matrix again
+    (``k_i`` has moved on in between), where one spans K it finds its
+    block in VMEM and costs its product alone (``_weight_fetches``
+    counts which).  The XLA ``ragged_dot`` on the CPU."""
     import jax
     import jax.numpy as jnp
     from ..kernels.platform import run_kernel
-    M, K = x.shape
-    N = w.shape[2]
+    tiling = _gmm_tiling(*x.shape, w.shape[2], w.dtype.itemsize)
 
     def build(on_cpu):
         if on_cpu:
             return lambda x, w, sizes: jax.lax.ragged_dot(
                 x, w, sizes, preferred_element_type=jnp.float32)
         from jax.experimental.pallas.ops.tpu.megablox import gmm
-        lanes = range(1024, 0, -128)
-        tiling = (_tile(M, (128, 64, 32, 16, 8)), _tile(K, lanes),
-                  _tile(N, lanes))
         return lambda x, w, sizes: gmm(x, w, sizes, jnp.float32, tiling)
 
     return run_kernel(build, x, w, sizes)
@@ -256,10 +300,13 @@ def held_experts_ffn(x, w_gate, w_up, w_down, idx, w, *, held_first,
     ``w`` (T, k) each row's chosen experts and weights over ALL experts
     (``route_group_limited``); ``live`` (T,) bool: rows whose pairs are
     dispatched (a dead row of a fixed-shape step is none of the
-    traffic).  Returns ``(y (T, D) float32, pairs, hit, sizes)``:
-    ``y = sum over a row's HELD choices of w_k E_k(x)``, the number of
-    row-expert pairs dispatched, of held experts with at least one, and
-    each held expert's pairs, (E_held,) int32.
+    traffic).  Returns ``(y (T, D) float32, pairs, hit, sizes,
+    fetches)``: ``y = sum over a row's HELD choices of w_k E_k(x)``,
+    the number of row-expert pairs dispatched, of held experts with at
+    least one, each held expert's pairs, (E_held,) int32, and the
+    copies of an expert's matrix that the three products' grids ask
+    for (``_weight_fetches`` under each product's ``_gmm_tiling``;
+    3 x ``hit`` where every hit expert is copied once a product).
 
     The ``T x k`` pairs are a static bound, so nothing is dropped and
     no capacity is set: the pairs are sorted by held expert (those of
@@ -290,4 +337,11 @@ def held_experts_ffn(x, w_gate, w_up, w_down, idx, w, *, held_first,
     out = jnp.where(held.reshape(-1, 1), out[back], 0.0)
     y = jnp.sum(out.reshape(T, K, D)
                 * jnp.where(held, w, 0.0).astype(f32)[..., None], axis=1)
-    return y, jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32), sizes
+
+    def fetches(k, n):
+        tm, tk, _ = _gmm_tiling(T * K, k, n, w_gate.dtype.itemsize)
+        return _weight_fetches(sizes, tm, -(-k // tk))
+
+    F = w_gate.shape[2]
+    return (y, jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32), sizes,
+            2 * fetches(D, F) + fetches(F, D))

@@ -136,9 +136,10 @@ absorbed queries and its ONE cache row instead of per-head keys and
 values: the pool holds that row a token a layer (``paged_kv.py``), the
 walk folds every head against it with the model's own softmax scale.
 Such a family's module may count on the device over the step's live
-rows (``STEP_COUNTERS``: its expert layers' dispatched pairs and experts
-hit): the counts ride behind the sampled tokens in the step's one
-read-back and ``counter_stats`` books them in ``stats`` at the commit.
+rows (``STEP_COUNTERS``: its expert layers' dispatched pairs, experts
+hit and weight copies): the counts ride behind the sampled tokens in
+the step's one read-back and ``counter_stats`` books them in ``stats``
+at the commit.
 Refused for it by name: ``prefix_cache``, ``spec_K``, the KV tier,
 ``admit_prefilled``, ``kv_int8``, ``tp > 1`` (no test shows them on
 latent pages yet).

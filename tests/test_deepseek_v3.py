@@ -198,7 +198,7 @@ def test_shares_add_up_to_the_uncut_expert_layer(ref, model):
     total, pairs = 0.0, 0
     for rank in range(8):
         cut = slice(4 * rank, 4 * rank + 4)
-        y, n, hit, _ = moe.held_experts_ffn(
+        y, n, hit, _, _ = moe.held_experts_ffn(
             m, layer["ew_gate"][cut], layer["ew_up"][cut],
             layer["ew_down"][cut], idx, w, held_first=4 * rank)
         # the reference, given the same share, agrees rank by rank
@@ -227,13 +227,13 @@ def test_dead_rows_dispatch_no_pair():
                       jnp.int32)
     w = jnp.ones((6, 2), jnp.float32)
     live = jnp.asarray([True, True, True, False, True, False])
-    y, pairs, hit, sizes = moe.held_experts_ffn(x, wg, wu, wd, idx, w,
-                                         held_first=0, live=live)
-    assert (int(pairs), int(hit)) == (5, 2)
+    y, pairs, hit, sizes, fetches = moe.held_experts_ffn(
+        x, wg, wu, wd, idx, w, held_first=0, live=live)
+    assert (int(pairs), int(hit), int(fetches)) == (5, 2, 6)
     assert sizes.tolist() == [3, 2]
     assert float(jnp.max(jnp.abs(y[jnp.asarray([2, 3, 5])]))) == 0.0
-    y2, pairs2, _, _ = moe.held_experts_ffn(x, wg, wu, wd, idx, w,
-                                         held_first=0)
+    y2, pairs2, _, _, _ = moe.held_experts_ffn(x, wg, wu, wd, idx, w,
+                                            held_first=0)
     assert int(pairs2) == 7
     np.testing.assert_allclose(np.asarray(y2)[[0, 1, 4]],
                                np.asarray(y)[[0, 1, 4]], rtol=1e-6)
@@ -298,6 +298,7 @@ def test_engine_serves_the_reference_tokens(ref, model, kernel, overlap):
     assert 0 < s["moe_pairs"] <= rows * 2 * 4
     assert 0 < s["moe_experts_hit"] <= min(s["moe_pairs"],
                                            s["steps"] * 2 * 4)
+    assert s["moe_weight_fetches"] == 3 * s["moe_experts_hit"]
     import model_math_deepseek_v3 as mm
     assert s["moe_expert_bytes"] == s["moe_experts_hit"] \
         * mm.expert_bytes(TOY, itemsize=4)
@@ -530,16 +531,17 @@ def test_benchmark_json_names_the_cell_and_its_metrics():
     cell = {"name": CELL, "bench": bench}
     per_layer = [m["name"] for m in chipbench_run.metrics_for(
         cell, "per_layer")]
-    assert per_layer[-4:] == ["moe_expert_bw_share.serve",
+    assert per_layer[-5:] == ["moe_expert_bw_share.serve",
                               "latent_read_bw_share.serve",
                               "moe_rows_per_expert.serve",
-                              "kv_chain_fill_share.serve"]
+                              "kv_chain_fill_share.serve",
+                              "moe_weight_fetch_ratio.serve"]
     assert "ssm_state_bw_share.serve" not in per_layer
-    assert "step_mfu.serve" in per_layer and len(per_layer) == 21
+    assert "step_mfu.serve" in per_layer and len(per_layer) == 22
     assert [m["name"] for m in chipbench_run.metrics_for(
         cell, "end_to_end")] == ["setup_s", "serve_tok_s", "itl_p95_ms"]
     # each new reader is silent where the program books no such counter
-    for name in per_layer[-4:]:
+    for name in per_layer[-5:]:
         reader = chipbench_run.load_module("layer_metrics", name)
         assert reader.read({"config": {}, "device": {"kind": "TPU v5 lite"}},
                            {}, {"steps": 5, "kv_pages_read": 7},

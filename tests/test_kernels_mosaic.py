@@ -180,16 +180,20 @@ def test_latent_paged_attention_compiles(v5e, geom, dtype):
 @pytest.mark.parametrize("T,K,D,F,E", [
     (256, 8, 7168, 2048, 16),
     # lfm2_8b_a1b_l12: top-4 over all 32 experts of width 1,792 = 7 x
-    # 256: the first width that is no multiple of 512 (896-wide tiles)
+    # 256: the first width that is no multiple of 512 (896-wide tiles),
+    # and both its weight tiles span K (3.5 MiB)
     (256, 4, 2048, 1792, 32),
 ], ids=["gigachat", "lfm2"])
 def test_held_experts_ffn_compiles(v5e, T, K, D, F, E):
     """A cell's expert layer: 256 rows x top-k static pairs over the
     held experts: three grouped products, each one Mosaic call
     (megablox)."""
-    from mxnet_tpu.parallel.moe import _tile, held_experts_ffn
-    assert _tile(F, range(1024, 0, -128)) == (1024 if F == 2048 else 896)
-    assert _tile(D, range(1024, 0, -128)) == 1024
+    from mxnet_tpu.parallel.moe import _gmm_tiling, held_experts_ffn
+    # gate / up: whole K at 896 lanes of 1,792; 7,168 x 512 is past the
+    # weight tile's budget.  down: whole K both (4 MiB at 2,048 x 1,024)
+    assert _gmm_tiling(T * K, D, F, 2) == (
+        (128, 1024, 1024) if F == 2048 else (128, 2048, 896))
+    assert _gmm_tiling(T * K, F, D, 2) == (128, F, 1024)
     text = _compile(
         lambda x, wg, wu, wd, idx, w, live: held_experts_ffn(
             x, wg, wu, wd, idx, w, held_first=0, live=live), v5e[0],

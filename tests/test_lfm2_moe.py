@@ -230,7 +230,7 @@ def test_held_experts_ffn_over_all_experts_is_the_references_loop(ref,
     np.testing.assert_allclose(
         np.asarray(w), picked / (picked.sum(-1, keepdims=True) + 1e-6),
         rtol=1e-5)
-    y, pairs, hit, sizes = moe.held_experts_ffn(
+    y, pairs, hit, sizes, fetches = moe.held_experts_ffn(
         m, layer["ew_gate"], layer["ew_up"], layer["ew_down"], idx, w,
         held_first=0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want),
@@ -239,6 +239,8 @@ def test_held_experts_ffn_over_all_experts_is_the_references_loop(ref,
     assert sizes.tolist() == np.bincount(np.asarray(idx).ravel(),
                                          minlength=8).tolist()
     assert int(hit) == int(jnp.sum(sizes > 0))
+    # a toy product has one K tile: each hit expert copied once of three
+    assert int(fetches) == 3 * int(hit)
 
 
 def test_expert_bias_chooses_and_does_not_weigh(ref):
@@ -323,7 +325,7 @@ def test_engine_serves_the_reference_tokens(ref, model, kernel, overlap):
             break
         counted.append({k: eng.stats[k] - before[k] for k in (
             "decode_rows", "prefill_rows", "moe_pairs", "moe_pairs_max",
-            "moe_experts_hit")})
+            "moe_experts_hit", "moe_weight_fetches")})
     eng.close()
     assert all(eng.requests[r].state == "done"
                and len(eng.requests[r].generated) == m
@@ -344,6 +346,7 @@ def test_engine_serves_the_reference_tokens(ref, model, kernel, overlap):
         assert c["moe_pairs_max"] * E >= c["moe_pairs"]
         assert c["moe_pairs_max"] <= c["moe_pairs"]
         assert c["moe_experts_hit"] <= E * EXPERT_LAYERS
+        assert c["moe_weight_fetches"] == 3 * c["moe_experts_hit"]
     s = eng.stats
     import model_math_lfm2_moe as mm
     assert s["moe_expert_bytes"] == s["moe_experts_hit"] \
@@ -662,17 +665,18 @@ def test_benchmark_json_names_the_cell_and_its_metrics():
     cell = {"name": CELL, "bench": bench}
     per_layer = [m["name"] for m in chipbench_run.metrics_for(
         cell, "per_layer")]
-    assert per_layer[-4:] == ["moe_expert_bw_share.serve",
+    assert per_layer[-5:] == ["moe_expert_bw_share.serve",
                               "moe_rows_per_expert.serve",
                               "kv_chain_fill_share.serve",
-                              "moe_load_max_ratio.serve"]
-    assert bench["per_layer"][-1] == {
+                              "moe_load_max_ratio.serve",
+                              "moe_weight_fetch_ratio.serve"]
+    assert bench["per_layer"][-2] == {
         "name": "moe_load_max_ratio.serve", "unit": "x", "better": "lower",
         "source": "program_counter", "layer": "expert layer",
         "moves": "serve_tok_s", "workloads": [CELL]}
     assert "ssm_state_bw_share.serve" not in per_layer
     assert "latent_read_bw_share.serve" not in per_layer
-    assert "step_mfu.serve" in per_layer and len(per_layer) == 21
+    assert "step_mfu.serve" in per_layer and len(per_layer) == 22
     assert [m["name"] for m in chipbench_run.metrics_for(
         cell, "end_to_end")] == ["setup_s", "serve_tok_s", "itl_p95_ms"]
     # the new reader: the heaviest expert over the mean, the experts from
