@@ -23,6 +23,8 @@ import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
+from .. import profiler
+
 __all__ = ["TransformerConfig", "init_params", "forward",
            "forward_with_aux", "mlm_loss", "make_train_step",
            "train_step_input_specs", "train_step_output_specs",
@@ -697,6 +699,32 @@ def train_step_output_specs(cfg: TransformerConfig, dp="dp", tp=None,
     return pspecs, P()
 
 
+class _SpannedStep:
+    """The compiled training step as its callers dispatch it: every call
+    is one ``train.step`` ``profiler.span`` (``os=True``; args ``step``,
+    this callable's own count of dispatches, and ``steps`` for a
+    ``scan_steps`` loop) around the dispatch.  The span ends when the
+    dispatch returns, not when the device has run the step: nothing
+    waits here, and the step's cadence is read from one span's start to
+    the next (``profiler.stalls("train.step")``).  Everything else a
+    caller reaches on the jitted function (``lower``, ``trace``,
+    ``eval_shape``, ...) is the jitted function's own."""
+
+    def __init__(self, jitted, **args):
+        self._jitted = jitted
+        self._args = args
+        self._dispatched = 0
+
+    def __call__(self, state, batch, rng):
+        with profiler.span("train.step", os=True, step=self._dispatched,
+                           **self._args):
+            self._dispatched += 1
+            return self._jitted(state, batch, rng)
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
+
+
 def make_train_step(cfg: TransformerConfig, mesh=None, learning_rate=1e-4,
                     weight_decay=0.01, shard_optimizer=False,
                     scan_steps=None, scan_superbatch=False, fsdp=False,
@@ -896,7 +924,7 @@ def make_train_step(cfg: TransformerConfig, mesh=None, learning_rate=1e-4,
         jit_kw = dict(donate_argnums=(0,))
 
     if scan_steps is None:
-        return init_state, jax.jit(step, **jit_kw)
+        return init_state, _SpannedStep(jax.jit(step, **jit_kw))
 
     def multi(state, batch, rng):
         def body(st, i):
@@ -905,7 +933,8 @@ def make_train_step(cfg: TransformerConfig, mesh=None, learning_rate=1e-4,
             return step(st, b, jax.random.fold_in(rng, i))
         return jax.lax.scan(body, state, jnp.arange(scan_steps))
 
-    return init_state, jax.jit(multi, **jit_kw)
+    return init_state, _SpannedStep(jax.jit(multi, **jit_kw),
+                                    steps=scan_steps)
 
 
 

@@ -15,7 +15,10 @@ the reference; compiled (jit) regions and on-device timing come from
 ``jax.profiler`` trace (on the clock of the device's own events) while
 such a session runs, in the bounded in-memory ring
 (:func:`recent_spans`) always, and in the chrome-trace stream while
-the profiler records.
+the profiler records.  ``span(name, os=True)`` also keeps what the OS
+says of the thread at both ends (CPU clocks, context switches, page
+faults, run-queue wait), and :func:`stalls` reads the ring for the
+turns that took too long and puts each down to a cause.
 """
 from __future__ import annotations
 
@@ -23,10 +26,16 @@ import collections
 import itertools
 import json
 import os
+import statistics
 import threading
 import time
 from collections import defaultdict
 from typing import Dict, List, Optional
+
+try:
+    import resource
+except ImportError:         # no such module off Unix: those readings None
+    resource = None
 
 from jax.profiler import TraceAnnotation
 
@@ -37,7 +46,7 @@ __all__ = ["set_config", "set_state", "state", "dump", "dumps", "pause",
            "resume", "memory_stats", "Task", "Frame", "Event", "Counter",
            "Marker", "now_us", "is_recording", "record_events",
            "events_generation", "span", "recent_spans", "Span",
-           "SPAN_PREFIX"]
+           "SPAN_PREFIX", "OS_FIELDS", "stalls"]
 
 _lock = threading.Lock()
 _config = {
@@ -110,6 +119,63 @@ _spans = collections.deque(maxlen=65536)
 _span_ids = itertools.count(1)
 _open_spans = threading.local()
 
+#: What ``span(name, os=True)`` reads of the calling thread, in this
+#: order, at both ends of the span (``args["os0"]``, ``args["os1"]``).
+#: Every one is CUMULATIVE over the thread's (the second: the
+#: process's) life, so a reader takes the difference inside a span and
+#: also from the end of one span to the start of the next on the same
+#: thread.  ``span(name, cpu=True)`` keeps the first two only
+#: (``cpu0``, ``cpu1``).  A source this platform lacks leaves None.
+OS_FIELDS = ("thread_cpu_s", "process_cpu_s", "vol_switches",
+             "invol_switches", "minor_faults", "major_faults",
+             "runq_wait_s")
+_NO_SOURCE = (AttributeError, OSError, ValueError, TypeError, IndexError)
+_schedstat = threading.local()    # .file, .fd: this thread's, kept open
+
+
+def _open_schedstat():
+    """This thread's ``/proc/thread-self/schedstat``, opened once and kept
+    (the file object dies, and closes, with the thread's
+    ``threading.local``); None where there is no such file."""
+    try:
+        f = open("/proc/thread-self/schedstat", "rb", buffering=0)
+    except OSError:
+        f = None
+    _schedstat.file = f
+    _schedstat.fd = None if f is None else f.fileno()
+    return _schedstat.fd
+
+
+def _cpu_reading():
+    """``OS_FIELDS[:2]``, now."""
+    try:
+        thread = time.thread_time()
+    except _NO_SOURCE:
+        thread = None
+    try:
+        return thread, time.process_time()
+    except _NO_SOURCE:
+        return thread, None
+
+
+def _os_reading():
+    """``OS_FIELDS``, now: four calls into the kernel."""
+    try:
+        ru = resource.getrusage(resource.RUSAGE_THREAD)
+        counts = ru.ru_nvcsw, ru.ru_nivcsw, ru.ru_minflt, ru.ru_majflt
+    except _NO_SOURCE:
+        counts = None, None, None, None
+    try:
+        fd = _schedstat.fd
+    except AttributeError:
+        fd = _open_schedstat()
+    try:
+        # "<ns on a CPU> <ns runnable, waiting for one> <timeslices>"
+        runq = int(os.pread(fd, 64, 0).split()[1]) * 1e-9
+    except _NO_SOURCE:
+        runq = None
+    return _cpu_reading() + counts + (runq,)
+
 
 class span:
     """``with profiler.span("engine.plan", rows=3) as s: ...`` — one
@@ -127,15 +193,26 @@ class span:
 
     ``s.set(**args)`` adds arguments known only once the span is open;
     ``s.t0`` / ``s.t1`` are its ends in ``time.perf_counter()``
-    seconds."""
+    seconds.
+
+    ``os=True`` reads :data:`OS_FIELDS` when the span opens and when it
+    closes and keeps the two tuples in the ``Span``'s ``args`` (``os0``,
+    ``os1``); ``cpu=True`` keeps the two CPU clocks only (``cpu0``,
+    ``cpu1``).  The trace annotation gets neither: the device trace
+    needs none of it."""
 
     __slots__ = ("name", "cat", "args", "id", "parent", "t0", "t1",
-                 "_annotation", "_stack")
+                 "_annotation", "_stack", "_reads")
+    _READS = {"os": (_os_reading, "os0", "os1"),
+              "cpu": (_cpu_reading, "cpu0", "cpu1")}
 
-    def __init__(self, name: str, cat: str = "span", **args):
+    def __init__(self, name: str, cat: str = "span", os: bool = False,
+                 cpu: bool = False, **args):
         self.name = name
         self.cat = cat
         self.args = args
+        self._reads = self._READS["os" if os else "cpu"] \
+            if os or cpu else None
 
     def __enter__(self):
         try:
@@ -149,6 +226,8 @@ class span:
         self._annotation = TraceAnnotation(SPAN_PREFIX + self.name,
                                            **self.args)
         self._annotation.__enter__()
+        if self._reads is not None:
+            self.args[self._reads[1]] = self._reads[0]()
         self.t0 = time.perf_counter()
         return self
 
@@ -158,6 +237,8 @@ class span:
 
     def __exit__(self, *exc):
         self.t1 = time.perf_counter()
+        if self._reads is not None:
+            self.args[self._reads[2]] = self._reads[0]()
         self._annotation.__exit__(*exc)
         stack = self._stack     # its own thread's, wherever it ends
         if stack[-1] == self.id:
@@ -182,6 +263,166 @@ def recent_spans() -> List[Span]:
     """A copy of the ring: the last 65,536 finished spans of every
     thread, in order of their END."""
     return list(_spans)
+
+
+# ---------------------------------------------------------------------------
+# Stalls: the turns of the ring that took too long, each with a cause
+# ---------------------------------------------------------------------------
+
+_WAIT = "engine.wait"       # the one phase in which the host waits for the device
+
+
+def _os_delta(a, b, wall):
+    """What happened on the thread between two readings ``wall`` seconds
+    apart (``OS_FIELDS`` tuples, or their first two): the fields'
+    differences, with ``offcpu_s`` (wall less the thread's CPU) and
+    ``others_cpu_s`` (the process's CPU less the thread's: what the
+    runtime's other threads burnt) in place of ``process_cpu_s``.  A
+    field either side lacks is None; no readings, only ``wall_s``."""
+    out = {"wall_s": wall}
+    if a is None or b is None:
+        return out
+    for field, x, y in zip(OS_FIELDS, a, b):
+        out[field] = None if x is None or y is None else y - x
+    cpu, process = out.pop("thread_cpu_s"), out.pop("process_cpu_s")
+    out["cpu_s"] = cpu
+    out["offcpu_s"] = None if cpu is None else max(0.0, wall - cpu)
+    out["others_cpu_s"] = None if cpu is None or process is None \
+        else max(0.0, process - cpu)
+    return out
+
+
+def stalls(name: str = "engine.step", factor: float = 1.5,
+           since: Optional[float] = None) -> List[dict]:
+    """The stalled turns of the spans called ``name``, from the ring.  A
+    turn runs from the start of one such span to the start of the next
+    on its thread (the last one's to its own end); it stalled if it
+    lasted more than ``factor`` medians of its thread's turns.  ``since``
+    (``time.perf_counter()`` seconds) leaves out the spans that began
+    before it.  One dict a stall, in order of start:
+
+    ``t0``, ``thread``, ``args`` (the span's own, readings left out);
+    ``turn_s``, ``span_s``, ``median_turn_s``, ``excess_s`` (the turn
+    less the median); ``in_span`` and ``after`` (from the span's end to
+    the next one's start; None for the last), each :func:`_os_delta`'s
+    dict: ``wall_s``, ``cpu_s`` / ``offcpu_s`` of the thread,
+    ``others_cpu_s``, switches, faults, ``runq_wait_s``, as far as the
+    spans carry readings (``os=True``); ``children``: ``{name: dict}`` of
+    the leaf spans below it, each ``wall_s``, ``excess_s`` over that
+    child's median and, from ``cpu=True``, ``cpu_s`` / ``offcpu_s`` /
+    ``others_cpu_s``; ``next_wait_s`` and ``median_wait_s``: the NEXT
+    span's ``engine.wait`` and the median one; and the verdict
+    (docs/observability.md, "Reading a stall"):
+
+    ``where``: the child, ``"between"`` (after the span, before the
+    next) or ``"self"`` (in the span, in no child) whose time grew most;
+    ``cause``: ``"device_late"`` or ``"readback_late"`` (in
+    ``engine.wait``: the next wait normal, or under half the median: the
+    device had run ahead), else ``"host_ran"`` (the thread was on a CPU
+    for half of that time or more), ``"host_preempted"`` (off it,
+    runnable: the run-queue wait is half of the time off or more),
+    ``"host_blocked"`` (off it, asleep), ``"host_off_cpu"`` (off it, and
+    the platform keeps no run-queue wait to say which) or ``"host"`` (no
+    readings);
+    ``runtime``: ``"busy"`` where the process's OTHER threads burnt half
+    the excess or more in CPU, ``"idle"`` where less (the whole process
+    slept), None without readings."""
+    ring = list(_spans)
+    by_id = {s.id: s for s in ring}
+    parents = {s.parent for s in ring}
+    threads = defaultdict(list)
+    for s in ring:
+        if s.name == name and (since is None or s.t0 >= since):
+            threads[s.thread].append(s)
+    below = defaultdict(dict)       # id of a named span -> its leaves
+    for s in ring:
+        if s.id in parents or s.name == name:
+            continue
+        up = by_id.get(s.parent)
+        while up is not None and up.name != name:
+            up = by_id.get(up.parent)
+        if up is not None:
+            kid = below[up.id].setdefault(s.name, {"wall_s": 0.0})
+            kid["wall_s"] += s.t1 - s.t0
+            delta = _os_delta(s.args.get("cpu0"), s.args.get("cpu1"),
+                              s.t1 - s.t0)
+            for k in ("cpu_s", "offcpu_s", "others_cpu_s"):
+                if delta.get(k) is not None:
+                    kid[k] = kid.get(k, 0.0) + delta[k]
+    found = []
+    for mine in threads.values():
+        mine.sort(key=lambda s: s.t0)
+        turns = [nxt.t0 - s.t0 for s, nxt in zip(mine, mine[1:])] \
+            + [mine[-1].t1 - mine[-1].t0]
+        # a turn in parts: each child, "self", "between"
+        parts = []
+        for s, turn in zip(mine, turns):
+            walls = {k: v["wall_s"] for k, v in below[s.id].items()}
+            walls["self"] = (s.t1 - s.t0) - sum(walls.values())
+            walls["between"] = turn - (s.t1 - s.t0)
+            parts.append(walls)
+        names = set().union(*parts)
+        medians = {k: statistics.median(p.get(k, 0.0) for p in parts)
+                   for k in names}
+        median = statistics.median(turns)
+        for i, (s, turn, walls) in enumerate(zip(mine, turns, parts)):
+            if turn <= factor * median:
+                continue
+            nxt = mine[i + 1] if i + 1 < len(mine) else None
+            span_s = s.t1 - s.t0
+            in_span = _os_delta(s.args.get("os0"), s.args.get("os1"), span_s)
+            after = None if nxt is None else _os_delta(
+                s.args.get("os1"), nxt.args.get("os0"), turn - span_s)
+            kids = {k: dict(v, excess_s=v["wall_s"] - medians[k])
+                    for k, v in below[s.id].items()}
+            where = max(walls, key=lambda k: walls[k] - medians[k])
+            next_wait = None if nxt is None else \
+                below[nxt.id].get(_WAIT, {}).get("wall_s")
+            whole = _os_delta(
+                s.args.get("os0"),
+                s.args.get("os1") if nxt is None else nxt.args.get("os0"),
+                turn)
+            # the thread's CPU and wall time in the part that grew: its own
+            # where it carries readings (a child with ``cpu=True``, what
+            # follows the span), else the span's less such children's
+            known = {k: v["cpu_s"] for k, v in kids.items() if "cpu_s" in v}
+            if where == "between":
+                cpu, wall = (after or {}).get("cpu_s"), walls[where]
+            elif where in known:
+                cpu, wall = known[where], walls[where]
+            else:
+                cpu = in_span.get("cpu_s")
+                if cpu is not None:
+                    cpu -= sum(known.values())
+                wall = span_s - sum(walls[k] for k in known)
+            runq = whole.get("runq_wait_s")
+            if where == _WAIT:
+                collapsed = next_wait is not None \
+                    and next_wait < 0.5 * medians[_WAIT]
+                cause = "readback_late" if collapsed else "device_late"
+            elif cpu is None:
+                cause = "host"
+            elif cpu >= 0.5 * wall:
+                cause = "host_ran"
+            elif runq is None:
+                cause = "host_off_cpu"
+            else:
+                cause = "host_preempted" if runq >= 0.5 * (wall - cpu) \
+                    else "host_blocked"
+            others = whole.get("others_cpu_s")
+            found.append({
+                "t0": s.t0, "thread": s.thread,
+                "args": {k: v for k, v in s.args.items()
+                         if k not in ("os0", "os1", "cpu0", "cpu1")},
+                "turn_s": turn, "span_s": span_s, "median_turn_s": median,
+                "excess_s": turn - median, "in_span": in_span,
+                "after": after, "children": kids,
+                "next_wait_s": next_wait,
+                "median_wait_s": medians.get(_WAIT),
+                "where": where, "cause": cause,
+                "runtime": None if others is None else
+                "busy" if others >= 0.5 * (turn - median) else "idle"})
+    return sorted(found, key=lambda r: r["t0"])
 
 
 def _op_hook(event: str, name: str):

@@ -1832,7 +1832,7 @@ class ServingEngine:
             # no step
             if self._inflight is None and self._idle():
                 return False
-        with profiler.span("engine.step",
+        with profiler.span("engine.step", os=True,
                            step=self.stats["steps"]) as sp:
             with profiler.span("engine.plan"), self._mu:
                 plan = self._build_plan()
@@ -1876,7 +1876,7 @@ class ServingEngine:
         """Block on a dispatched step's sampled tokens and commit it.
         At depth 1 this runs AFTER the next step was dispatched — the
         readback waits out step N's tail while N+1 executes."""
-        with profiler.span("engine.wait") as wait:
+        with profiler.span("engine.wait", cpu=True) as wait:
             # mxlint: allow(host-sync) -- intentional: the ONE device
             # sync per step — at depth 1 one step BEHIND dispatch (the
             # latency-hiding point); the host branches on step N's

@@ -531,6 +531,12 @@ def test_benchmark_json_names_the_cell_and_its_metrics():
     cell = {"name": CELL, "bench": bench}
     per_layer = [m["name"] for m in chipbench_run.metrics_for(
         cell, "per_layer")]
+    # (the last four: what a stall was, every serving cell's, PR 38)
+    assert per_layer[-4:] == ["turn_stall_max_ms.serve",
+                              "stall_offcpu_share.serve",
+                              "stall_host_late_share.serve",
+                              "stall_runtime_busy_share.serve"]
+    per_layer = per_layer[:-4]
     assert per_layer[-5:] == ["moe_expert_bw_share.serve",
                               "latent_read_bw_share.serve",
                               "moe_rows_per_expert.serve",

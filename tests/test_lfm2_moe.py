@@ -665,12 +665,19 @@ def test_benchmark_json_names_the_cell_and_its_metrics():
     cell = {"name": CELL, "bench": bench}
     per_layer = [m["name"] for m in chipbench_run.metrics_for(
         cell, "per_layer")]
+    # (the last four: what a stall was, every serving cell's, PR 38;
+    # two more of the training cell close ``per_layer``)
+    assert per_layer[-4:] == ["turn_stall_max_ms.serve",
+                              "stall_offcpu_share.serve",
+                              "stall_host_late_share.serve",
+                              "stall_runtime_busy_share.serve"]
+    per_layer = per_layer[:-4]
     assert per_layer[-5:] == ["moe_expert_bw_share.serve",
                               "moe_rows_per_expert.serve",
                               "kv_chain_fill_share.serve",
                               "moe_load_max_ratio.serve",
                               "moe_weight_fetch_ratio.serve"]
-    assert bench["per_layer"][-2] == {
+    assert bench["per_layer"][-8] == {
         "name": "moe_load_max_ratio.serve", "unit": "x", "better": "lower",
         "source": "program_counter", "layer": "expert layer",
         "moves": "serve_tok_s", "workloads": [CELL]}

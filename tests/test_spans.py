@@ -205,8 +205,10 @@ def test_engine_step_encloses_the_five_phases(overlap):
         assert all(a.t1 <= b.t0 for a, b in zip(kids, kids[1:]))
         assert sum(k.t1 - k.t0 for k in kids) <= st.t1 - st.t0
         assert st.parent == 0
+        # beside what the step says of its plan, what the OS says of
+        # its thread (tests/test_stall_forensics.py)
         assert set(st.args) == {"step", "decode", "prefill", "dead",
-                                "pages"}
+                                "pages", "os0", "os1"}
         assert st.args["decode"] + st.args["prefill"] + st.args["dead"] \
             == eng.n_rows
         # the gather path (this engine's, on CPU) reads every row's
@@ -409,7 +411,9 @@ def _without_scopes(monkeypatch):
 @pytest.mark.parametrize("program", ["train", "engine"])
 def test_scopes_change_nothing_the_compiler_emits(program, monkeypatch):
     """The compiled step programs with the scopes are, metadata
-    stripped, the programs without them."""
+    stripped, the programs without them; and the training step, which
+    callers dispatch through its ``train.step`` span, lowers to the text
+    of the jitted function inside, the program of before the span."""
     from mxnet_tpu.models import gpt as G, transformer as T
     from mxnet_tpu.serving import engine as E
 
@@ -419,6 +423,12 @@ def test_scopes_change_nothing_the_compiler_emits(program, monkeypatch):
             else _engine_step(E, G, T)
 
     with_scopes = build()
+    if program == "train":
+        monkeypatch.setattr(T, "_SpannedStep", lambda jitted, **args: jitted)
+        bare = build()
+        monkeypatch.undo()
+        assert with_scopes.as_text() == bare.as_text()
+        assert _stripped(with_scopes) == _stripped(bare)
     assert "attn_out" in with_scopes.as_text(debug_info=True) \
         or "mlm_head" in with_scopes.as_text(debug_info=True)
     _without_scopes(monkeypatch)
