@@ -37,12 +37,10 @@ in.  The slots that a block's last trip has no item for fold what
 they hold, masked, into a spare row of the state (a guard a slot
 would cost every item a basic block's boundary, a guarded extra trip
 a second copy of the loop's body to trace and lower at every
-start-up).  G, F, R and K follow from the shapes
-(``walk_geometry``): for the cell's bf16 pool of 16 heads of 64,
-pages are 64 KiB, G = 8 (four 512 KiB slots), F = G, R = 32, K = 4.
-The flat column fold keeps the older loop: per row a ``fori_loop`` over
-its groups, one group copied ahead into the other of two slots, the
-state carried from group to group.
+start-up).  G, R and K follow from the shapes (``walk_geometry``):
+for the cell's bf16 pool of 16 heads of 64, pages are 64 KiB, G = 8
+(four 512 KiB slots), R = 32, K = 4.  Every pool the walk takes goes
+through this one loop.
 
 **The dense fold** (``_fold_dense``, PR 31) is what the walk folds a
 ``(ps, H, 2*dh)`` pool with, a whole group a turn.  A page's rows,
@@ -63,16 +61,18 @@ head's k then v side by side on the lanes, tokens on the sublanes
 (``serving/paged_kv.py`` chooses the layout and writes it,
 ``write_rows``; ``pool_kv.ndim == 3`` tells it here).  With 4 heads the ``(ps, H, 2*dh)`` page's two minor
 dims would be no whole tiles; the flat page is, so the same walk cuts
-it out of HBM with the same copies.  ``_fold_flat`` is the same
-recurrence with the roles of the axes turned: a head's scores are a
-column over the page's tokens, its k and v whole lane tiles read by
-each of the ``Hq / Hkv`` query heads that share them.  Where a head's
-``[k | v]`` pair is ONE lane tile (heads of 64, PR 36: there the
-column fold read 80 GB/s, a lane reduction a query head a turn of two
-pages under 32 query heads) the flat pool goes through the ring with
-the dense form, ``_fold_flat_dense``: a pair the MXU's weights as it
+it out of HBM with the same copies.  The walk folds it with
+``_fold_flat_dense``: a key/value head's ``[k | v]`` pair, whole lane
+tiles (one at heads of 64, two at 128), is the MXU's weights as it
 lies in VMEM, every query head against it, the rows of the heads that
-share it kept; the scores one tile, heads by tokens.
+share it kept; the scores one tile, heads by tokens.  ``_fold_flat``,
+the column form, is the same recurrence with the roles of the axes
+turned: a head's scores are a column over the page's tokens, its k and
+v cut out of the page and read by each of the ``Hq / Hkv`` query heads
+that share them, one lane reduction on the XLU a query head and turn.
+It is the per-page grid's fold for flat pools; bound by those
+reductions, it walked pages three to six times slower than the dense
+form wherever a pair is whole lane tiles (PERF.md).
 
 **Latent pools** (PR 32).  Multi-head latent attention caches ONE row a
 token, ``[c_kv (rank) | rotated k_pe (rope)]``, shared by every query
@@ -86,20 +86,23 @@ scale is the model's (``scale=``) and multiplies the float32 scores;
 there is no head size to take a root of.  Only the walk folds it.
 
 **Which pool takes which feeder** (``walk_geometry`` decides, from
-shapes alone).  The walk: every 32-bit pool; 16-bit ``(ps, H, 2*dh)``
-pools whose head count is a multiple of 8 (the benchmark's BERT
-cells, 16 heads); flat 16-bit pools whose pages hold a multiple of 16
-tokens and of 128 lanes (the Falcon-H1 cell, 4 x 2 x 128 lanes at 16
-tokens).  **The per-page grid** (``_page_kernel``), the older feeder
-of the same folds — grid (T, PP), the BlockSpec index map streams
-page ``bt[t, j]`` per grid step, pages past the position skipped by
-``pl.when`` after their copy was paid — serves what is left, because
-this Mosaic refuses a ``memref_slice`` whose two minor dims are not
-whole tiles even where it takes them whole: 16-bit pools whose head
-count is no multiple of 8 (the ``full`` preset's 12 and its H/tp
-slices 6 and 3), flat 16-bit pools with 8-token pages, and int8
-pools, whose ``(ps, H)`` scale planes are never whole tiles (ROADMAP
-C records the debt).  int8-KV pages dequantize inside the fold — the
+shapes alone).  The walk: every 32-bit ``(ps, H, 2*dh)`` pool; 16-bit
+ones whose head count is a multiple of 8 (the benchmark's BERT cells,
+16 heads); flat pools whose pages fill the sublanes (16 tokens of 16
+bits, 8 of 32) and whose heads' ``[k | v]`` pairs are whole lane tiles
+(the Falcon-H1 cell, 4 x 256 lanes at 16 tokens; the LFM2 cell, 8 x
+128).  **The per-page grid** (``_page_kernel``), the older feeder
+of the same column folds — grid (T, PP), the BlockSpec index map
+streams page ``bt[t, j]`` per grid step, pages past the position
+skipped by ``pl.when`` after their copy was paid — serves what is
+left, because this Mosaic refuses a ``memref_slice`` whose two minor
+dims are not whole tiles even where it takes them whole: 16-bit pools
+whose head count is no multiple of 8 (the ``full`` preset's 12 and
+its H/tp slices 6 and 3), flat 16-bit pools with 8-token pages, and
+int8 pools, whose ``(ps, H)`` scale planes are never whole tiles
+(ROADMAP C records the debt); and flat pools whose heads' pairs split
+a lane tile (heads of 32 or 96), which the dense form cannot take as
+the MXU's weights.  int8-KV pages dequantize inside the fold — the
 k scale multiplies the scores, the v scale folds into the softmax
 weights, exactly where ``_attend_rows`` folds them — reading the
 round-22 TILE-SHAPED scale pages: ``(pages, 2, ps, H)`` f32 planes (k
@@ -167,12 +170,6 @@ __all__ = ["paged_attention", "paged_attention_reference"]
 # pages as fit (the ring's _RING slots are live, so the walk's buffers
 # are that many times this, beside the f32 temporaries of one fold)
 _GROUP_BYTES = 512 * 1024
-# rows of the step a grid step walks: the first copy of a grid step
-# has nothing to hide behind, so its latency is paid once per _ROWS
-# rows; q and the output block over it and stay small
-_ROWS = 16
-
-
 # rows a grid step of the LATENT walk takes: its q and output blocks
 # are 64 heads wide (R x H x (W + rank) values, double-buffered)
 _ROWS_LATENT = 8
@@ -190,20 +187,16 @@ def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False,
     """``(G, F, R, K)`` of the walk for one pool geometry — ``G`` pages
     are copied per DMA group (as many whole pages as ``_GROUP_BYTES``
     holds, at least one, at most a row's table), ``F`` of them are
-    folded per turn of the inner loop (the whole group under the dense
-    fold, whose fixed cost, the MXU's latency twice over, is then paid
-    once a group; two a turn under the flat fold where G is even: a
-    row's last turn folds at most one page it did not need), ``R``
-    rows are walked per grid step, ``K`` groups are taken per trip of
-    the walk's loop, each folded by a chain of its own out of its own
-    slot of a ring of K copies (``_RING`` where the fold is one MXU
-    turn a group, F == G: while one group is folded, K - 1 are on
-    their way; 1 under the flat fold, whose turns are bound by the
-    XLU's lane reductions and keep their loop of one group a trip
-    over two slots) — or ``None`` where Mosaic cannot cut whole pages
-    out of the pool and the per-page grid serves instead: a
-    ``memref_slice`` of an HBM ref must be whole tiles in its two
-    minor dims even where it takes them whole.
+    folded per turn (F = G: every fold is one MXU turn a group, whose
+    fixed cost, the MXU's latency twice over, is then paid once a
+    group), ``R`` rows are walked per grid step, ``K`` groups are taken
+    per trip of the walk's loop, each folded by a chain of its own out
+    of its own slot of a ring of K copies (``_RING``: while one group
+    is folded, K - 1 are on their way) — or ``None`` where the per-page
+    grid serves instead: where Mosaic cannot cut whole pages out of the
+    pool (a ``memref_slice`` of an HBM ref must be whole tiles in its
+    two minor dims even where it takes them whole), or where a flat
+    pool's fold would have to cut inside a lane tile.
 
     ``H`` is the pool's head count (the key/value heads), ``flat``
     which of the two page layouts it has:
@@ -215,13 +208,11 @@ def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False,
       scale planes never are;
     * ``(page_size, H*2*dh)`` (``flat``: grouped-query pools, few
       key/value heads): whole tiles when the tokens fill the sublanes
-      (8 rows of 32 bits, 16 of 16) and the heads' lanes are a
-      multiple of 128 — 4 heads of 128 in bf16 at 16-token pages walk.
-      Where a head's ``[k | v]`` pair is ONE lane tile (heads of 64:
-      PR 36) the flat pool takes the ring and the dense form of its
-      fold (``_fold_flat_dense``); at heads of 128 the column fold and
-      its loop stay until a PR measures the switch in the cell that
-      runs it (ROADMAP A12 (b)).
+      (8 rows of 32 bits, 16 of 16); the walk's fold
+      (``_fold_flat_dense``) takes each head's ``[k | v]`` pair whole
+      as the MXU's weights, so the pair must be whole lane tiles, ``2
+      * dh`` a multiple of 128: heads of 64, 128 and 256 walk, heads
+      of 32 or 96 go to the per-page grid and its column fold.
 
     ``latent``: a flat pool of one shared row a token (``H`` 1,
     ``2*dh`` the padded row): the flat page's rule; the whole group is
@@ -236,7 +227,7 @@ def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False,
     if kv_dtype == np.int8:
         return None
     if flat or latent:
-        if page_size % (32 // kv_dtype.itemsize) or (H * 2 * dh) % 128:
+        if page_size % (32 // kv_dtype.itemsize) or (2 * dh) % 128:
             return None
     elif kv_dtype.itemsize < 4 and H % 8:
         return None
@@ -245,15 +236,7 @@ def walk_geometry(H, dh, page_size, PP, kv_dtype, flat=False,
     if latent:
         G -= G % 8 if G > 8 else 0
         return G, G, _ROWS_LATENT, _RING
-    if flat and 2 * dh != 128:
-        return G, 2 - G % 2, _ROWS, 1
     return G, G, _ROWS_RING, _RING
-
-
-def _ring(geometry):
-    """Whether the walk of this geometry folds whole groups out of the
-    ring (its folds take the query zero-extended over the v lanes)."""
-    return geometry is not None and geometry[3] > 1
 
 
 def _scale_folds(dh):
@@ -480,27 +463,23 @@ def _fold_latent(kv, q, m, l, acc, k0, pos, dh, cdt, *, rank, scale):
 
 
 def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, *scratch,
-                 page_size, dh, T, PP, G, F, R, K, flat, latent=None,
+                 page_size, dh, T, PP, G, R, K, flat, latent=None,
                  scale=None):
     """Grid over blocks of R rows; the pool stays in HBM.  A grid step's
     work is the flat sequence of its rows' live groups of G pages, the
     (row, group) ITEMS, bounded by each row's own position.
 
-    The ring (``K`` > 1: ``_fold_dense``; ``latent`` pools, ``latent``
-    the rank and ``scale`` the softmax's, ``_fold_latent``): item i
-    lives in VMEM slot i mod K, a buffer of its own.  A cursor copies
-    K - 1 items ahead of the fold; a trip of the loop takes K items,
-    slot by static slot: the item's copies waited for, the item K - 1 on
-    copied into the slot the item before left, the whole group folded at
-    once (F = G) FROM A FRESH (m, l, acc), and that partial merged into
-    its row's running ``state`` (m, l, acc: VMEM, R rows and a spare
-    one for the slots a block's last trip has no item for); the rows
-    are normalised and written when the grid step's items are in.
-
-    One item a trip (``flat`` pools): per row a loop over its groups,
-    group g+1 (or the next row's first) copied into one of two slots
-    while group g is folded out of the other, two pages a turn with
-    ``_fold_flat``, (m, l, acc) carried from group to group."""
+    The ring (``_fold_dense``; ``flat`` pools ``_fold_flat_dense``;
+    ``latent`` pools, ``latent`` the rank and ``scale`` the softmax's,
+    ``_fold_latent``): item i lives in VMEM slot i mod K, a buffer of
+    its own.  A cursor copies K - 1 items ahead of the fold; a trip of
+    the loop takes K items, slot by static slot: the item's copies
+    waited for, the item K - 1 on copied into the slot the item before
+    left, the whole group folded at once FROM A FRESH (m, l, acc), and
+    that partial merged into its row's running ``state`` (m, l, acc:
+    VMEM, R rows and a spare one for the slots a block's last trip has
+    no item for); the rows are normalised and written when the grid
+    step's items are in."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -512,81 +491,25 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, *scratch,
     row0 = pl.program_id(0) * R
     # rows of this grid step that exist (the last block may be short)
     n_rows = jnp.minimum(R, T - row0)
-    if K == 1:
-        buf, sem = scratch
-        slots = [buf]
-    else:
-        # a buffer a slot: the compiler then sees that a copy into one
-        # slot and a fold out of another touch nothing in common, and
-        # runs the scalar code of the one under the other
-        slots, sem, state = scratch[:K], scratch[K], scratch[K + 1:]
-
-    def last_page(t):
-        # the page that holds the row's own position: the walk's one
-        # bound.  A dead row (pos 0, all-zero table row) walks the
-        # scratch page and nothing else
-        return jnp.minimum(pos_ref[t] // ps, PP - 1)
+    # a buffer a slot: the compiler then sees that a copy into one slot
+    # and a fold out of another touch nothing in common, and runs the
+    # scalar code of the one under the other
+    slots, sem, state = scratch[:K], scratch[K], scratch[K + 1:]
 
     def copy(page, slot, i):
-        dst = buf.at[slot, i] if K == 1 else slots[slot].at[i]
-        return pltpu.make_async_copy(kv_hbm.at[page], dst, sem.at[slot, i])
+        return pltpu.make_async_copy(kv_hbm.at[page], slots[slot].at[i],
+                                     sem.at[slot, i])
 
-    def start(t, g, slot):
-        # row t's group g: whole pages, HBM to VMEM slot ``slot``;
-        # pages past the row's position are not copied
-        last = last_page(t)
+    def start(t, slot):
+        # row t's first group: whole pages, HBM to VMEM slot ``slot``;
+        # pages past the row's position (the walk's one bound: a dead
+        # row, pos 0 and an all-zero table row, walks the scratch page
+        # and nothing else) are not copied
+        last = jnp.minimum(pos_ref[t] // ps, PP - 1)
         for i in range(G):
-            j = g * G + i
-
-            @pl.when(j <= last)
+            @pl.when(i <= last)
             def _():
-                copy(bt_ref[t * PP + j], slot, i).start()
-
-    def row(r, slot):
-        # ``slot`` holds (or is receiving) this row's first group
-        t = row0 + r
-        pos = pos_ref[t]
-        last = last_page(t)
-        n_groups = last // G + 1
-        q = _scaled(q_ref[r], dh)                      # (H, dh)
-
-        def group(g, carry):
-            m, l, acc, slot = carry
-            more = g + 1 < n_groups
-            t_nxt = jnp.where(more, t, jnp.minimum(t + 1, T - 1))
-            g_nxt = jnp.where(more, g + 1, 0)
-
-            @pl.when(more | (r + 1 < n_rows))
-            def _():
-                start(t_nxt, g_nxt, 1 - slot)
-
-            def turn(c, carry):
-                # F pages a turn; those past the row's last were not
-                # copied and hold older pages (or the zeros below):
-                # finite, and masked by position like any tail
-                for f in range(F):
-                    @pl.when(g * G + c * F + f <= last)
-                    def _():
-                        # a wait goes by the copy's slot and size, not
-                        # by its source
-                        copy(0, slot, c * F + f).wait()
-                kv = buf[slot, pl.ds(c * F, F)]
-                return _fold_flat(kv.reshape((F * ps,) + kv.shape[2:]), q,
-                                  *carry, (g * G + c * F) * ps, pos, dh,
-                                  q_ref.dtype)
-
-            n_pages = jnp.minimum(G, last + 1 - g * G)
-            m, l, acc = jax.lax.fori_loop(0, (n_pages + F - 1) // F,
-                                          turn, (m, l, acc))
-            return m, l, acc, 1 - slot
-
-        init = ((jnp.full((1, 1), -jnp.inf, f32),) * H,
-                (jnp.zeros((1, 1), f32),) * H,
-                (jnp.zeros((1, dh), f32),) * H, slot)
-        _, l, acc, slot = jax.lax.fori_loop(0, n_groups, group, init)
-        for i in range(H):
-            o_ref[r, pl.ds(i, 1), :] = (acc[i] / l[i]).astype(o_ref.dtype)
-        return slot
+                copy(bt_ref[t * PP + i], slot, i).start()
 
     # -- the ring's cursor: an item is (r, g, last): row r of the grid
     # step, its group g, and the row's last page, -1 (no page) for the
@@ -685,7 +608,7 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, *scratch,
             head, tail = after(head), after(tail)
         return head, tail
 
-    if F > 1:
+    if G > 1:
         @pl.when(pl.program_id(0) == 0)
         def _():
             # what a turn may fold without having copied it must not
@@ -693,10 +616,7 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, *scratch,
             for b in slots:
                 b[...] = jnp.zeros_like(b)
 
-    start(row0, 0, 0)
-    if K == 1:
-        jax.lax.fori_loop(0, n_rows, row, 0)
-        return
+    start(row0, 0)
     n_items = lax.fori_loop(
         0, n_rows, lambda r, n: n + lax.div(row_last(r), i32(G)) + 1, i32(0))
     head = (i32(0), i32(0), row_last(i32(0)))
@@ -792,13 +712,14 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
 
     flat = Hkv is not None
     # a page as the pool holds it, and a row's queries: zero-extended
-    # over the v lanes for the (H, 2*dh) fold, bare for the flat one
+    # over the v lanes for the walk's folds and the (H, 2*dh) column
+    # fold, bare for the flat column fold
     page = (page_size, Hkv * 2 * dh) if flat else (page_size, H, 2 * dh)
     ow = latent or dh
     zeros = (0,) * len(page)
     geometry = walk_geometry(Hkv if flat else H, dh, page_size, PP,
                              kv_dtype, flat=flat, latent=bool(latent))
-    qw = 2 * dh if latent or not flat or _ring(geometry) else dh
+    qw = 2 * dh if latent or not flat or geometry else dh
     if latent and geometry is None:
         raise ValueError(
             "paged_attention: a latent pool is folded by the page walk "
@@ -807,7 +728,7 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
             "lanes a multiple of 128)"
             % (page_size, kv_dtype, 2 * dh))
     if geometry is not None:
-        G, F, R, K = geometry
+        G, _, R, K = geometry
         R = min(R, T)
         grid = (-(-T // R),)
         in_specs = [
@@ -816,19 +737,15 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
         ]
         out_specs = pl.BlockSpec((R, H, ow),
                                  lambda b, bt, pos: (b, 0, 0))
-        if K == 1:
-            scratch = [pltpu.VMEM((2, G) + page, kv_dtype),
-                       pltpu.SemaphoreType.DMA((2, G))]
-        else:
-            # the ring's slots, a buffer each; the rows' running
-            # (m, l, acc) beside them
-            scratch = [pltpu.VMEM((G,) + page, kv_dtype)] * K + [
-                pltpu.SemaphoreType.DMA((K, G)),
-                pltpu.VMEM((R + 1, H, 1), jnp.float32),
-                pltpu.VMEM((R + 1, H, 1), jnp.float32),
-                pltpu.VMEM((R + 1, H, latent or 2 * dh), jnp.float32)]
+        # the ring's slots, a buffer each; the rows' running (m, l, acc)
+        # beside them
+        scratch = [pltpu.VMEM((G,) + page, kv_dtype)] * K + [
+            pltpu.SemaphoreType.DMA((K, G)),
+            pltpu.VMEM((R + 1, H, 1), jnp.float32),
+            pltpu.VMEM((R + 1, H, 1), jnp.float32),
+            pltpu.VMEM((R + 1, H, latent or 2 * dh), jnp.float32)]
         body = functools.partial(_walk_kernel, page_size=page_size,
-                                 dh=dh, T=T, PP=PP, G=G, F=F, R=R, K=K,
+                                 dh=dh, T=T, PP=PP, G=G, R=R, K=K,
                                  flat=flat, latent=latent, scale=scale)
     else:
         grid = (T, PP)
@@ -952,10 +869,10 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
                          % (pool_kv.shape[2], H))
     # q zero-extended over the v half of a page's lanes (the kernel's
     # one full-width product then contracts q with k alone); the flat
-    # column fold cuts k out of the page and takes q as it is (the
-    # latent query was padded to the row above)
-    bare = latent or (Hkv and not _ring(walk_geometry(
-        Hkv, dh, page_size, PP, pool_kv.dtype, flat=True)))
+    # column fold of the per-page grid cuts k out of the page and takes
+    # q as it is (the latent query was padded to the row above)
+    bare = latent or (Hkv and walk_geometry(
+        Hkv, dh, page_size, PP, pool_kv.dtype, flat=True) is None)
     args = [block_tables.reshape(-1).astype(jnp.int32),
             row_pos.astype(jnp.int32),
             q if bare else jnp.concatenate([q, jnp.zeros_like(q)],

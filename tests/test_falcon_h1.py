@@ -317,23 +317,29 @@ def _paged_case(T, Hq, Hkv, dh, ps, PP, dtype, seed):
     return q, pool, bt, pos.at[0].set(0).at[1].set(PP * ps - 1)
 
 
-@pytest.mark.parametrize("ps,dtype,walks,tol", [
-    (16, "float32", True, 2e-6),     # the walk, interpreted
-    (4, "float32", False, 2e-6),     # pages no whole tiles: per-page grid
-    (16, "bfloat16", True, 2e-2),
-    (8, "bfloat16", False, 2e-2),
+@pytest.mark.parametrize("ps,dh,dtype,walks,tol", [
+    (16, 128, "float32", True, 2e-6),    # the walk, interpreted
+    (4, 128, "float32", False, 2e-6),    # pages no whole tiles: per-page grid
+    (16, 128, "bfloat16", True, 2e-2),
+    (8, 128, "bfloat16", False, 2e-2),
+    # whole-tile pages, but a head's [k | v] pair half a lane tile: the
+    # walk's fold cannot take it as the MXU's weights, the grid serves
+    (16, 32, "float32", False, 2e-6),
+    (16, 32, "bfloat16", False, 2e-2),
 ])
-def test_grouped_paged_attention_matches_reference(ps, dtype, walks, tol):
-    """20 query heads over 4 key/value heads of 128, as the cell has."""
+def test_grouped_paged_attention_matches_reference(ps, dh, dtype, walks,
+                                                   tol):
+    """20 query heads over 4 key/value heads of 128, as the cell has (and
+    of 32)."""
     from mxnet_tpu.kernels.paged_attention import (
         paged_attention, paged_attention_reference, walk_geometry)
-    assert (walk_geometry(4, 128, ps, 6, dtype, flat=True)
+    assert (walk_geometry(4, dh, ps, 6, dtype, flat=True)
             is not None) == walks
-    q, pool, bt, pos = _paged_case(5, 20, 4, 128, ps, 6, dtype, ps)
+    q, pool, bt, pos = _paged_case(5, 20, 4, dh, ps, 6, dtype, ps)
     got = paged_attention(q, pool, None, bt, pos, page_size=ps,
                           interpret=True)
     want = paged_attention_reference(q, pool, None, bt, pos, page_size=ps)
-    assert got.shape == (5, 20, 128)
+    assert got.shape == (5, 20, dh)
     assert float(jnp.max(jnp.abs(got - want))) <= tol
 
 

@@ -124,7 +124,11 @@ _GQA64 = dict(T=256, Hq=32, Hkv=8, dh=64, ps=16, PP=128, NP=16385)
     # 8-token bf16 pages are half a tile: the per-page grid
     (dict(_GQA, T=32, ps=8, NP=353), "bfloat16", False),
     (_GQA64, "bfloat16", True),
-], ids=["cell-bf16", "odd-f32", "half-tile-bf16", "heads-of-64-bf16"])
+    # heads of 32: a head's [k | v] pair is half a lane tile, which the
+    # walk's fold cannot take whole: the per-page grid's column fold
+    (dict(_GQA, T=32, dh=32, NP=353), "float32", False),
+], ids=["cell-bf16", "odd-f32", "half-tile-bf16", "heads-of-64-bf16",
+        "heads-of-32-f32"])
 def test_grouped_paged_attention_compiles(v5e, geom, dtype, walks):
     from mxnet_tpu.kernels.paged_attention import (paged_attention,
                                                    walk_geometry)
@@ -132,14 +136,12 @@ def test_grouped_paged_attention_compiles(v5e, geom, dtype, walks):
     geometry = walk_geometry(g["Hkv"], g["dh"], g["ps"], g["PP"], dtype,
                              flat=True)
     assert (geometry is not None) == walks
-    if walks and g["dh"] == 128:
-        # the column fold keeps its turns of two pages (one where the
-        # group is an odd count) and its loop: one group a trip
-        assert geometry[1] == 2 - geometry[0] % 2 and geometry[3] == 1
-    elif walks:
-        # a head's [k | v] pair one lane tile: the ring, a whole group
-        # of 16 pages a turn under the dense form of the flat fold
-        assert geometry == (16, 16, 32, 4)
+    if walks:
+        # a head's [k | v] pair whole lane tiles: the ring, a whole group
+        # a turn under the dense form of the flat fold (16 bf16 pages of
+        # 32 KiB, or the 7-page table of 64 KiB f32 pages)
+        G = min(g["PP"], 16 if dtype == "bfloat16" else 8)
+        assert geometry == (G, G, 32, 4)
     _compile(lambda q, kv, bt, pos: paged_attention(
         q, kv, None, bt, pos, page_size=g["ps"]), v5e[0],
         _sds((g["T"], g["Hq"], g["dh"]), dtype),

@@ -361,7 +361,7 @@ def test_engine_serves_the_reference_tokens(ref, model, kernel, overlap):
         * eng.cache.bytes_per_slot_state
     # the toy's flat page is 64 lanes, no whole tile: the per-page grid
     # serves under "pallas" and both lowerings book the whole window (the
-    # cell's 1,024-lane page walks: test_flat_walk_folds_heads_of_64)
+    # cell's 1,024-lane page walks: test_flat_walk_folds_whole_pairs)
     assert s["kv_pages_read"] == s["kv_pages_window"] > 0
 
 
@@ -542,30 +542,30 @@ def test_step_scopes_in_lowered_text(model):
         assert _has_scope(locs, scope), scope
 
 
-# ------------------------------------------- the flat walk at heads of 64 ---
+# ------------------------------------------------ the flat walk's ring ---
 
+@pytest.mark.parametrize("Hkv,dh,Hq", [(8, 64, 32), (4, 128, 20)],
+                         ids=["heads-of-64", "heads-of-128"])
 @pytest.mark.parametrize("dtype,tol", [("float32", 2e-6),
                                        ("bfloat16", 2e-2)])
-def test_flat_walk_folds_heads_of_64(dtype, tol):
-    """32 query heads over 8 key/value heads of 64, as the cell has: a
-    head's ``[k | v]`` pair is one lane tile of the flat 1,024-lane page,
-    and the walk (interpreted here) takes it through the ring with the
-    dense form of the flat fold: groups of 16 pages a turn (8 in
-    float32), several groups a row, a short last block of rows."""
+def test_flat_walk_folds_whole_pairs(Hkv, dh, Hq, dtype, tol):
+    """The flat 1,024-lane page of this cell (32 query heads over 8
+    key/value heads of 64: a head's ``[k | v]`` pair one lane tile) and
+    of the Falcon-H1 cell (20 over 4 heads of 128: a pair two lane
+    tiles): the walk (interpreted here) takes both through the ring
+    with the dense form of the flat fold: groups of 16 pages a turn (8
+    in float32), several groups a row, a short last block of rows."""
     from mxnet_tpu.kernels.paged_attention import (
         paged_attention, paged_attention_reference, walk_geometry)
-    assert walk_geometry(8, 64, 16, 128, "bfloat16", flat=True) \
+    assert walk_geometry(Hkv, dh, 16, 128, "bfloat16", flat=True) \
         == (16, 16, 32, 4)
-    # heads of 128 keep the column fold and its loop of one group a trip
-    assert walk_geometry(4, 128, 16, 48, "bfloat16", flat=True) \
-        == (16, 2, 16, 1)
     rs = np.random.RandomState(3)
     T, PP, ps = 35, 20, 16
-    G = walk_geometry(8, 64, ps, PP, dtype, flat=True)[0]
+    G = walk_geometry(Hkv, dh, ps, PP, dtype, flat=True)[0]
     assert G < PP
     NP = T * PP + 1
-    pool = jnp.asarray(rs.randn(NP, ps, 8 * 2 * 64), dtype)
-    q = jnp.asarray(rs.randn(T, 32, 64), dtype)
+    pool = jnp.asarray(rs.randn(NP, ps, Hkv * 2 * dh), dtype)
+    q = jnp.asarray(rs.randn(T, Hq, dh), dtype)
     bt = jnp.asarray(rs.permutation(np.arange(1, NP))[:T * PP]
                      .reshape(T, PP), jnp.int32)
     pos = jnp.asarray(rs.randint(0, PP * ps, T), jnp.int32).at[:6].set(
@@ -573,7 +573,7 @@ def test_flat_walk_folds_heads_of_64(dtype, tol):
     got = paged_attention(q, pool, None, bt, pos, page_size=ps,
                           interpret=True)
     want = paged_attention_reference(q, pool, None, bt, pos, page_size=ps)
-    assert got.shape == (T, 32, 64)
+    assert got.shape == (T, Hq, dh)
     assert float(jnp.max(jnp.abs(got - want))) <= tol
 
 

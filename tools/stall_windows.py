@@ -29,14 +29,17 @@ either witness woke late inside it.
 ``span(os=True)`` in this process, with the runtime's threads alive
 (the process's CPU clock walks all of them); ``--hlo`` prints the sha256
 of the cell's step program lowered for the device it runs on (a serving
-cell: both schedules), for comparing two commits.  Both work on a
+cell: both schedules; each Pallas kernel's Mosaic body printed without
+its source locations), for comparing two commits.  Both work on a
 checkout from before ``profiler.stalls`` (``--windows 0``).
 """
 import argparse
+import base64
 import gc
 import hashlib
 import json
 import os
+import re
 import statistics
 import subprocess
 import sys
@@ -134,8 +137,23 @@ def span_cost(profiler, n=100000):
     return out
 
 
+# a Mosaic kernel's body inside the StableHLO: base64 MLIR bytecode
+MOSAIC_BODY = re.compile(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22')
+
+
 def sha(lowered):
-    return hashlib.sha256(lowered.as_text().encode()).hexdigest()
+    """sha256 of the lowered text with each Mosaic body printed WITHOUT
+    its locations: a body carries the file:line of every operation, so
+    an edit anywhere in a kernel's file would move a raw hash."""
+    from jaxlib.mlir import ir
+
+    def body(match):
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            return module.operation.get_asm(enable_debug_info=False)
+    text = MOSAIC_BODY.sub(body, lowered.as_text())
+    return hashlib.sha256(text.encode()).hexdigest()
 
 
 def step_hlo(session):
