@@ -118,6 +118,12 @@ _GQA = dict(T=128, Hq=20, Hkv=4, dh=128, ps=16, PP=48, NP=3073)
 _GQA64 = dict(T=256, Hq=32, Hkv=8, dh=64, ps=16, PP=128, NP=16385)
 
 
+# ... and the k_exaone_236b_l5_ep8 cell's one full layer 256 step rows, 64
+# query heads over 8 key/value heads of 128 (a 2,048-lane page, 64 KiB in
+# bf16, 8 query heads a group), 320 pages a row, a (40961, 16, 2048) pool
+_GQA128X8 = dict(T=256, Hq=64, Hkv=8, dh=128, ps=16, PP=320, NP=40961)
+
+
 @pytest.mark.parametrize("geom,dtype,walks", [
     (_GQA, "bfloat16", True),
     (dict(_GQA, T=37, PP=7, NP=353), "float32", True),
@@ -127,10 +133,12 @@ _GQA64 = dict(T=256, Hq=32, Hkv=8, dh=64, ps=16, PP=128, NP=16385)
     # heads of 32: a head's [k | v] pair is half a lane tile, which the
     # walk's fold cannot take whole: the per-page grid's column fold
     (dict(_GQA, T=32, dh=32, NP=353), "float32", False),
+    (_GQA128X8, "bfloat16", True),
 ], ids=["cell-bf16", "odd-f32", "half-tile-bf16", "heads-of-64-bf16",
-        "heads-of-32-f32"])
+        "heads-of-32-f32", "2048-lanes-bf16"])
 def test_grouped_paged_attention_compiles(v5e, geom, dtype, walks):
-    from mxnet_tpu.kernels.paged_attention import (paged_attention,
+    from mxnet_tpu.kernels.paged_attention import (_GROUP_BYTES,
+                                                   paged_attention,
                                                    walk_geometry)
     g = geom
     geometry = walk_geometry(g["Hkv"], g["dh"], g["ps"], g["PP"], dtype,
@@ -139,8 +147,11 @@ def test_grouped_paged_attention_compiles(v5e, geom, dtype, walks):
     if walks:
         # a head's [k | v] pair whole lane tiles: the ring, a whole group
         # a turn under the dense form of the flat fold (16 bf16 pages of
-        # 32 KiB, or the 7-page table of 64 KiB f32 pages)
-        G = min(g["PP"], 16 if dtype == "bfloat16" else 8)
+        # 32 KiB, 8 of 64 KiB, or the 7-page table of 64 KiB f32 pages)
+        page = g["ps"] * g["Hkv"] * 2 * g["dh"] * jnp.dtype(dtype).itemsize
+        G = min(g["PP"], _GROUP_BYTES // page)
+        assert G == (8 if g is _GQA128X8 else
+                     min(g["PP"], 16 if dtype == "bfloat16" else 8))
         assert geometry == (G, G, 32, 4)
     _compile(lambda q, kv, bt, pos: paged_attention(
         q, kv, None, bt, pos, page_size=g["ps"]), v5e[0],
@@ -185,7 +196,11 @@ def test_latent_paged_attention_compiles(v5e, geom, dtype):
     # 256: the first width that is no multiple of 512 (896-wide tiles),
     # and both its weight tiles span K (3.5 MiB)
     (256, 4, 2048, 1792, 32),
-], ids=["gigachat", "lfm2"])
+    # k_exaone_236b_l5_ep8: top-8 over 128, 16 held, K = 6,144: gate / up
+    # split K into six 1,024 tiles (6,144 x 512 is past the weight tile's
+    # budget), down spans K as GigaChat's does
+    (256, 8, 6144, 2048, 16),
+], ids=["gigachat", "lfm2", "exaone"])
 def test_held_experts_ffn_compiles(v5e, T, K, D, F, E):
     """A cell's expert layer: 256 rows x top-k static pairs over the
     held experts: three grouped products, each one Mosaic call

@@ -633,6 +633,42 @@ def build_serving_step_layer_kinds():
                 sds((_SLOTS + n_counts, 1), i32), sds((n_rows,), i32))
 
 
+def build_serving_step_sliding():
+    """The step of a family whose sliding-window layers keep a ring of
+    their last positions per slot beside full layers that keep pages
+    (``models/exaone_moe.py``) as a TPU engine runs it: the SAME
+    live ``_make_step`` builder, pipelined; the rings and the pages
+    donated, each in the layer that keeps it; the expert layers' and the
+    rings' counts behind the tokens.  sliding, sliding, full, sliding at
+    toy widths and a window of 8, 1 dense and 3 expert layers holding 2
+    of 8 experts and a shared one."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.models import exaone_moe as M
+    from mxnet_tpu.serving.engine import _make_step
+    from mxnet_tpu.serving.paged_kv import PagedKVCache
+    cfg = M.ExaoneMoeConfig(
+        vocab_size=256, d_model=64, n_layers=4, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=128, moe_d_ff=32, n_routed_experts=8,
+        n_shared_experts=1, top_k=2, sliding_windows=(8, 8, 0, 8),
+        mlp_layer_types=("dense", "sparse", "sparse", "sparse"),
+        held_count=2, dtype="bfloat16")
+    pps, n_rows = 4, _SLOTS + _CHUNK
+    fn = _make_step(cfg, _SLOTS, n_rows, pps, _PAGE, False, kernel="xla",
+                    overlap=True)
+    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+    params = jax.eval_shape(
+        lambda: M.init_params(jax.random.PRNGKey(0), cfg))
+    pools = jax.eval_shape(lambda: PagedKVCache(
+        cfg, _SLOTS * pps + 1, _PAGE, num_slots=_SLOTS).pools)
+    n_counts = len(M.STEP_COUNTERS)
+    return fn, (params, pools, sds((n_rows,), i32), sds((n_rows,), i32),
+                sds((n_rows,), i32), sds((n_rows,), jnp.bool_),
+                sds((_SLOTS + 1, pps), i32), sds((_SLOTS, 1), i32),
+                sds((_SLOTS + 1,), jnp.bool_),
+                sds((_SLOTS + n_counts, 1), i32), sds((n_rows,), i32))
+
+
 def build_paged_attention_kernel():
     import jax
     import jax.numpy as jnp
@@ -686,6 +722,9 @@ def live_programs() -> List[ProgramSpec]:
         # PR 36: a family whose layers keep different things (windows
         # beside pages): every leaf of the mixed pools donated
         spec("serving_step_layer_kinds", build_serving_step_layer_kinds,
+             donate=(1,)),
+        # sliding-window rings beside pages, every leaf donated
+        spec("serving_step_sliding", build_serving_step_sliding,
              donate=(1,)),
         spec("serving_step_tp", build_serving_step_tp, donate=(1,),
              dtype_region="int8", f32_allow=acc),
