@@ -76,7 +76,8 @@ from .falcon_h1 import _mm, _rms, _rope, _slot_rows
 
 __all__ = ["ExaoneMoeConfig", "param_shapes", "init_params", "forward",
            "serve_embed", "serve_block", "serve_logits", "layer_cache",
-           "SlotState", "slot_window", "StepCounts", "STEP_COUNTERS",
+           "SlotState", "slot_window", "latent_window", "StepCounts",
+           "STEP_COUNTERS",
            "counter_stats"]
 
 
@@ -337,6 +338,97 @@ def slot_window(q, k, v, row_pos, row_slot, pool, chunk):
     return out, pool, jnp.sum(live), jnp.sum(read)
 
 
+def latent_window(q, row, row_pos, row_slot, pool, chunk, *, rank, scale):
+    """``slot_window`` over a ring of LATENT rows: one shared row a
+    token, every head reading it (``models/motif3.py``).
+
+    q (T, H, dk): each head's absorbed query, scored against the first
+    ``dk`` lanes of a row; row (T, L): each row's ``[c_kv | k_pe]`` as
+    the ring holds it (padded); the values are a row's first ``rank``
+    lanes; ``scale`` the softmax's.  pool (S + 1, W, L) and the rows'
+    slots and positions as ``slot_window``'s.  ``chunk`` (static) bounds
+    the rows of ALL slots with several rows in the call together, which
+    lie within ``chunk`` consecutive rows (the engine's prefill rows:
+    one budget, after the decode rows).  Slots with one row take
+    ``slot_window``'s pass over the pool; the window of ``chunk`` rows
+    is read in ONE pass — each row against its slot's ring as it was
+    before the call and the window's own rows of its slot, causal,
+    inside ``W`` — and its slots' last ``W`` rows are written into their
+    rings in one scatter after it; no loop over slots, and no pass at
+    all in a call that has no such slot.  Returns (out (T, H, rank)
+    float32, the pool, the live rows, the ring entries and own rows the
+    attention had to read)."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    T, H, dk = q.shape
+    S1, W, L = pool.shape
+    cdt = pool.dtype
+    kv = row.astype(cdt)
+    q = q.astype(cdt)
+    cnt, first, last = _slot_rows(row_slot, S1)
+    real = jnp.arange(S1) < S1 - 1                 # not the scratch slot
+    single = (cnt == 1) & real
+    multi = (cnt >= 2) & real
+    p0 = row_pos[first]                                          # (S1,)
+
+    def softmax(s, see):
+        return jax.nn.softmax(jnp.where(see, s * scale, -1e30),
+                              axis=-1).astype(cdt)
+
+    # slots with one row: its row written at p mod W, then its window
+    # (p - W, p] read from the ring, one pass over the pool
+    ent = jnp.where(single, p0 % W, W)                   # W: dropped
+    pool = pool.at[jnp.arange(S1), ent].set(kv[first], mode="drop")
+    held = p0[:, None] - (p0[:, None] - jnp.arange(W)[None]) % W
+    s1 = jnp.einsum("shd,swd->shw", q[first], pool[..., :dk],
+                    preferred_element_type=f32)
+    o1 = jnp.einsum("shw,swr->shr", softmax(s1, (held >= 0)[:, None]),
+                    pool[..., :rank], preferred_element_type=f32)
+    out = jnp.where(single[row_slot][:, None, None], o1[row_slot], 0.0)
+
+    R = min(chunk, T)
+
+    def several(args):
+        """The window of R rows that holds every slot with several rows:
+        their outputs placed, their rings written."""
+        out, pool = args
+        r0 = jnp.clip(jnp.min(jnp.where(multi, first, T)), 0, T - R)
+        cut = lambda a: jax.lax.dynamic_slice_in_dim(a, r0, R)  # noqa: E731
+        slot, pos, rows = cut(row_slot), cut(row_pos), cut(kv)
+        mine = multi[slot]                                       # (R,)
+        ring = pool[slot]                                        # (R, W, L)
+        before = p0[slot][:, None] - 1 \
+            - (p0[slot][:, None] - 1 - jnp.arange(W)[None]) % W  # (R, W)
+        see_ring = (before >= 0) & (before > pos[:, None] - W)
+        see_own = (slot[None] == slot[:, None]) & mine[None] \
+            & (pos[None] <= pos[:, None]) & (pos[None] > pos[:, None] - W)
+        qw = cut(q)
+        sc = jnp.concatenate([
+            jnp.einsum("rhd,rwd->rhw", qw, ring[..., :dk],
+                       preferred_element_type=f32),
+            jnp.einsum("rhd,kd->rhk", qw, rows[:, :dk],
+                       preferred_element_type=f32)], axis=-1)
+        p = softmax(sc, jnp.concatenate([see_ring, see_own], -1)[:, None])
+        o = jnp.einsum("rhw,rwv->rhv", p[..., :W], ring[..., :rank],
+                       preferred_element_type=f32) \
+            + jnp.einsum("rhk,kv->rhv", p[..., W:], rows[:, :rank],
+                         preferred_element_type=f32)
+        out = jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.where(mine[:, None, None], o, cut(out)), r0, 0)
+        # each such slot's last W rows at their place in its ring
+        keep = mine & (pos > row_pos[last[slot]] - W)
+        pool = pool.at[slot, jnp.where(keep, pos % W, W)].set(
+            rows, mode="drop")
+        return out, pool
+
+    out, pool = jax.lax.cond(jnp.any(multi), several, lambda a: a,
+                             (out, pool))
+    live = cnt * real
+    read = jnp.where(live > 0, jnp.minimum(p0, W - 1) + live, 0)
+    return out, pool, jnp.sum(live), jnp.sum(read)
+
+
 class SlotState:
     """The slot-state backend of one layer in one call: the layer's
     ring pool and which slot each row belongs to.  The engine's
@@ -351,6 +443,16 @@ class SlotState:
     def window(self, q, k, v, row_pos, counts=None):
         out, self.pools["win"], rows, read = slot_window(
             q, k, v, row_pos, self.row_slot, self.pools["win"], self.chunk)
+        if counts is not None:
+            counts.add_window(rows, read)
+        return out
+
+    def latent_window(self, q, row, row_pos, rank, scale, counts=None):
+        """The window over a ring of latent rows (``latent_window``):
+        (T, H, rank) float32."""
+        out, self.pools["win"], rows, read = latent_window(
+            q, row, row_pos, self.row_slot, self.pools["win"], self.chunk,
+            rank=rank, scale=scale)
         if counts is not None:
             counts.add_window(rows, read)
         return out

@@ -291,7 +291,7 @@ def _grouped_dot(x, w, sizes):
 
 
 def held_experts_ffn(x, w_gate, w_up, w_down, idx, w, *, held_first,
-                     live=None):
+                     live=None, act=None):
     """The held experts' part of a dropless expert layer.
 
     ``x`` (T, D) rows in the compute dtype; ``w_gate`` / ``w_up``
@@ -300,7 +300,10 @@ def held_experts_ffn(x, w_gate, w_up, w_down, idx, w, *, held_first,
     ``w`` (T, k) each row's chosen experts and weights over ALL experts
     (``route_group_limited``); ``live`` (T,) bool: rows whose pairs are
     dispatched (a dead row of a fixed-shape step is none of the
-    traffic).  Returns ``(y (T, D) float32, pairs, hit, sizes,
+    traffic); ``act(gate, up, expert)``: the experts' activation of the
+    sorted pairs' two products ((T*k, F) float32 each; ``expert``
+    (T*k,) int32 each pair's held expert, ``E_held`` past the groups),
+    SwiGLU's ``silu(gate) * up`` where None.  Returns ``(y (T, D) float32, pairs, hit, sizes,
     fetches)``: ``y = sum over a row's HELD choices of w_k E_k(x)``,
     the number of row-expert pairs dispatched, of held experts with at
     least one, each held expert's pairs, (E_held,) int32, and the
@@ -328,8 +331,13 @@ def held_experts_ffn(x, w_gate, w_up, w_down, idx, w, *, held_first,
     sizes = jnp.sum(key[:, None] == jnp.arange(E)[None, :], axis=0,
                     dtype=jnp.int32)                          # (E,)
     xs = x[order // K]                                        # (T*K, D)
-    h = (jax.nn.silu(_grouped_dot(xs, w_gate, sizes))
-         * _grouped_dot(xs, w_up, sizes)).astype(x.dtype)
+    if act is None:
+        h = jax.nn.silu(_grouped_dot(xs, w_gate, sizes)) \
+            * _grouped_dot(xs, w_up, sizes)
+    else:
+        h = act(_grouped_dot(xs, w_gate, sizes),
+                _grouped_dot(xs, w_up, sizes), key[order])
+    h = h.astype(x.dtype)
     out = _grouped_dot(h, w_down, sizes)                      # (T*K, D)
     # rows past the groups are whatever the grouped product leaves
     # there: selected away, not multiplied away

@@ -657,19 +657,25 @@ def test_chipbench_control_and_faults_come_out_not_correct(ref):
 
 def test_benchmark_json_names_the_cell_and_its_metrics():
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    assert bench["workloads"][-1] == {
+    entry = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert entry == {
         "name": CELL, "config": "k_exaone_236b_l5_ep8",
         "traffic": "closed128_p512-2048_o1024-3072", "chips": 1,
-        "why": bench["workloads"][-1]["why"]}
-    conf = bench["configs"][-1]
-    assert conf["name"] == "k_exaone_236b_l5_ep8"
+        "why": entry["why"]}
+    conf = next(c for c in bench["configs"]
+                if c["name"] == "k_exaone_236b_l5_ep8")
     assert conf["reduced"] == _CONFIG["reduced"]
     assert conf["source"] == _CONFIG["source"]
-    # no metric of its own: the accepted per_layer list is left as it was
-    assert [m["name"] for m in bench["per_layer"][-6:]] == [
+    # no metric of its own: the accepted per_layer list is left as it was,
+    # and what later cells append after it is not reported in this one
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("turn_stall_max_ms.serve")
+    assert names[at:at + 6] == [
         "turn_stall_max_ms.serve", "stall_offcpu_share.serve",
         "stall_host_late_share.serve", "stall_runtime_busy_share.serve",
         "step_stall_share.train", "turn_stall_max_ms.train"]
+    assert all(CELL not in m.get("workloads", [CELL])
+               for m in bench["per_layer"][at + 6:])
     cell = {"name": CELL, "bench": bench}
     per_layer = [m["name"] for m in chipbench_run.metrics_for(
         cell, "per_layer")]
