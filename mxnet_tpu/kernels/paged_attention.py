@@ -19,15 +19,19 @@ per page.  The block table and the positions are scalar-prefetched
 (``pltpu.PrefetchScalarGridSpec``); the pool stays in HBM
 (``memory_space=pl.ANY``).  A grid step's work is the flat sequence
 of its rows' live groups of G pages, the (row, group) items, bounded
-by ``row_pos // page_size + 1`` and by nothing else: pages past a
-row's position, and every page of a dead row but its scratch page,
-are neither copied nor folded.  A cursor steps through the items
-twice, K - 1 items apart: ``make_async_copy`` brings item i + K - 1
-into slot (i - 1) mod K of a ring of K VMEM buffers while item i is
-folded out of slot i mod K — a whole page ``(ps, H, 2*dh)`` is one
-contiguous copy, and a row's first copy hides behind the rows before
-it.  The loop takes K items a trip, so every slot index is static and
-the compiler, which sees each slot as a buffer of its own, runs the
+by ``row_pos // page_size + 1`` and by the step's live rows: the
+serving engine packs them first and hands the kernel their count
+(``n_live``, the last entry of the prefetched positions), so pages
+past a row's position, and every page of the dead rows after the
+last live one, are neither copied nor folded; a block of dead rows
+starts no copy, and dead rows are written zeros.  A cursor steps
+through the items twice, K - 1 items apart: ``make_async_copy``
+brings item i + K - 1 into slot (i - 1) mod K of a ring of K VMEM
+buffers while item i is folded out of slot i mod K — a whole page
+``(ps, H, 2*dh)`` is one contiguous copy, and a row's first copy
+hides behind the rows before it.  The loop takes K items a trip, so
+every slot index is static and the compiler, which sees each slot as
+a buffer of its own, runs the
 scalar code that issues the copies under the chain of the fold beside
 it; each item is folded FROM A FRESH (m, l, acc), a whole group at
 once, and merged into its row's running state in VMEM (the two-way
@@ -479,7 +483,9 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, *scratch,
     that partial merged into its row's running ``state`` (m, l, acc:
     VMEM, R rows and a spare one for the slots a block's last trip has
     no item for); the rows are normalised and written when the grid
-    step's items are in."""
+    step's items are in.  The rows from ``pos_ref[T]`` on are dead:
+    they cut the block's ``n_rows``, which bounds the items, and are
+    written zeros."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -490,7 +496,10 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, *scratch,
     H = q_ref.shape[1]
     row0 = pl.program_id(0) * R
     # rows of this grid step that exist (the last block may be short)
-    n_rows = jnp.minimum(R, T - row0)
+    # and are live: the rows from ``pos_ref[T]`` on are dead, walked
+    # by no item and written zeros
+    n_rows = jnp.maximum(
+        jnp.minimum(jnp.minimum(R, T - row0), pos_ref[T] - row0), 0)
     # a buffer a slot: the compiler then sees that a copy into one slot
     # and a fold out of another touch nothing in common, and runs the
     # scalar code of the one under the other
@@ -502,9 +511,9 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, *scratch,
 
     def start(t, slot):
         # row t's first group: whole pages, HBM to VMEM slot ``slot``;
-        # pages past the row's position (the walk's one bound: a dead
-        # row, pos 0 and an all-zero table row, walks the scratch page
-        # and nothing else) are not copied
+        # pages past the row's position (a dead row before the last
+        # live one, pos 0 and an all-zero table row, walks the scratch
+        # page and nothing else) are not copied
         last = jnp.minimum(pos_ref[t] // ps, PP - 1)
         for i in range(G):
             @pl.when(i <= last)
@@ -616,20 +625,29 @@ def _walk_kernel(bt_ref, pos_ref, q_ref, kv_hbm, o_ref, *scratch,
             for b in slots:
                 b[...] = jnp.zeros_like(b)
 
-    start(row0, 0)
+    head = (i32(0), i32(0), row_last(i32(0)))
+    ahead = [after(head)]
+    for _ in range(1, K - 1):
+        ahead.append(after(ahead[-1]))
+    *ahead, tail = ahead
+
+    # a block of dead rows has no item: a copy started here would be
+    # waited for by none
+    @pl.when(n_rows > 0)
+    def _():
+        start(row0, 0)
+        for slot, item in enumerate(ahead, 1):
+            issue(item, slot)
     n_items = lax.fori_loop(
         0, n_rows, lambda r, n: n + lax.div(row_last(r), i32(G)) + 1, i32(0))
-    head = (i32(0), i32(0), row_last(i32(0)))
-    tail = after(head)
-    for slot in range(1, K - 1):
-        issue(tail, slot)
-        tail = after(tail)
     lax.fori_loop(0, lax.div(n_items + i32(K - 1), i32(K)), trip,
                   (head, tail))
     m_ref, l_ref, acc_ref = state
     acc = acc_ref[pl.ds(0, R)]
-    o_ref[...] = ((acc if latent else acc[:, :, dh:])
-                  / l_ref[pl.ds(0, R)]).astype(o_ref.dtype)
+    # the rows past the live ones hold an earlier block's state, or none
+    live = lax.broadcasted_iota(i32, o_ref.shape, 0) < n_rows
+    o_ref[...] = jnp.where(live, (acc if latent else acc[:, :, dh:])
+                           / l_ref[pl.ds(0, R)], 0.0).astype(o_ref.dtype)
 
 
 def _page_kernel(bt_ref, pos_ref, q_ref, kv_ref, *rest, page_size, dh,
@@ -646,8 +664,11 @@ def _page_kernel(bt_ref, pos_ref, q_ref, kv_ref, *rest, page_size, dh,
     else:
         s_ref = None
         o_ref, m_ref, l_ref, acc_ref = rest
-    j = pl.program_id(1)
-    pos = pos_ref[pl.program_id(0)]
+    t, j = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[t]
+    # the rows from pos[T] on are dead: written zeros, as the walk
+    # writes them
+    n_live = pos_ref[pl.num_programs(0)]
 
     @pl.when(j == 0)
     def _init():
@@ -682,7 +703,9 @@ def _page_kernel(bt_ref, pos_ref, q_ref, kv_ref, *rest, page_size, dh,
     @pl.when(j == pl.num_programs(1) - 1)
     def _out():
         acc = acc_ref[...] if flat else acc_ref[:, dh:]
-        o_ref[0] = (acc / l_ref[...]).astype(o_ref.dtype)
+        live = jnp.full(acc.shape, t, jnp.int32) < n_live
+        o_ref[0] = jnp.where(live, acc / l_ref[...],
+                             0.0).astype(o_ref.dtype)
 
 
 # bounded cache of built pallas_call closures, keyed on every
@@ -783,7 +806,7 @@ def _build(T, H, dh, PP, page_size, num_pages, kv_dtype, q_dtype,
 
 def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
                     page_size, interpret=None, mesh=None, latent=None,
-                    scale=None):
+                    scale=None, n_live=None):
     """Single-token attention over paged K/V via block-table walk.
 
     Parameters
@@ -804,6 +827,12 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
         should point at the scratch page 0.
     row_pos : (T,) int32 per-row absolute positions — each row attends
         to positions <= its own (the continuous-batching mask).
+    n_live : optional int32 scalar, traced: the rows from ``n_live`` on
+        are dead, walked by nothing, and their output is zeros (the
+        serving engine packs a step's live rows first).  ``None`` is
+        ``T``: every row attends.  It rides as the last entry of the
+        scalar-prefetched positions, so the built call is the same
+        whatever its value.
     latent : ``(rank, rope)`` of a LATENT pool ``(num_pages, page_size,
         W)``, ``W = latent_width(rank, rope)``: ``q`` is then ``(T, H,
         rank + rope)``, each head's absorbed query against the one row
@@ -874,7 +903,9 @@ def paged_attention(q, pool_kv, pool_s, block_tables, row_pos, *,
     bare = latent or (Hkv and walk_geometry(
         Hkv, dh, page_size, PP, pool_kv.dtype, flat=True) is None)
     args = [block_tables.reshape(-1).astype(jnp.int32),
-            row_pos.astype(jnp.int32),
+            jnp.append(row_pos.astype(jnp.int32),
+                       T if n_live is None
+                       else jnp.asarray(n_live, jnp.int32)),
             q if bare else jnp.concatenate([q, jnp.zeros_like(q)],
                                            axis=-1), pool_kv]
     if int8:

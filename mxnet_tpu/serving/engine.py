@@ -4,10 +4,11 @@ One **step program** per engine config — compiled exactly once — takes
 a fixed-shape batch of T token rows, where each row is (token, slot,
 position): running requests contribute ONE decode row each, freshly
 admitted requests contribute up to ``prefill_chunk`` prompt rows
-(chunked prefill), and leftover rows are dead padding aimed at the
-scratch page.  The program embeds all rows, scatters every row's k/v
-into the paged pools at (block_table[slot][pos//page_size],
-pos%page_size), gathers each row's block-table view, and attends via
+(chunked prefill), and leftover rows, after every live one, are dead
+padding aimed at the scratch page.  The program embeds all rows,
+scatters every row's k/v into the paged pools at
+(block_table[slot][pos//page_size], pos%page_size), gathers each
+row's block-table view, and attends via
 the SAME ``_attend_rows`` code the contiguous decode step uses (with
 per-row positions instead of one scalar — that is the whole
 continuous-batching trick at the model level).  Greedy argmax logits
@@ -441,6 +442,10 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
                          bt[row_slot, page_idx], 0)    # (T,)
         off = row_pos % page_size
         row_pages = bt[row_slot]                       # (T, PP)
+        # one past the last live row: ``_build_plan`` packs the live
+        # rows first, so the walk stops here and skips the dead tail
+        n_live = jnp.max(jnp.where(
+            row_live, jnp.arange(1, n_rows + 1, dtype=jnp.int32), 0))
 
         new_pools = []
         # what the model counts over the step's live rows, all layers
@@ -459,11 +464,15 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
                 with jax.named_scope("kv_write"):
                     pool_kv = write_latent(pool["kv"], page, off, row)
                     new["kv"] = pool_kv
-                fn = PA.paged_attention if kernel == "pallas" \
-                    else PA.paged_attention_reference
-                return fn(q, pool_kv, None, row_pages, row_pos,
-                          page_size=page_size, latent=latent,
-                          scale=cfg.softmax_scale)
+                if kernel == "pallas":
+                    return PA.paged_attention(
+                        q, pool_kv, None, row_pages, row_pos,
+                        page_size=page_size, latent=latent,
+                        scale=cfg.softmax_scale, n_live=n_live)
+                return PA.paged_attention_reference(
+                    q, pool_kv, None, row_pages, row_pos,
+                    page_size=page_size, latent=latent,
+                    scale=cfg.softmax_scale)
 
             def attend(q, k, v, pool=pool, new=new):
                 """Write the rows' k/v into their pages, then each
@@ -499,7 +508,7 @@ def _make_step(cfg, num_slots, n_rows, pages_per_slot, page_size,
                     return paged_attention(q, pool_kv, pool_s,
                                            row_pages, row_pos,
                                            page_size=page_size,
-                                           mesh=mesh)
+                                           mesh=mesh, n_live=n_live)
                 # block-table gather + _attend_rows — ONE copy of the
                 # gather lives in kernels/paged_attention.py, shared
                 # with the tests' oracle, so the engine path and the
@@ -1905,7 +1914,14 @@ class ServingEngine:
         in-flight sampling position + 1 — the dispatch never waits for
         the readback.  Everything the later commit needs is recorded
         here at build time (plan k+1 is built before k's commit
-        runs)."""
+        runs).
+
+        The rows are PACKED: carried, decode (each with its draft
+        rows), then prefill rows fill rows ``0 .. n_rows_used - 1``,
+        and every row after them is dead.  The step program walks
+        pages up to the last live row only (``_body``'s ``n_live``), so
+        a dead row between live ones would be walked, correctly and
+        for nothing."""
         plan = _Plan()
         plan.pipelined = self.overlap
         inflight = self._inflight[0] if self._inflight else None
@@ -2079,13 +2095,15 @@ class ServingEngine:
             self.stats["dead_rows"] += T - r
             # how far the attention's reads follow the rows: the
             # window is every row's whole table; the walk reads up to
-            # each row's own position (a dead row its scratch page)
+            # each live row's own position, and nothing of the dead
+            # rows after them
             window = T * self.pages_per_slot
             plan.kv_pages = window
             if self._walk_turn:
                 # a row's live pages, and those rounded up to the
                 # whole turns the walk's two contractions run over
                 live = row_pos // self.page_size + 1
+                live[r:] = 0
                 F = self._walk_turn
                 plan.kv_pages = int(live.sum())
                 self.stats["kv_pages_folded"] += int(

@@ -190,6 +190,36 @@ def test_latent_paged_attention_compiles(v5e, geom, dtype):
         _sds((g["T"], g["PP"]), "int32"), _sds((g["T"],), "int32"))
 
 
+@pytest.mark.parametrize("pool", ["dense", "flat", "latent"])
+def test_walk_compiles_with_live_rows(v5e, pool):
+    """The walk as the serving engine calls it, the live rows' count a
+    traced int32 scalar (``n_live``, the prefetched positions' last
+    entry), at the cells' geometries: bert_large_decoder's dense pool,
+    falcon_h1_34b_l6's flat one, gigachat3_702b_l5_ep16's latent one."""
+    from mxnet_tpu.kernels.paged_attention import (paged_attention,
+                                                   walk_geometry)
+    from mxnet_tpu.serving.paged_kv import latent_width
+    if pool == "dense":
+        T, H, dh, ps, PP, NP = 160, 16, 64, 16, 32, 3073
+        q, kv, kw = (T, H, dh), (NP, ps, H, 2 * dh), {}
+        assert walk_geometry(H, dh, ps, PP, "bfloat16") is not None
+    elif pool == "flat":
+        g = _GQA
+        T, ps, PP = g["T"], g["ps"], g["PP"]
+        q = (T, g["Hq"], g["dh"])
+        kv, kw = (g["NP"], ps, g["Hkv"] * 2 * g["dh"]), {}
+    else:
+        g = _MLA
+        T, ps, PP = g["T"], g["ps"], g["PP"]
+        q = (T, g["H"], g["rank"] + g["rope"])
+        kv = (g["NP"], ps, latent_width(g["rank"], g["rope"]))
+        kw = dict(latent=(g["rank"], g["rope"]), scale=0.14468)
+    _compile(lambda q, kv, bt, pos, n: paged_attention(
+        q, kv, None, bt, pos, page_size=ps, n_live=n, **kw), v5e[0],
+        _sds(q, "bfloat16"), _sds(kv, "bfloat16"), _sds((T, PP), "int32"),
+        _sds((T,), "int32"), _sds((), "int32"))
+
+
 @pytest.mark.parametrize("T,K,D,F,E", [
     (256, 8, 7168, 2048, 16),
     # lfm2_8b_a1b_l12: top-4 over all 32 experts of width 1,792 = 7 x

@@ -27,7 +27,7 @@ _RTOL, _ATOL = 3e-6, 3e-6
 
 
 def _mk(T=6, H=2, dh=8, ps=4, PP=3, NP=11, int8=False, seed=0,
-        dtype="float32", latent=None):
+        dtype="float32", latent=None, flat=None):
     import jax.numpy as jnp
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(T, H, dh), jnp.dtype(dtype))
@@ -39,6 +39,12 @@ def _mk(T=6, H=2, dh=8, ps=4, PP=3, NP=11, int8=False, seed=0,
         rows = np.zeros((NP, ps, latent_width(*latent)), np.float32)
         rows[..., :dh] = rng.randn(NP, ps, dh)
         pool, scale = jnp.asarray(rows, jnp.dtype(dtype)), None
+    elif flat:
+        # a grouped-query pool: ``flat`` key/value heads' [k | v] pairs
+        # side by side on a flat page's lanes
+        pool = jnp.asarray(rng.randn(NP, ps, flat * 2 * dh),
+                           jnp.dtype(dtype))
+        scale = None
     elif int8:
         pool = jnp.asarray(rng.randint(-127, 128, (NP, ps, H, 2 * dh)),
                            jnp.int8)
@@ -54,13 +60,14 @@ def _mk(T=6, H=2, dh=8, ps=4, PP=3, NP=11, int8=False, seed=0,
     return q, pool, scale, bt
 
 
-def _both(q, pool, scale, bt, pos, ps, latent=None):
+def _both(q, pool, scale, bt, pos, ps, latent=None, n_live=None):
     import jax.numpy as jnp
     from mxnet_tpu.kernels import paged_attention as PA
     pos = jnp.asarray(pos, jnp.int32)
     kw = dict(latent=latent, scale=0.17) if latent else {}
-    out = PA.paged_attention(q, pool, scale, bt, pos, page_size=ps,
-                             interpret=True, **kw)
+    out = PA.paged_attention(
+        q, pool, scale, bt, pos, page_size=ps, interpret=True,
+        n_live=None if n_live is None else jnp.int32(n_live), **kw)
     ref = PA.paged_attention_reference(q, pool, scale, bt, pos,
                                        page_size=ps, **kw)
     return np.asarray(out), np.asarray(ref)
@@ -157,22 +164,26 @@ def test_kernel_mesh_tp_parity():
     walking its 1/tp heads slice of the heads-sharded pool — matches
     the single-device reference at the same page-boundary positions,
     f32 and int8 (the retiled scale planes shard their trailing heads
-    axis).  The lowering is the unit under test; the kernel body is
-    pinned above."""
+    axis), and with a dead tail (``n_live``, replicated to every
+    device) zeros past it.  The lowering is the unit under test; the
+    kernel body is pinned above."""
     from mxnet_tpu.kernels import paged_attention as PA
     from mxnet_tpu.parallel.mesh import serving_mesh
     import jax.numpy as jnp
 
     mesh = serving_mesh(2)
     pos = jnp.asarray([0, 3, 4, 8, 5, 11], jnp.int32)
-    for int8 in (False, True):
+    for int8, n in ((False, 6), (True, 6), (False, 4)):
         q, pool, scale, bt = _mk(T=6, int8=int8)
         out = PA.paged_attention(q, pool, scale, bt, pos, page_size=4,
-                                 interpret=True, mesh=mesh)
+                                 interpret=True, mesh=mesh,
+                                 n_live=jnp.int32(n))
         ref = PA.paged_attention_reference(q, pool, scale, bt, pos,
                                            page_size=4)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+        np.testing.assert_allclose(np.asarray(out)[:n],
+                                   np.asarray(ref)[:n],
                                    rtol=_RTOL, atol=_ATOL)
+        np.testing.assert_array_equal(np.asarray(out)[n:], 0.0)
         # heads really shard: 2 devices, half the heads each
         assert len(out.addressable_shards) == 2
 
@@ -181,12 +192,35 @@ def test_kernel_mesh_tp_parity():
 # the walk: rows x groups of pages, bounded by each row's own position
 # ---------------------------------------------------------------------------
 
+def _dead_tail_case(name):
+    """``_walk_case`` for ``dead_tail_<pool>_<where>``: a step whose
+    rows from ``n_live`` on are dead (position 0 on the all-zero table
+    row, as the serving engine packs them), ``n_live`` inside the last
+    block of R rows, on a block's boundary, 0 or T, over a dense, flat
+    or latent pool."""
+    pool, where = name[len("dead_tail_"):].split("_", 1)
+    mk, R = {
+        "dense": (dict(T=38, H=2, dh=8, ps=4, PP=5, NP=23), 32),
+        "flat": (dict(T=38, H=4, dh=64, ps=8, PP=5, NP=23, flat=2), 32),
+        "latent": (dict(T=12, H=4, dh=80, ps=8, PP=5, NP=23,
+                        latent=(64, 16)), 8)}[pool]
+    T = mk["T"]
+    n = {"inside_block": R + 3, "block_boundary": R, "none_live": 0,
+         "all_live": T}[where]
+    span = mk["PP"] * mk["ps"]
+    return (dict(mk, n_live=n), 2, {r: 0 for r in range(n, T)},
+            lambda G, F: [(7 * r + 3) % span if r < n else 0
+                          for r in range(T)])
+
+
 def _walk_case(name):
-    """(kwargs of ``_mk``, pages a group or None, table rows to alter
-    or None, the positions as a function of the walk's G and F) for
-    one named case.  Tiny pages in groups of two put several group
-    boundaries inside a five-page table; ``None`` keeps the module's
-    own group size."""
+    """(kwargs of ``_mk``, ``n_live`` among them where the call is
+    given one; pages a group or None, table rows to alter or None, the
+    positions as a function of the walk's G and F) for one named case.
+    Tiny pages in groups of two put several group boundaries inside a
+    five-page table; ``None`` keeps the module's own group size."""
+    if name.startswith("dead_tail_"):
+        return _dead_tail_case(name)
     two_pages = 2
     small = dict(T=6, H=2, dh=8, ps=4, PP=5, NP=23)
     return {
@@ -301,6 +335,10 @@ def _walk_case(name):
                           lambda G, F: [0, 5, 8, 9, 16, 19]),
         "int8_h6_per_page": (dict(small, H=6, int8=True), None, None,
                              lambda G, F: [0, 5, 8, 9, 16, 19]),
+        # the per-page grid writes the dead tail zeros too
+        "bf16_h6_dead_tail_per_page": (
+            dict(small, H=6, dh=16, dtype="bfloat16", n_live=4), None,
+            {4: 0, 5: 0}, lambda G, F: [0, 5, 8, 9, 0, 0]),
     }[name]
 
 
@@ -314,16 +352,24 @@ def _walk_case(name):
     "short_last_block_odd_items", "cell_page_bf16_odd_items",
     "latent_bf16_own_group", "f32_h6", "f32_h3", "bf16_h8",
     "bf16_h6_per_page", "bf16_h3_per_page", "int8_per_page",
-    "int8_h6_per_page"])
+    "int8_h6_per_page", "bf16_h6_dead_tail_per_page"] + [
+    "dead_tail_%s_%s" % (pool, where)
+    for pool in ("dense", "flat", "latent")
+    for where in ("inside_block", "block_boundary", "none_live",
+                  "all_live")])
 def test_walk_cases(name, monkeypatch):
     """The walk against the gathered reference where its loops turn:
     group boundaries, rows of one page, rows sharing pages, short row
     blocks, every pool kind — and the per-page grid on the pools the
-    walk cannot cut pages out of."""
+    walk cannot cut pages out of.  Where the call is given ``n_live``,
+    the rows before it match the reference and the rows from it on
+    (dead) are exactly zero."""
     import jax.numpy as jnp
     from mxnet_tpu.kernels import paged_attention as PA
 
     mk, group_pages, rows, positions = _walk_case(name)
+    mk = dict(mk)
+    n_live = mk.pop("n_live", None)
     q, pool, scale, bt = _mk(**mk)
     ps, PP = mk["ps"], mk["PP"]
     if group_pages is not None:
@@ -335,6 +381,9 @@ def test_walk_cases(name, monkeypatch):
     if latent:
         geometry = PA.walk_geometry(1, pool.shape[-1] // 2, ps, PP,
                                     pool.dtype, flat=True, latent=True)
+    elif mk.get("flat"):
+        geometry = PA.walk_geometry(mk["flat"], mk["dh"], ps, PP,
+                                    pool.dtype, flat=True)
     else:
         geometry = PA.walk_geometry(mk["H"], mk["dh"], ps, PP, pool.dtype)
     assert (geometry is None) == name.endswith("_per_page")
@@ -357,10 +406,13 @@ def test_walk_cases(name, monkeypatch):
             bt[r, int(how[4:]):] = 0
     pos = positions(G, F)
     assert len(pos) == mk["T"] and max(pos) < PP * ps
-    out, ref = _both(q, pool, scale, jnp.asarray(bt), pos, ps, latent)
+    out, ref = _both(q, pool, scale, jnp.asarray(bt), pos, ps, latent,
+                     n_live)
     tol = dict(rtol=2e-2, atol=2e-2) if mk.get("dtype") == "bfloat16" \
         else dict(rtol=_RTOL, atol=_ATOL)
-    np.testing.assert_allclose(out, ref, **tol)
+    n = mk["T"] if n_live is None else n_live
+    np.testing.assert_allclose(out[:n], ref[:n], **tol)
+    np.testing.assert_array_equal(out[n:], 0.0)
 
 
 @pytest.mark.parametrize("PP,G,pos", [

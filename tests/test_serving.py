@@ -217,8 +217,9 @@ def test_kv_pages_counters(kernel, kv_int8):
     """``kv_pages_window`` books every dispatched step's whole
     attention window (rows x pages a slot); ``kv_pages_read`` equals
     it on the gather path and on a pool the walk cannot cut pages out
-    of (int8), and equals the rows' own page counts — position //
-    page_size + 1, a dead row one scratch page — on the walk."""
+    of (int8), and equals the live rows' own page counts — position //
+    page_size + 1, a dead row none: the walk stops at the last live
+    row — on the walk."""
     import jax
     from mxnet_tpu.models import transformer as T
     from mxnet_tpu.serving import ServingEngine
@@ -232,7 +233,8 @@ def test_kv_pages_counters(kernel, kv_int8):
     dispatch = eng._dispatch
 
     def counting(plan):
-        own.append(int((plan.buf.row_pos // 4 + 1).sum()))
+        buf = plan.buf
+        own.append(int(((buf.row_pos // 4 + 1) * buf.row_live).sum()))
         steps.append(plan.kv_pages)
         return dispatch(plan)
     eng._dispatch = counting
@@ -272,10 +274,10 @@ def test_kv_pages_folded_counter(kernel, page_size, kv_int8):
     """``kv_pages_folded`` books, per dispatched step, each row's live
     pages rounded up to the whole turns of F pages that the walk's two
     contractions run over (F from ``walk_geometry``: the whole group
-    under the dense fold): at least ``kv_pages_read``, equal to it
-    where every row ends on a fold boundary (one page a slot: F = 1),
-    and nothing where no walk runs — the gather path, the per-page
-    grid on an int8 pool."""
+    under the dense fold), a dead row none: at least ``kv_pages_read``,
+    equal to it where every row ends on a fold boundary (one page a
+    slot: F = 1), and nothing where no walk runs — the gather path, the
+    per-page grid on an int8 pool."""
     import jax
     from mxnet_tpu.kernels.paged_attention import walk_geometry
     from mxnet_tpu.models import transformer as T
@@ -292,7 +294,7 @@ def test_kv_pages_folded_counter(kernel, page_size, kv_int8):
     folded, seen = [], []
 
     def book(plan):
-        live = plan.buf.row_pos // page_size + 1
+        live = (plan.buf.row_pos // page_size + 1) * plan.buf.row_live
         folded.append(int(((live + F - 1) // F * F).sum()))
         seen.append(eng.stats["kv_pages_folded"])
     _run_counting(eng, book)
@@ -316,12 +318,13 @@ def test_kv_pages_folded_counter(kernel, page_size, kv_int8):
 def test_kv_chain_counters(kernel, page_size, kv_int8, group_pages,
                            monkeypatch):
     """``kv_groups_live`` books, per dispatched step, the groups of G
-    pages that hold a live page; ``kv_chain_slots`` those rounded up,
-    block of R rows by block, to the whole trips of K chains that the
-    walk's loop makes (G, R and K from ``walk_geometry``): at least the
-    live groups, the same share wherever a row is one group (16 pages
-    of 4 tokens or one of 64), and nothing where no walk runs — the
-    gather path, the per-page grid on an int8 pool."""
+    pages that hold a live page, a dead row none; ``kv_chain_slots``
+    those rounded up, block of R rows by block, to the whole trips of K
+    chains that the walk's loop makes (G, R and K from
+    ``walk_geometry``): at least the live groups, the same share
+    wherever a row is one group (16 pages of 4 tokens or one of 64),
+    and nothing where no walk runs — the gather path, the per-page grid
+    on an int8 pool."""
     import jax
     from mxnet_tpu.kernels import paged_attention as PA
     from mxnet_tpu.models import transformer as T
@@ -341,10 +344,12 @@ def test_kv_chain_counters(kernel, page_size, kv_int8, group_pages,
         cfg.n_heads, cfg.d_model // cfg.n_heads, page_size,
         eng.pages_per_slot, "float32")
     assert G == (group_pages or eng.pages_per_slot) and K == 4
-    live, slots, seen = [], [], []
+    live, slots, seen, rows = [], [], [], []
 
     def book(plan):
-        groups = -(-(plan.buf.row_pos // page_size + 1) // G)
+        buf = plan.buf
+        groups = -(-(buf.row_pos // page_size + 1) // G) * buf.row_live
+        rows.append(int(buf.row_live.sum()))
         live.append(int(groups.sum()))
         slots.append(sum(-(-int(groups[b:b + R].sum()) // K) * K
                          for b in range(0, len(groups), R)))
@@ -361,13 +366,80 @@ def test_kv_chain_counters(kernel, page_size, kv_int8, group_pages,
     assert (eng.stats["kv_groups_live"], eng.stats["kv_chain_slots"]) \
         == (sum(live), sum(slots))
     assert sum(live) <= sum(slots)
-    rows = 3 + 6                        # the step's rows: slots + chunk
+    # the step's live rows, all inside one block of R
+    assert 0 < max(rows) <= min(R, 3 + 6)
     if group_pages:
-        assert sum(live) > rows * len(live)         # rows of several
+        assert sum(live) > sum(rows)                # rows of several
     else:
-        # one group a row, whatever the page: the same share
-        assert sum(live) == rows * len(live)
-        assert sum(slots) == -(-rows // K) * K * len(live)
+        # one group a live row, whatever the page: the same share
+        assert sum(live) == sum(rows)
+        assert sum(slots) == sum(-(-n // K) * K for n in rows)
+
+
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_plans_pack_live_rows_first(pipelined):
+    """Every dispatched plan's live rows are a prefix of its rows, the
+    dead ones after them: the page walk stops at the last live row and
+    so skips the whole dead tail.  Serial with draft rows beside the
+    decode and prefill rows (speculation runs at depth 0), and
+    pipelined with carried decode rows (depth 1, the TPU's)."""
+    import jax
+    from conftest import pools_seen_on
+    from mxnet_tpu.models import transformer as T
+    from mxnet_tpu.serving import ServingEngine
+
+    cfg = _cfg()
+    params = T.init_params(jax.random.PRNGKey(3), cfg)
+    kw = dict(num_slots=3, page_size=4, prefill_chunk=6, kernel="xla")
+    if pipelined:
+        with pools_seen_on("tpu"):
+            eng = ServingEngine(params, cfg, **kw)
+    else:
+        # a drafter that always proposes: draft rows in every step
+        eng = ServingEngine(params, cfg, spec_K=2, spec_drafter=lambda
+                            toks, K: np.ones(K, np.int32), **kw)
+    assert eng.overlap is pipelined
+    kinds = np.zeros(4, int)        # carried, decode, draft, prefill rows
+
+    def book(plan):
+        live, r = plan.buf.row_live, plan.n_rows_used
+        assert live[:r].all() and not live[r:].any()
+        kinds[:] += (plan.carried, plan.n_dec_rows - plan.carried,
+                     r - plan.n_dec_rows - plan.n_pre_rows,
+                     plan.n_pre_rows)
+    _run_counting(eng, book)
+    carried, decode, draft, prefill = kinds
+    assert prefill > 0 and bool(carried) == pipelined
+    if not pipelined:
+        assert decode > 0 and draft > 0
+
+
+def test_step_program_traced_once_across_live_counts(monkeypatch):
+    """The live rows' count reaches the walk as a traced scalar: steps
+    whose live rows number differently run ONE step program over one
+    built walk (a count fixed at trace time would trace, lower and
+    compile a program per count, at every start-up)."""
+    import jax
+    from mxnet_tpu.kernels import paged_attention as PA
+    from mxnet_tpu.models import transformer as T
+    from mxnet_tpu.serving import ServingEngine, engine
+
+    # built afresh here, not found from an earlier test
+    monkeypatch.setattr(engine, "_step_cache", {})
+    monkeypatch.setattr(PA, "_call_cache", {})
+    cfg = _cfg()
+    params = T.init_params(jax.random.PRNGKey(3), cfg)
+    eng = ServingEngine(params, cfg, num_slots=3, page_size=4,
+                        prefill_chunk=6, kernel="pallas")
+    counts = set()
+    _run_counting(eng, lambda plan: counts.add(plan.n_rows_used))
+    assert len(counts) > 2
+    assert eng._step_fn._cache_size() == 1
+    # one geometry, built for either platform's branch of the program
+    # (``kernels/platform.run_kernel``): the keys differ in
+    # ``interpret`` alone
+    keys = list(PA._call_cache)
+    assert len(keys) == 2 and len({k[:9] + k[10:] for k in keys}) == 1
 
 
 def _counter_reader(metric):
